@@ -1,5 +1,6 @@
-"""Guided-vs-unguided comparison harness (the PROFILE_r5 K_DELAY-table
-discipline applied to the search subsystem).
+"""Guided-vs-unguided comparison harness (the round-5 K_DELAY-table
+discipline — same engine, same budget, one variable — applied to the
+search subsystem).
 
 Runs the SAME engine, the SAME seed budget, the SAME batch machinery
 (`Engine.run_seed_batch`) twice per configuration:

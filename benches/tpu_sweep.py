@@ -41,9 +41,6 @@ if MESH_MODE and "xla_force_host_platform_device_count" not in os.environ.get(
     ).strip()
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from madsim_tpu._backend_watchdog import ensure_live_backend
-
-ensure_live_backend()
 
 import jax  # noqa: E402
 
@@ -134,7 +131,7 @@ def run_mesh_sweep(out_path: str, batch: int = 1024, segment_steps: int = 192) -
         not in ("", "0"),
         coverage=os.environ.get("MADSIM_TPU_COVERAGE", "1") not in ("", "0"),
     )
-    eng = Engine(RaftMachine(num_nodes=5, log_capacity=8), cfg)
+    eng = Engine.on_xla_step_path(RaftMachine(num_nodes=5, log_capacity=8), cfg)
     gates = {
         "rng_stream": cfg.rng_stream,
         "clog_packed": cfg.clog_packed,

@@ -28,10 +28,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from madsim_tpu._backend_watchdog import ensure_live_backend
-
-ensure_live_backend()  # falls back to CPU if the accelerator is wedged
-
 import jax.numpy as jnp
 
 from madsim_tpu.engine import Engine, EngineConfig, FaultPlan, replay, shrink

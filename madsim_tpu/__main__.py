@@ -189,6 +189,11 @@ def _build_engine_inner(args, Engine, EngineConfig, FaultPlan):
             **_fault_kind_flags(args),
         ),
     )
+    if _device_count(args) > 1:
+        # the code, not an environment variable, picks the step path a
+        # meshed run can take (run_stream refuses a kernel a library
+        # caller forces onto a mesh)
+        return Engine.on_xla_step_path(machine, cfg)
     return Engine(machine, cfg)
 
 
@@ -345,8 +350,25 @@ def _make_emitter(args):
     if not base:
         return None
     from .tracing import StatsEmitter
+    from .utils import device_info
 
-    return StatsEmitter(base, labels=getattr(args, "stats_labels", None))
+    return StatsEmitter(
+        base, labels=getattr(args, "stats_labels", None),
+        common=device_info(),
+    )
+
+
+def _print_device() -> None:
+    """The device the run was placed on, as jax reports it — printed
+    with every explore/hunt summary so no count or rate is read
+    without it."""
+    from .utils import device_info
+
+    d = device_info()
+    print(
+        f"device: platform={d['platform']} kind={d['device_kind']!r} "
+        f"count={d['device_count']}"
+    )
 
 
 def _print_cov_stats(stats) -> None:
@@ -546,8 +568,12 @@ def _stream_batches(eng, args, purpose="explore"):
             _save_ckpt(bi, True)  # seed budget already consumed: complete
             break
         t0 = wall.perf_counter()
+        # every batch runs the full lane count, the tail included: a
+        # narrower tail would be a new shape — a fresh compile, which
+        # on the chip costs more than the lanes it saves — and a lane
+        # count the mesh may not divide
         out = eng.run_stream(
-            chunk, batch=min(batch, chunk), segment_steps=384,
+            chunk, batch=batch, segment_steps=384,
             seed_start=cursor, max_steps=args.max_steps, **sk,
         )
         el = max(wall.perf_counter() - t0, 1e-9)
@@ -803,6 +829,7 @@ def cmd_explore(args) -> int:
             f"(pipelined={st['pipelined']}, donation={st['donation']}, "
             f"depth={st['dispatch_depth']}x{st['segments_per_dispatch']})"
         )
+        _print_device()
         _print_fr_stats(st)
         _print_cov_stats(st)
         _print_attribution(st)
@@ -821,6 +848,7 @@ def cmd_explore(args) -> int:
     n_done = int(res.done.sum())
     print(f"explored {len(seeds.tolist())} seeds ({n_done} completed), "
           f"{len(failing)} failing")
+    _print_device()
     if getattr(args, "coverage", False):
         import numpy as np
 
@@ -878,6 +906,7 @@ def cmd_hunt(args) -> int:
         )
         + plateau_txt
     )
+    _print_device()
     _print_fr_stats(stream_stats)
     _print_cov_stats(stream_stats)
     _print_attribution(stream_stats)
@@ -1765,9 +1794,8 @@ def _cmd_prof_compile(args) -> int:
     backend_s per streaming fn at this shape, plus cost_analysis
     flops/bytes and memory_analysis peak bytes, keyed by the same
     `cache_subkey` bench.py warms. One JSON line + a table."""
-    import jax
-
     from .compile_cache import cache_subkey
+    from .utils import device_info
 
     eng = _build_engine(args)
     sk = _stream_kwargs(args)
@@ -1793,7 +1821,7 @@ def _cmd_prof_compile(args) -> int:
     print(json.dumps({
         "metric": "prof_compile_autopsy",
         "machine": args.machine,
-        "platform": jax.devices()[0].platform,
+        **device_info(),
         "cache_subkey": subkey,
         "lanes": args.batch,
         "fns": rows,
@@ -1917,11 +1945,10 @@ def cmd_bench_ab(args) -> int:
     step_cost after it misread the provenance gate by 13x on this
     drifting box (PR 7's receipt: 8% single-rep vs 0.61% interleaved).
     Prints one JSON line + a human summary."""
-    import jax
-
     from .engine import Engine
     from .perf.ab import interleaved_ab
     from .perf.recorder import current_recorder
+    from .utils import device_info
 
     eng = _build_engine(args)
     base = eng.config
@@ -1970,7 +1997,7 @@ def cmd_bench_ab(args) -> int:
         "metric": f"{args.gate}_ab_delta_pct",
         "gate": args.gate,
         "machine": args.machine,
-        "platform": jax.devices()[0].platform,
+        **device_info(),
         "lanes": lanes,
         "seeds_per_rep": n_rep,
         **res.to_dict(),
@@ -2013,7 +2040,7 @@ def cmd_bench(args) -> int:
     import statistics
     import time as wall
 
-    import jax
+    from .utils import device_info
 
     eng = _build_engine(args)
     lanes = args.lanes or 8192
@@ -2037,7 +2064,7 @@ def cmd_bench(args) -> int:
         "metric": f"{args.machine}_seeds_per_sec",
         "value": round(statistics.median(rates), 1),
         "unit": "seeds/sec",
-        "platform": jax.devices()[0].platform,
+        **device_info(),
         "diagnostics": {
             "reps": [round(x, 1) for x in rates],
             "failing_total": fails,
@@ -2112,8 +2139,10 @@ def main(argv=None) -> int:
         p.add_argument(
             "--compile-cache", default=os.environ.get("MADSIM_TPU_COMPILE_CACHE"),
             help="JAX persistent compilation cache directory (also "
-            "$MADSIM_TPU_COMPILE_CACHE): pay each compile once per "
-            "machine, not once per process",
+            "$MADSIM_TPU_COMPILE_CACHE; default <checkout>/"
+            ".madsim-jit-cache): pay each compile once per machine, "
+            "not once per process. $JAX_COMPILATION_CACHE_DIR, where "
+            "set, wins over all of these",
         )
         p.add_argument(
             "--flight-recorder", action="store_true",
@@ -2929,20 +2958,16 @@ def main(argv=None) -> int:
         )
     )
     if getattr(args, "multihost", False):
-        # distributed init must precede ANY backend access — including
-        # the watchdog's own device probe, which would pin a
-        # single-process backend
+        # distributed init must precede ANY backend access, which
+        # would pin a single-process backend
         from .parallel import multihost
 
         multihost.initialize()
-    elif not jax_free:
-        from ._backend_watchdog import ensure_live_backend
-
-        cli_args = list(argv) if argv is not None else sys.argv[1:]
-        ensure_live_backend(argv=["-m", "madsim_tpu"] + cli_args)
     if not jax_free:
         # Warm-start priming: wire the persistent compilation cache
-        # (--compile-cache / $MADSIM_TPU_COMPILE_CACHE) BEFORE the
+        # (compile_cache.enable_compile_cache: $JAX_COMPILATION_CACHE_DIR,
+        # else --compile-cache / $MADSIM_TPU_COMPILE_CACHE, else the
+        # checkout default) BEFORE the
         # subcommand's first jit, so hunt/explore/bench-ab warmups
         # read and write the cache from their very first compile —
         # enabling is first-directory-wins per process, and an engine

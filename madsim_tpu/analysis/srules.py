@@ -127,9 +127,10 @@ COLLECTIVES: Dict[str, Collective] = {
         "global coverage map fold: bitwise-or all-reduce of the packed "
         "[W] words per segment (the 'tiny all-reduces' the ROADMAP "
         "names). Executed as ops/coverage.cov_fold_words: shard-local "
-        "or-reduce, then a bit-unpacked bool-any cross-device combine "
+        "or-reduce, then a bit-unpacked int32-max cross-device combine "
         "— integer or-all-reduce is unimplemented on the CPU collective "
-        "runtime the mesh path is CI-proven on; exact either way",
+        "runtime the mesh path is CI-proven on, and a pred all-reduce "
+        "lost bits on the v5e; exact either way",
     ),
     "cov-buffer-fold": Collective(
         "or", ("step",),

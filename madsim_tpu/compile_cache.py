@@ -1,34 +1,36 @@
-"""Opt-in JAX persistent compilation cache wiring (+ warm-start keys).
+"""JAX persistent compilation cache wiring (+ warm-start keys).
 
-PROFILE_r5 measured multi-second `lane_step` / streaming-executor
-recompiles paid once per *process*; hunts, sweeps and CI shards spawn
-many processes over the same configs, so they should pay each compile
-once per *machine*. Enabling is one env var (or `EngineConfig` /
-`--compile-cache`):
+Hunts, sweeps and CI shards spawn many processes over the same configs,
+so they should pay each compile once per *machine*, not once per
+*process*. The cache is therefore ON by default, at one fixed place.
 
-    MADSIM_TPU_COMPILE_CACHE=~/.cache/madsim_tpu python -m madsim_tpu ...
+Where the cache lives (`enable_compile_cache`):
+
+  * `JAX_COMPILATION_CACHE_DIR` set: jax itself already points there.
+    That directory is the active one whatever `--compile-cache`,
+    `$MADSIM_TPU_COMPILE_CACHE` or `EngineConfig.compile_cache_dir`
+    say, nothing is nested under it, and this module never touches
+    `jax_compilation_cache_dir` — whoever runs the program (a chip
+    tool, a CI job) places the cache from outside.
+  * otherwise `--compile-cache` / `$MADSIM_TPU_COMPILE_CACHE` /
+    `EngineConfig.compile_cache_dir`, else `DEFAULT_CACHE_DIR`
+    (`<checkout>/.madsim-jit-cache`, gitignored). Never a temporary
+    name, a pid or a time: the directory is where the NEXT process
+    looks, so a path that moves never hits.
 
 The cache is keyed by (HLO, jaxlib version, XLA flags, device kind), so
-it is safe to share a directory across configs and machines of the same
-software image; a mismatched key is simply a miss. Works on CPU, GPU and
-TPU backends with current jaxlib.
+it is safe to share a directory across configs, backends and machines
+of the same software image; a mismatched key is simply a miss.
 
-Warm-start discipline (r11): jax's internal key makes sharing SAFE but
-says nothing about what a given worker will actually *hit* — a fleet
-primes per-(jax version, gate tuple, stream version, shape) so a cold
-worker's first compile is a deserialize, not a build. `cache_subkey`
-renders exactly that tuple as a directory-name-safe string; bench.py
-routes its cache under it and reports `compile_s_cold` vs
-`compile_s_warm` (the warm number is measured by dropping the
-in-process jit caches and recompiling against the just-written
-persistent entries — the path every warm fleet worker takes). CI keys
-its actions/cache on the same string.
+`cache_subkey` renders the (jax version, gate tuple, stream version,
+shape, topology) tuple as a directory-name-safe string: the fleet
+allocator groups same-compile jobs by it and the AOT artifacts below
+are filed under it. It is not part of the XLA cache path.
 
-Failure discipline: `enable_compile_cache` used to degrade silently
-when the directory could not be created or written — a fleet that
-*thinks* it is warm but recompiles everywhere is the worst of both
-worlds. It now probes writability: `strict=True` (bench, priming jobs)
-raises; the default logs a warning and leaves the cache off.
+Failure discipline: an unwritable directory is probed by an actual
+write: `strict=True` (bench, priming jobs) raises; the default logs a
+warning and leaves the cache off — never a silent no-op that lets a
+fleet believe it is warm while every worker recompiles.
 """
 
 from __future__ import annotations
@@ -40,6 +42,12 @@ import re
 from typing import Optional
 
 _active_dir: Optional[str] = None
+
+#: where the cache lives when nothing outside the program places it
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".madsim-jit-cache",
+)
 
 _log = logging.getLogger("madsim_tpu.compile_cache")
 
@@ -247,31 +255,27 @@ def enable_compile_cache(
     path: Optional[str] = None,
     *,
     strict: bool = False,
-    subdir: Optional[str] = None,
 ) -> Optional[str]:
-    """Enable the JAX persistent compilation cache.
+    """Enable the JAX persistent compilation cache; returns the active
+    directory (see the module docstring for how it is chosen).
 
-    `path` falls back to $MADSIM_TPU_COMPILE_CACHE; with neither set
-    this is a no-op returning None. `subdir` (usually a `cache_subkey`)
-    nests the cache under the base path — pick it BEFORE the first jit,
-    because enabling is idempotent: the first directory wins for the
-    process (jax's cache is global); later calls with a different
-    directory return the ACTIVE one rather than silently rebinding half
-    the jit cache. Returns the active directory.
+    Idempotent, and the first directory wins for the process (jax's
+    cache is global): later calls return the ACTIVE directory rather
+    than silently rebinding half the jit cache — so call it BEFORE the
+    first jit.
 
     An unwritable directory raises RuntimeError under `strict` and
-    logs a warning (cache left off) otherwise — never the old silent
-    no-op that let a fleet believe it was warm while every worker
-    recompiled."""
+    logs a warning (cache left off, None returned) otherwise."""
     global _active_dir
-    path = path or os.environ.get("MADSIM_TPU_COMPILE_CACHE")
-    if not path:
-        return _active_dir
-    path = os.path.abspath(os.path.expanduser(path))
-    if subdir:
-        path = os.path.join(path, subdir)
     if _active_dir is not None:
         return _active_dir
+    external = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if external:
+        path = external
+    else:
+        path = os.path.abspath(os.path.expanduser(
+            path or os.environ.get("MADSIM_TPU_COMPILE_CACHE") or DEFAULT_CACHE_DIR
+        ))
     err = _probe_writable(path)
     if err is not None:
         msg = (
@@ -283,6 +287,7 @@ def enable_compile_cache(
         _log.warning("%s — persistent cache left DISABLED", msg)
         return None
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
     # cache wiring lands on the host timeline (madsim_tpu/perf) so a
     # --perf-timeline run shows whether its compiles could hit a
@@ -290,7 +295,8 @@ def enable_compile_cache(
     from .perf.recorder import maybe_count
 
     maybe_count("compile_cache_enabled")
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not external:
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache every compile, not just the multi-second ones: a hunt's many
     # small jits (replay steps, shrink candidates) add up too. -1 on the
     # entry-size floor disables the filesystem-specific override that 0
@@ -300,12 +306,7 @@ def enable_compile_cache(
     # the cache module latches "no cache" on the first compile of the
     # process; a reset makes the next compile re-initialize against the
     # directory just configured (no-op if nothing compiled yet)
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - layout drift across jax versions
-        pass
+    _cc.reset_cache()
     _active_dir = path
     return _active_dir
 
@@ -313,6 +314,15 @@ def enable_compile_cache(
 def active_compile_cache() -> Optional[str]:
     """The directory enabled for this process, or None."""
     return _active_dir
+
+
+def cache_entry_count() -> int:
+    """Executables in the active cache directory (0 when the cache is
+    off) — what a run prints before and after so a second run shows it
+    wrote nothing new."""
+    if _active_dir is None:
+        return 0
+    return sum(name.endswith("-cache") for name in os.listdir(_active_dir))
 
 
 def measure_warm_compile(build_and_run, cold_trace: bool = False) -> Optional[float]:

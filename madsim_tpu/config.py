@@ -9,11 +9,7 @@ so the host and TPU engines agree bit-for-bit.
 from __future__ import annotations
 
 import hashlib
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # python < 3.11: tomli is API-compatible
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from dataclasses import dataclass, field
 
 

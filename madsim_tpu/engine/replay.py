@@ -12,6 +12,7 @@ the property the reference gets from reproduce-by-seed
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Any, Callable, List, Optional
 
 import jax
@@ -128,6 +129,20 @@ def decode_ring(lane_ring) -> List[TraceEvent]:
     ]
 
 
+def cpu_device():
+    """The CPU backend's first device — every replay runs there, beside
+    whatever accelerator found the seed."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as exc:
+        raise RuntimeError(
+            "replay runs on jax's CPU backend, and this process has none "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}: {exc}). "
+            "Leave JAX_PLATFORMS unset, or list the CPU after the "
+            "accelerator: JAX_PLATFORMS=tpu,cpu"
+        ) from exc
+
+
 def _replay_cache(engine: Engine) -> dict:
     """Compiled-replay cache, held on the MACHINE object so every Engine
     wrapping the same machine shares it (shrink builds a fresh Engine per
@@ -201,8 +216,7 @@ def replay_outcome(engine: Engine, seed: int, max_steps: int = 10_000) -> Replay
     workhorse."""
     import jax.numpy as jnp
 
-    cpus = jax.devices("cpu")
-    with jax.default_device(cpus[0]):
+    with jax.default_device(cpu_device()):
         state = engine.init_lane(seed)
         state = _fast_outcome_fn(engine)(
             state,
@@ -230,8 +244,7 @@ def replay(
     """
     if not trace and on_step is None:
         return replay_outcome(engine, seed, max_steps=max_steps)
-    cpus = jax.devices("cpu")
-    with jax.default_device(cpus[0]):
+    with jax.default_device(cpu_device()):
         state = engine.init_lane(seed)
         # jit the single-lane step: still bit-identical (XLA integer ops are
         # exact and threefry is backend-stable), but the replay materializes

@@ -223,16 +223,23 @@ def cov_fold_words(lane_maps: jax.Array, *, shards: int = 1) -> jax.Array:
     the same fold restructured so every CROSS-DEVICE combine uses a
     reduction computation the collective runtimes implement: an
     integer bitwise-or AllReduce is UNIMPLEMENTED on the CPU backend
-    the mesh path is CI-proven on (and niche on others), while sum /
-    max / boolean-or are universal. Step 1 reduces shard-locally (a
+    the mesh path is CI-proven on (and niche on others), while an
+    int32 sum / max is universal. Step 1 reduces shard-locally (a
     split reshape keeps the lane axis's sharding on the leading factor,
     so the [shards, L/shards, W] -> [shards, W] or-reduce never crosses
     devices). Step 2 combines the per-shard partials bit-unpacked:
-    [shards, W, 32] bool `any` over the shard dim (a boolean-or
+    [shards, W, 32] int32 0/1 `max` over the shard dim (an int32 max
     AllReduce), repacked by summing the disjoint single-bit words —
     bits are disjoint so the sum IS the or, exactly. The intermediates
-    are [shards, W, 32] (a few KiB at any batch size): the restructured
-    fold costs O(devices * words), not O(lanes).
+    are [shards, W, 32] (64 KiB per shard at any batch size): the
+    restructured fold costs O(devices * words), not O(lanes).
+
+    The combine is deliberately NOT a boolean `any`: on a TPU v5e
+    (libtpu 0.0.34) XLA packs a pred AllReduce four-to-a-u32, and the
+    result kept only a quarter of the bits — the first run of the mesh
+    on hardware (PR 21) streamed the right seeds and reported 1114 of
+    4337 coverage slots. The CPU backend computed it correctly, so no
+    virtual-device test could see it.
 
     OR is associative/commutative/idempotent, so both forms compute
     the identical [W] vector for any lane->shard split — the
@@ -245,13 +252,13 @@ def cov_fold_words(lane_maps: jax.Array, *, shards: int = 1) -> jax.Array:
     lanes, words = lane_maps.shape
     # madsim: collective(cov-map-or, reduce=or) — the split reshape
     # keeps the lane sharding on the leading factor; the shard-local
-    # or-reduce below it never crosses devices, the bool-any combine is
+    # or-reduce below it never crosses devices, the int32-max combine is
     # the actual cross-chip leg
     split = lane_maps.reshape(shards, lanes // shards, words)
     part = jax.lax.reduce(split, jnp.int32(0), jax.lax.bitwise_or, (1,))
     bits = jnp.arange(COV_WORD_BITS, dtype=jnp.int32)
-    hit = ((part[:, :, None] >> bits) & 1).any(axis=0)  # [W, 32] bool
-    return (hit.astype(jnp.int32) << bits).sum(axis=-1, dtype=jnp.int32)
+    hit = ((part[:, :, None] >> bits) & 1).max(axis=0)  # [W, 32] int32 0/1
+    return (hit << bits).sum(axis=-1, dtype=jnp.int32)
 
 
 def empty_cov_map(slots_log2: int) -> jax.Array:

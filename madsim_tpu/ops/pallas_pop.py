@@ -41,10 +41,13 @@ cross-lane shuffles, so the kernels lower cleanly on Mosaic.
 The engine flips the fused kernels default-ON when the backend is TPU
 (`Engine.use_pallas_pop` / `Engine.use_megakernel`;
 `MADSIM_TPU_PALLAS_POP=0/1` and `MADSIM_TPU_PALLAS_MEGAKERNEL=0/1`
-force either way). The vmapped XLA path remains the fallback and the
-bit-identity oracle: both paths are asserted equal in interpreter mode
-for queue capacities {32, 64} and payload widths {4, 6}
-(tests/test_pallas.py).
+force either way). A selected kernel runs or raises; the vmapped XLA
+path is the bit-identity oracle, not a fallback. Both paths are
+asserted equal in interpreter mode for queue capacities {32, 64} and
+payload widths {4, 6} (tests/test_pallas.py), and `python
+chip_smoke.py` asserts it on the chip: all four kernels compile through
+Mosaic as written (libtpu 0.0.34, TPU v5e, PR 21) at Q = 32/96/320 and
+give the XLA path's bits at 8192 lanes.
 """
 
 from __future__ import annotations
@@ -53,15 +56,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import pop_earliest
-
-try:  # pallas is part of jax, but keep the engine importable without it
-    from jax.experimental import pallas as pl
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 LANE_BLOCK = 8  # lanes per grid step (int32 sublane tile)
 
@@ -433,7 +430,7 @@ def cov_flush_batch(cov_map, buf, n, use_pallas: bool = False, interpret: bool =
     """Batched buffer→map fold: the Pallas VMEM kernel, or the vmapped
     sequential `coverage.cov_flush` reference (the bit-identity
     oracle)."""
-    if use_pallas and HAVE_PALLAS:
+    if use_pallas:
         return cov_flush_pallas(cov_map, buf, n, interpret=interpret)
     from .coverage import cov_flush
 
@@ -442,7 +439,7 @@ def cov_flush_batch(cov_map, buf, n, use_pallas: bool = False, interpret: bool =
 
 def pop_earliest_batch(eq_time, eq_seq, eq_valid, use_pallas: bool = False, interpret: bool = False):
     """Reference implementation (vmapped XLA) or the fused Pallas kernel."""
-    if use_pallas and HAVE_PALLAS:
+    if use_pallas:
         return pop_earliest_pallas(eq_time, eq_seq, eq_valid, interpret=interpret)
     return jax.vmap(pop_earliest)(eq_time, eq_seq, eq_valid)
 
@@ -455,7 +452,7 @@ def pop_gather_batch(
     the vmapped-XLA reference (pop + take_along_axis gathers). Both
     return (idx, any_valid, (time, kind, node, src, payload)) with
     bit-identical values."""
-    if use_pallas and HAVE_PALLAS:
+    if use_pallas:
         return pop_gather_pallas(
             eq_time, eq_seq, eq_valid, eq_kind, eq_node, eq_src, eq_payload,
             interpret=interpret,

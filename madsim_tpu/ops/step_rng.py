@@ -69,11 +69,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.4.16
-    from jax.extend.random import threefry_2x32
-except Exception:  # pragma: no cover - older jax layouts
-    from jax._src.prng import threefry_2x32  # type: ignore
+from jax.extend.random import threefry_2x32
 
 # The stream contract also pins the PRNG *lowering*. jax's
 # `jax_threefry_partitionable` flag changes the bits jax.random.split /

@@ -12,7 +12,7 @@ series a queryable artifact:
   jax/jaxlib/python versions, lanes/reps/segment_steps, the engine
   gate tuple) — the fields that decide whether two rows are comparable
   at all;
-* the legacy BENCH_r01..r09 files import once (auto, on first append)
+* legacy BENCH_r*.json files import once (auto, on first append)
   so the trajectory starts populated, tagged by their round;
 * the budget check becomes a NEIGHBOR comparison: the newest prior row
   whose platform/lanes/gates (and host, when both recorded) match —
@@ -198,8 +198,8 @@ def next_tag(rows: List[dict]) -> str:
 
 def import_legacy(repo_dir: str) -> List[dict]:
     """Parse every BENCH_r*.json in `repo_dir` into history rows.
-    Handles both shapes in the wild: the r01/r02 driver-capture wrapper
-    ({"parsed": {...}}) and the direct bench.py JSON (r03+). Fields a
+    Handles both shapes in the wild: the driver-capture wrapper
+    ({"parsed": {...}}) and the direct bench.py JSON. Fields a
     round didn't record stay None — the neighbor selector treats
     missing lanes/gates as not-comparable rather than guessing."""
     rows: List[dict] = []

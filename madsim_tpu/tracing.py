@@ -118,10 +118,14 @@ class StatsEmitter:
     does exactly that with ``labels={"job": <id>}``."""
 
     def __init__(self, base: str, prefix: str = "madsim_tpu",
-                 labels: Optional[dict] = None):
+                 labels: Optional[dict] = None,
+                 common: Optional[dict] = None):
         self.base = base
         self.prefix = prefix
         self.labels = dict(labels) if labels else None
+        # fields stamped on EVERY record (the CLI passes the device the
+        # run is placed on, so no rate is ever read without it)
+        self.common = dict(common) if common else {}
         self.seq = 0
         self._jsonl = open(base + ".jsonl", "a")
 
@@ -169,7 +173,8 @@ class StatsEmitter:
 
         self.seq += 1
         # madsim: allow(D001) — JSONL sink stamps host wall time
-        row = {"ts": round(time.time(), 6), "seq": self.seq, **record}
+        ts = round(time.time(), 6)
+        row = {"ts": ts, "seq": self.seq, **self.common, **record}
         with maybe_span("stats_emit"):
             return self._emit_row(row)
 

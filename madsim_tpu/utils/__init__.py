@@ -33,3 +33,19 @@ def tree_stack_fields(tree, n):
     """Broadcast each leaf to a leading dim of n (used to replicate an
     initial node state over N nodes)."""
     return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + jnp.shape(x)), tree)
+
+
+def device_info() -> dict:
+    """The device work is placed on, as jax reports it: platform,
+    device_kind and how many such devices are visible. Every stream
+    summary, bench line and stats record carries it, so a number can
+    never be read without the device it came from. Honors an active
+    `jax.default_device(...)` (the CPU replay/oracle paths)."""
+    dev = jax.config.jax_default_device or jax.devices()[0]
+    if isinstance(dev, str):
+        dev = jax.devices(dev)[0]
+    return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices(dev.platform)),
+    }

@@ -681,14 +681,14 @@ def test_checkpoint_refuses_mismatched_args(tmp_path, monkeypatch, echo_engine):
 
 
 def test_retry_transient_unit():
-    from madsim_tpu._backend_watchdog import retry_transient
+    from madsim_tpu._dispatch_retry import retry_transient
 
     calls = []
 
     def flaky():
         calls.append(1)
         if len(calls) < 3:
-            raise RuntimeError("UNAVAILABLE: fake tunnel blip")
+            raise RuntimeError("UNAVAILABLE: fake backend blip")
         return 42
 
     sleeps = []
@@ -715,7 +715,7 @@ def test_run_stream_retries_transient_dispatch(monkeypatch, echo_engine):
     be retried (counted in stats) and the stream still completes. The
     fake raises BEFORE touching the donated carry — the retry-able
     shape; a post-consumption failure propagates (not retried), which
-    the donation caveat in _backend_watchdog documents."""
+    the donation caveat in _dispatch_retry documents."""
     orig = Engine._stream_fns
     state = {"tripped": False}
 

@@ -180,8 +180,7 @@ def test_raft_overcommit_bug_found_at_scale_and_fixed():
     corpus-rot audit traced it (and all 8 corpus entries) to jax's
     jax_threefry_partitionable default differing between the recording
     box and this container. The engine now pins the lowering
-    (ops/step_rng.py) and the seed reproduces again; NOTES_PR3.md has
-    the full bisection."""
+    (ops/step_rng.py) and the seed reproduces again."""
 
     class OvercommitRaft(RaftMachine):
         COMMIT_TO_LOG_LEN = True

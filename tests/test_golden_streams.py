@@ -14,7 +14,7 @@ jax's `jax_threefry_partitionable` flag defaulted FALSE. The corpus and
 slow-seed 66531 were recorded earlier, on a box whose newer jax
 defaulted it TRUE, producing different split/bits streams for the same
 seed; the flag gap — not any engine edit — was the whole "corpus rot"
-(NOTES_PR3.md carries the bisection). The engine now pins
+(found by bisection in PR 3). The engine now pins
 partitionable=True in ops/step_rng.py (the recording-era value and the
 one modern jax keeps), and the constants below are the re-capture under
 that pinned lowering — i.e. the restored ORIGINAL seed-era streams.

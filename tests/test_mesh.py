@@ -129,8 +129,29 @@ def test_mesh_refuses_pallas_kernels():
         EngineConfig(horizon_us=2_000_000, queue_capacity=64),
         use_pallas_pop=True,
     )
-    if not eng.use_pallas_pop:
-        pytest.skip("Pallas unavailable in this build")
     mesh = make_mesh(_devices_or_skip(2))
     with pytest.raises(ValueError, match="[Pp]allas"):
         eng.run_stream(32, mesh=mesh, **STREAM_KW)
+
+
+def test_meshed_cov_fold_combines_shards_with_an_int32_max():
+    """The cross-device leg of the coverage fold must not be a boolean
+    reduce: on a TPU v5e (libtpu 0.0.34) the pred AllReduce it became
+    kept one bit in four inside the full supersegment (PR 21, the first
+    mesh run on hardware: 1,114 coverage slots for 4,337), while every
+    CPU device count computes it correctly — so this pins the
+    formulation, and `chip_smoke.py` on four chips pins the result."""
+    import jax.numpy as jnp
+
+    from madsim_tpu.ops.coverage import cov_fold_words
+
+    rng = np.random.default_rng(7)
+    maps = rng.integers(-(2**31), 2**31, size=(16, 8), dtype=np.int64)
+    maps = (maps & rng.integers(-(2**31), 2**31, size=(16, 8))).astype(np.int32)
+    want = np.bitwise_or.reduce(maps, axis=0)
+    for shards in (1, 2, 4, 8):
+        got = np.asarray(cov_fold_words(jnp.asarray(maps), shards=shards))
+        assert np.array_equal(got, want), shards
+    jaxpr = str(jax.make_jaxpr(lambda m: cov_fold_words(m, shards=4))(maps))
+    assert "reduce_max" in jaxpr
+    assert "bool" not in jaxpr and "reduce_or" not in jaxpr

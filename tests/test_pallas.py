@@ -12,15 +12,12 @@ import pytest
 
 from madsim_tpu.ops import pop_earliest
 from madsim_tpu.ops.pallas_pop import (
-    HAVE_PALLAS,
     pop_earliest_batch,
     pop_gather_batch,
     step_megakernel,
     step_rng_words_fused,
     threefry2x32_pair,
 )
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 
 
 def _random_queues(key, lanes=32, q=96):

@@ -11,6 +11,8 @@ fake clocks, no device work.
 import json
 import math
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -321,22 +323,38 @@ def test_history_neighbor_selection():
     assert history.neighbor_budget(rows, 104.0, _fp(platform="tpu")) is None
 
 
-def test_history_legacy_import_real_series():
-    """The checked-in BENCH_r01..r10 series imports with its recorded
-    values; wrapped driver captures (r01/r02) parse too."""
-    rows = history.import_legacy(REPO)
-    tags = [r["tag"] for r in rows]
-    assert tags[:9] == [f"r{i:02d}" for i in range(1, 10)]
+def test_history_legacy_import_real_series(tmp_path):
+    """Legacy BENCH_r*.json captures import with their recorded values
+    in both shapes in the wild: a wrapped driver capture
+    ({"parsed": {...}}) and the flat bench.py JSON."""
+    flat_gates = {"rng_stream": 3, "clog_packed": True, "pallas_pop": False,
+                  "flight_recorder": True, "coverage": True}
+    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
+        "n": 1, "cmd": "python bench.py", "rc": 0, "tail": "...",
+        "parsed": {"metric": "madraft5_seeds_per_sec_per_chip",
+                   "value": 207.1, "unit": "seeds/sec", "platform": "cpu"},
+    }))
+    for tag, value in (("r08", 447.6), ("r09", 452.5)):
+        (tmp_path / f"BENCH_{tag}.json").write_text(json.dumps({
+            "metric": "madraft5_seeds_per_sec_per_chip", "value": value,
+            "platform": "cpu", "compile_s": 24.07, "gates": flat_gates,
+            "diagnostics": {"reps": [461.7, 458.5, value, 434.0, 434.4],
+                            "lanes": 8192, "segment_steps": 384,
+                            "spread_pct": 6.0},
+        }))
+    (tmp_path / "BENCH_r10.json").write_text("{not json")  # skipped, not fatal
+    rows = history.import_legacy(str(tmp_path))
+    assert [r["tag"] for r in rows] == ["r01", "r08", "r09"]
     by_tag = {r["tag"]: r for r in rows}
     assert by_tag["r01"]["value"] == 207.1
-    assert by_tag["r06"]["value"] == 505.8
+    assert by_tag["r01"]["fingerprint"]["lanes"] is None  # never recorded
+    assert by_tag["r09"]["value"] == 452.5
     assert by_tag["r09"]["fingerprint"]["lanes"] == 8192
+    assert by_tag["r09"]["fingerprint"]["reps"] == 5
     assert by_tag["r09"]["fingerprint"]["gates"]["coverage"] is True
     assert by_tag["r09"]["ts"] is None  # legacy: capture time unknown
     # r09's neighbor under its own config is r08 (same gates/lanes/platform)
-    nb = history.select_neighbor(
-        rows[:8], by_tag["r09"]["fingerprint"]
-    )
+    nb = history.select_neighbor(rows[:2], by_tag["r09"]["fingerprint"])
     assert nb["tag"] == "r08"
 
 
@@ -353,25 +371,25 @@ def test_history_report_renders_checked_in_series():
     assert "COMPARABLE" in text
 
 
-def test_bench_report_cli_is_jax_free(tmp_path, monkeypatch):
-    """`python -m madsim_tpu bench report` renders without touching the
-    backend watchdog (it must work on a box with no accelerator stack);
-    exercised in-process against a scratch history."""
-    from madsim_tpu.__main__ import main
-
+def test_bench_report_cli_is_jax_free(tmp_path):
+    """`python -m madsim_tpu bench report` renders without importing
+    jax at all (it must work on a box with no accelerator stack):
+    run in a child whose `jax` import is poisoned."""
     path = tmp_path / "h.jsonl"
     history.append(
         str(path), history.make_record("r01", 42.0, _fp(), ts=1.0)
     )
-
-    def boom(*a, **kw):  # the probe would re-exec; report must not probe
-        raise AssertionError("bench report must not touch the backend")
-
-    import madsim_tpu._backend_watchdog as wd
-
-    monkeypatch.setattr(wd, "ensure_live_backend", boom)
-    rc = main(["bench", "report", "--history", str(path)])
-    assert rc == 0
+    code = (
+        "import sys; sys.modules['jax'] = None; "  # any `import jax` raises
+        "from madsim_tpu.__main__ import main; "
+        f"sys.exit(main(['bench', 'report', '--history', {str(path)!r}]))"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert "r01" in proc.stdout
 
 
 def test_history_fingerprint_gate_normalization():
@@ -525,13 +543,75 @@ def test_compile_cache_unwritable_fails_loud(tmp_path, monkeypatch):
     bad = str(blocker / "cache")
     monkeypatch.setattr(cc, "_active_dir", None)
     monkeypatch.delenv("MADSIM_TPU_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     with pytest.raises(RuntimeError, match="not writable"):
         cc.enable_compile_cache(bad, strict=True)
     # non-strict: warns, returns None, cache stays off
     assert cc.enable_compile_cache(bad) is None
     assert cc._active_dir is None
-    # no path configured at all: no-op either way
-    assert cc.enable_compile_cache(None) is None
+    # a directory placed from outside is probed the same way
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", bad)
+    with pytest.raises(RuntimeError, match="not writable"):
+        cc.enable_compile_cache(strict=True)
+
+
+def _recorded_cache_wiring(monkeypatch):
+    """compile_cache with jax's config updates and cache reset recorded
+    instead of applied: the cache is process-global, and a test must
+    not rebind it under the rest of the suite."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    from madsim_tpu import compile_cache as cc
+
+    updates = {}
+    monkeypatch.setattr(cc, "_active_dir", None)
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.setattr(jcc, "reset_cache", lambda: None)
+    return cc, updates
+
+
+def test_compile_cache_placed_from_outside_wins(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory is the active one
+    whatever --compile-cache / $MADSIM_TPU_COMPILE_CACHE /
+    EngineConfig.compile_cache_dir say, nothing is nested under it, and
+    the code never sets `jax_compilation_cache_dir` — only the
+    cache-everything thresholds."""
+    cc, updates = _recorded_cache_wiring(monkeypatch)
+    outside = tmp_path / "placed-by-the-driver"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+    monkeypatch.setenv("MADSIM_TPU_COMPILE_CACHE", str(tmp_path / "env"))
+    assert cc.enable_compile_cache(str(tmp_path / "flag")) == str(outside)
+    assert cc.active_compile_cache() == str(outside)
+    assert "jax_compilation_cache_dir" not in updates
+    assert updates == {
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+    }
+    assert sorted(os.listdir(tmp_path)) == ["placed-by-the-driver"]
+    assert os.listdir(outside) == []  # no subdirectory, no probe left
+    assert cc.cache_entry_count() == 0
+    # first directory wins: a later Engine(compile_cache_dir=...) is ignored
+    assert cc.enable_compile_cache(str(tmp_path / "later")) == str(outside)
+
+
+def test_compile_cache_default_is_the_checkout_dir(tmp_path, monkeypatch):
+    """Nothing set: the cache is ON at the fixed <checkout>/
+    .madsim-jit-cache (never a temporary name); $MADSIM_TPU_COMPILE_CACHE
+    or an explicit path moves it."""
+    cc, updates = _recorded_cache_wiring(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("MADSIM_TPU_COMPILE_CACHE", raising=False)
+    default = os.path.join(REPO, ".madsim-jit-cache")
+    assert cc.DEFAULT_CACHE_DIR == default
+    assert cc.enable_compile_cache() == default
+    assert updates["jax_compilation_cache_dir"] == default
+    monkeypatch.setattr(cc, "_active_dir", None)
+    monkeypatch.setenv("MADSIM_TPU_COMPILE_CACHE", str(tmp_path / "env"))
+    assert cc.enable_compile_cache() == str(tmp_path / "env")
+    monkeypatch.setattr(cc, "_active_dir", None)
+    assert cc.enable_compile_cache(str(tmp_path / "flag")) == str(tmp_path / "flag")
+    assert updates["jax_compilation_cache_dir"] == str(tmp_path / "flag")
 
 
 def test_bench_reports_cold_and_warm_compile_keys():

@@ -16,7 +16,6 @@ import pytest
 from madsim_tpu.engine import Engine, EngineConfig, FaultPlan
 from madsim_tpu.engine.replay import replay
 from madsim_tpu.models.raft import RaftMachine
-from madsim_tpu.ops.pallas_pop import HAVE_PALLAS
 
 # all six fault kinds + real packet loss: every clog representation and
 # every chaos-draw section of the RNG block is exercised
@@ -75,7 +74,6 @@ def test_clog_packed_gate_bit_identical(cfg, rng_stream):
     _assert_results_equal(r_packed, r_bool)
 
 
-@pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 def test_pallas_pop_gate_bit_identical():
     # fused pop+gather (interpreter mode off-TPU) vs the XLA oracle
     cfg = dataclasses.replace(FULL_CHAOS, rng_stream=3)
@@ -353,7 +351,6 @@ def test_compile_cache_wiring(tmp_path, monkeypatch):
         assert os.listdir(active), "no cache entries written"
 
 
-@pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 def test_megakernel_gate_bit_identical():
     """The whole-event step megakernel (pop + gather + v3 RNG block +
     digest fold in one fused pass, interpreter mode off-TPU) vs the XLA
