@@ -254,11 +254,13 @@ def _repro_line(args, seed) -> str:
 @contextlib.contextmanager
 def _perf_session(args):
     """`--perf-timeline PATH` / `--xla-profile DIR` wrapper around a
-    whole subcommand: a PerfRecorder publishes itself for the engine's
+    whole subcommand: a PerfRecorder publishes itself for the program's
     span instrumentation (madsim_tpu/perf/recorder.py) and the Chrome/
     Perfetto host timeline + summary land AFTER the command's own
     output; `--xla-profile` additionally wraps the run in
-    `jax.profiler.trace` (device/XLA-level profile for tensorboard).
+    `jax.profiler.trace` (device/XLA-level profile for tensorboard),
+    and with both the recorder annotates: every span is also a
+    `madsim.<name>` slice in that profile, on the device ops' clock.
     The timeline is written even when the command fails — a failing
     run's wall-clock profile is exactly what you want to look at."""
     path = getattr(args, "perf_timeline", None)
@@ -276,9 +278,12 @@ def _perf_session(args):
             if path:
                 from .perf.recorder import PerfRecorder
 
-                rec = stack.enter_context(
-                    PerfRecorder(meta={"cmd": getattr(args, "cmd", None)})
-                )
+                # with --xla-profile the spans are written into the
+                # capture too: host and device on one clock
+                rec = stack.enter_context(PerfRecorder(
+                    meta={"cmd": getattr(args, "cmd", None)},
+                    annotate=bool(xla_dir),
+                ))
             yield rec
     finally:
         if rec is not None and rec.wall_us:
@@ -430,6 +435,8 @@ def _stream_batches(eng, args, purpose="explore"):
     import numpy as np
     import time as wall
 
+    from .perf.recorder import maybe_span
+
     if getattr(args, "guided", False):
         # coverage-feedback search (madsim_tpu/search): same aggregate
         # shape, same checkpoint file, same stats feed — but every
@@ -558,7 +565,8 @@ def _stream_batches(eng, args, purpose="explore"):
         )
 
     # compile + warm outside the timed loop (same discipline as before)
-    eng.run_stream(1, batch=batch, segment_steps=384, max_steps=args.max_steps, **sk)
+    with maybe_span("warmup_dispatch"):
+        eng.run_stream(1, batch=batch, segment_steps=384, max_steps=args.max_steps, **sk)
 
     t_start = wall.perf_counter()
     bi = start_bi - 1
@@ -875,7 +883,7 @@ def cmd_explore(args) -> int:
 def cmd_hunt(args) -> int:
     """explore -> shrink -> corpus: every found failing seed becomes a
     durable "open" regression entry with its minimized config."""
-    from .engine import audit, corpus, shrink
+    from .perf.recorder import maybe_span
 
     if getattr(args, "guided", False):
         if not args.stream:
@@ -885,68 +893,74 @@ def cmd_hunt(args) -> int:
             sys.exit("--guided needs --coverage: the bias signal IS the "
                      "live coverage map")
     eng = _build_engine(args)
+    # after the build, whose span holds the engine's import chain
+    from .engine import audit, corpus, shrink
+
     failing, infra, abandoned, agg = _find_failing(eng, args, purpose="hunt")
-    stream_stats = agg.get("stats", {})
-    hunted = agg.get("completed", args.seeds)
-    plateau_txt = ""
-    if agg.get("plateau"):
-        # honest reporting: a plateaued hunt ran FEWER seeds than asked
-        plateau_txt = (
-            f" [coverage plateau: stopped after batch "
-            f"{agg['batches_run']}/{agg['batches_planned']} — "
-            f"{max(0, args.seeds - hunted)} budgeted seeds not run]"
-        )
-    print(
-        f"hunted {hunted} seeds: {len(failing)} failing"
-        + (f", {abandoned} abandoned (over --max-steps)" if abandoned else "")
-        + (
-            f", {len(infra)} infra artifacts (queue overflow — rerun "
-            f"with a bigger --queue; not recorded as findings)"
-            if infra else ""
-        )
-        + plateau_txt
-    )
-    _print_device()
-    _print_fr_stats(stream_stats)
-    _print_cov_stats(stream_stats)
-    _print_attribution(stream_stats)
-    guided_rec = agg.get("guided") or {}
-    if guided_rec:
-        g = stream_stats.get("guided", {})
-        print(
-            f"guided: escalation step {g.get('escalation', 0)}, "
-            f"{g.get('parents', 0)} corpus parents, "
-            f"{g.get('mutants', 0)} mutants over {g.get('batches', 0)} "
-            f"batches (trail recorded"
-            + (" in checkpoint)" if getattr(args, "checkpoint", None)
-               else ")")
-        )
-    _write_coverage_out(eng, args, agg)
-    entries = corpus.load(args.corpus)
-    known = {e.key for e in entries}
-    added = 0
-    # Shrink one representative per distinct fail code (high-find-rate
-    # hunts surface thousands of seeds of the SAME bug; shrinking five
-    # copies of one code is pure waste). --all-seeds restores the
-    # first-N behavior for deliberately sampling one code's seeds.
-    if getattr(args, "all_seeds", False):
-        to_shrink = failing[: args.limit]
-    else:
-        by_code: dict = {}
-        for seed, code in failing:
-            by_code.setdefault(code, []).append(seed)
-        to_shrink = [(s[0], c) for c, s in sorted(by_code.items())][: args.limit]
-        shrinking = {c for _s, c in to_shrink}
-        for code, seeds_of in sorted(by_code.items()):
-            verb = (
-                f"shrinking seed {seeds_of[0]}" if code in shrinking
-                else "beyond --limit, not shrunk"
+    # the stream's return to the first shrink: prints, the coverage
+    # file, the corpus load
+    with maybe_span("hunt_report"):
+        stream_stats = agg.get("stats", {})
+        hunted = agg.get("completed", args.seeds)
+        plateau_txt = ""
+        if agg.get("plateau"):
+            # honest reporting: a plateaued hunt ran FEWER seeds than asked
+            plateau_txt = (
+                f" [coverage plateau: stopped after batch "
+                f"{agg['batches_run']}/{agg['batches_planned']} — "
+                f"{max(0, args.seeds - hunted)} budgeted seeds not run]"
             )
-            print(f"  code {code}: {len(seeds_of)} seeds ({verb})")
-    esc_by_seed = {
-        int(k): int(v)
-        for k, v in (guided_rec.get("failing_escalation") or {}).items()
-    } if guided_rec else {}
+        print(
+            f"hunted {hunted} seeds: {len(failing)} failing"
+            + (f", {abandoned} abandoned (over --max-steps)" if abandoned else "")
+            + (
+                f", {len(infra)} infra artifacts (queue overflow — rerun "
+                f"with a bigger --queue; not recorded as findings)"
+                if infra else ""
+            )
+            + plateau_txt
+        )
+        _print_device()
+        _print_fr_stats(stream_stats)
+        _print_cov_stats(stream_stats)
+        _print_attribution(stream_stats)
+        guided_rec = agg.get("guided") or {}
+        if guided_rec:
+            g = stream_stats.get("guided", {})
+            print(
+                f"guided: escalation step {g.get('escalation', 0)}, "
+                f"{g.get('parents', 0)} corpus parents, "
+                f"{g.get('mutants', 0)} mutants over {g.get('batches', 0)} "
+                f"batches (trail recorded"
+                + (" in checkpoint)" if getattr(args, "checkpoint", None)
+                   else ")")
+            )
+        _write_coverage_out(eng, args, agg)
+        entries = corpus.load(args.corpus)
+        known = {e.key for e in entries}
+        added = 0
+        # Shrink one representative per distinct fail code (high-find-rate
+        # hunts surface thousands of seeds of the SAME bug; shrinking five
+        # copies of one code is pure waste). --all-seeds restores the
+        # first-N behavior for deliberately sampling one code's seeds.
+        if getattr(args, "all_seeds", False):
+            to_shrink = failing[: args.limit]
+        else:
+            by_code: dict = {}
+            for seed, code in failing:
+                by_code.setdefault(code, []).append(seed)
+            to_shrink = [(s[0], c) for c, s in sorted(by_code.items())][: args.limit]
+            shrinking = {c for _s, c in to_shrink}
+            for code, seeds_of in sorted(by_code.items()):
+                verb = (
+                    f"shrinking seed {seeds_of[0]}" if code in shrinking
+                    else "beyond --limit, not shrunk"
+                )
+                print(f"  code {code}: {len(seeds_of)} seeds ({verb})")
+        esc_by_seed = {
+            int(k): int(v)
+            for k, v in (guided_rec.get("failing_escalation") or {}).items()
+        } if guided_rec else {}
     for seed, code in to_shrink:
         # a guided find made under an escalated vocabulary only
         # reproduces under that vocabulary: shrink (and the corpus
@@ -985,13 +999,15 @@ def cmd_hunt(args) -> int:
             continue
         # every new entry carries its digest trail + environment
         # fingerprint from birth, so future rot is auditable
-        entry, _trail = audit.record_entry(entry, build_machine)
+        with maybe_span("corpus_record", seed=int(seed)):
+            entry, _trail = audit.record_entry(entry, build_machine)
         known.add(entry.key)
         entries.append(entry)
         added += 1
         print(f"  + corpus: {sr.summary()}")
     if added:
-        corpus.save(args.corpus, entries)
+        with maybe_span("corpus_record"):
+            corpus.save(args.corpus, entries)
     if len(to_shrink) < (len(failing) if getattr(args, "all_seeds", False)
                          else len({c for _s, c in failing})):
         print(f"  (further failing codes/seeds not shrunk; raise --limit)")
@@ -1004,6 +1020,7 @@ def cmd_regress(args) -> int:
     entries must still reproduce their exact failure; fixed entries must
     keep passing. `--promote` flips open entries that no longer fail."""
     from .engine import corpus
+    from .perf.recorder import maybe_span
 
     entries = corpus.load(args.corpus)
     if not entries:
@@ -1013,7 +1030,8 @@ def cmd_regress(args) -> int:
     changed = False
     for i, e in enumerate(entries):
         try:
-            out = corpus.check(e, build_machine)
+            with maybe_span("regress_entry", seed=int(e.seed)):
+                out = corpus.check(e, build_machine)
         except SystemExit:
             # unknown machine name (renamed registry entry / foreign
             # corpus) must not kill the run — later entries still get
@@ -1193,6 +1211,7 @@ def cmd_audit(args) -> int:
     refusing entries whose behavioral outcome no longer matches their
     status contract (recording those would bake the rot in)."""
     from .engine import audit, corpus
+    from .perf.recorder import maybe_span
 
     entries = corpus.load(args.corpus)
     if not entries:
@@ -1226,7 +1245,8 @@ def cmd_audit(args) -> int:
                       f"{len(new.digests)} checkpoints every {new.digest_every} "
                       f"steps, final step {new.digest_final[0]}")
                 continue
-            out = audit.audit_entry(e, build_machine)
+            with maybe_span("audit_entry", seed=int(e.seed)):
+                out = audit.audit_entry(e, build_machine)
         except SystemExit:
             print(f"[FAIL] {e.machine} seed {e.seed}: unknown machine in registry")
             bad += 1
@@ -1848,9 +1868,10 @@ def _cmd_prof_compile(args) -> int:
 
 def cmd_prof(args) -> int:
     """The three-clock profiler (madsim_tpu/perf/xprof.py): stream a
-    hunt batch with MADSIM_TPU_XPROF on — device-phase TraceAnnotations,
-    clock-sync markers at dispatch/poll boundaries, a jax.profiler
-    device capture — and, with --merge, align host wall-clock spans,
+    hunt batch under a jax.profiler device capture — the program's
+    `madsim.*` phase scopes name the device ops, an annotating recorder
+    writes the host spans into the same capture, clock-sync markers sit
+    at dispatch/poll boundaries — and, with --merge, align host wall-clock spans,
     the device profile and the failing lane's virtual-time trace into
     ONE Perfetto session. `prof compile` prints the per-stage compile
     autopsy instead."""
@@ -1862,13 +1883,15 @@ def cmd_prof(args) -> int:
     if getattr(args, "action", None) == "compile":
         return _cmd_prof_compile(args)
 
-    # the gate must be on before any stream fn is traced; _stream_fns
-    # keys its cache on it, so this process re-traces with the scopes in
+    # the gate means "capture a device profile"; the phase scopes are
+    # in every program anyway, so nothing is re-traced for it
     os.environ[xprof.ENV_GATE] = "1"
     eng = _build_engine(args)
     sk = _stream_kwargs(args)
     logdir = args.profile_dir or tempfile.mkdtemp(prefix="madsim-xprof-")
-    rec = PerfRecorder(meta={
+    # annotate: every host span is also a `madsim.<name>` slice in the
+    # capture, on the device ops' clock
+    rec = PerfRecorder(annotate=True, meta={
         "cmd": "prof", "machine": args.machine, "seeds": args.seeds,
         "batch": args.batch,
     })

@@ -292,9 +292,12 @@ def enable_compile_cache(
     # cache wiring lands on the host timeline (madsim_tpu/perf) so a
     # --perf-timeline run shows whether its compiles could hit a
     # persistent cache at all
+    from .perf import compile_log
     from .perf.recorder import maybe_count
 
     maybe_count("compile_cache_enabled")
+    # compile stages by program, from here on (perf/compile_log.py)
+    compile_log.install()
     if not external:
         jax.config.update("jax_compilation_cache_dir", path)
     # cache every compile, not just the multi-second ones: a hunt's many

@@ -1089,7 +1089,8 @@ class Engine:
     # -- one event per lane --------------------------------------------------
 
     def lane_step(self, s: LaneState, horizon_us=None) -> LaneState:
-        idx, any_valid = pop_earliest(s.eq_time, s.eq_seq, s.eq_valid)
+        with _xprof.scope("step.pop"):
+            idx, any_valid = pop_earliest(s.eq_time, s.eq_seq, s.eq_valid)
         return self._lane_step_popped(s, idx, any_valid, horizon_us=horizon_us)
 
     def _lane_step_popped(
@@ -1128,709 +1129,719 @@ class Engine:
         immutable; the restart key is a block slice)."""
         m, cfg = self.machine, self.config
 
-        if popped is None:
-            ev_time = s.eq_time[idx]
-            ev_kind = s.eq_kind[idx]
-            ev_node = s.eq_node[idx]
-            ev_src = s.eq_src[idx]
-            ev_payload = s.eq_payload[idx]
-        else:
-            ev_time, ev_kind, ev_node, ev_src, ev_payload = popped
-
-        if cfg.provenance:
-            # the popped event's lineage word (fault slots carry their
-            # bit from init; messages/timers carry their sender's word)
-            ev_prov = s.eq_prov[idx]
-
-        new_now = jnp.maximum(s.now_us, ev_time)
-        hz = cfg.horizon_us if horizon_us is None else horizon_us
-        # `live` = this lane pops an event this step (frozen lanes never
-        # do; their popped tuple is junk-but-deterministic and every use
-        # below is gated on live/process/effective)
-        live = any_valid if active is None else any_valid & active
-        horizon_hit = live & (new_now >= hz)
-        process = live & ~horizon_hit
-        node_alive = ~s.killed[ev_node]
-        # pause windows: a handler event targeting a paused (alive) node
-        # is DEFERRED — the popped slot stays valid and only its time
-        # moves to the node's resume point (the state survives, nothing
-        # is processed, nothing is dropped). Kill still dominates: a
-        # dead node's events are consumed as before. The deferred pop
-        # itself is a popped event (trace ring / digest / coverage see
-        # it) — host replay pops it identically, so the contract holds.
-        if cfg.faults.allow_pause:
-            node_resume_us = s.paused_until[ev_node]
-            defer = (
-                process
-                & (ev_kind != EV_FAULT)
-                & node_alive
-                & (node_resume_us > new_now)
-            )
-        else:
-            defer = None
-        pop_mask = (jnp.arange(s.eq_valid.shape[0]) == idx) & live
-        if defer is not None:
-            pop_mask = pop_mask & ~defer
-        eq_valid = s.eq_valid & ~pop_mask
-
-        # on-device trace ring: record every popped event (same condition
-        # as the replay trace: popped, processed or not)
-        ring = s.ring
-        if cfg.trace_ring:
-            slot = (jnp.arange(cfg.trace_ring) == s.step % cfg.trace_ring) & live
-            ring = {
-                "step": jnp.where(slot, s.step, ring["step"]),
-                "time": jnp.where(slot, ev_time, ring["time"]),
-                "kind": jnp.where(slot, ev_kind, ring["kind"]),
-                "node": jnp.where(slot, ev_node, ring["node"]),
-                "src": jnp.where(slot, ev_src, ring["src"]),
-                "payload": jnp.where(slot[:, None], ev_payload[None, :], ring["payload"]),
-            }
-
-        # One batched draw covers the step's randomness (handler words,
-        # per-message latency draws, and whatever chaos draws this
-        # config can consume). The block layout and draw count are the
-        # versioned stream contract (ops/step_rng.py): v2 is the legacy
-        # split-chain (two threefry invocations, fixed block), v3 is
-        # counter-based off the immutable lane key and the step index
-        # (ONE threefry invocation, block sized to the enabled config).
-        layout = self._rng_layout
-        if step_block is None:
-            key, step_words, k_restart = draw_step_words(s.rng_key, s.step, layout)
-        else:
-            # megakernel path: the word block arrived from the fused
-            # Pallas pass. v3 semantics exactly — the lane key is
-            # immutable and the restart key is the block's restart
-            # slice (step_words_v3's contract).
-            step_words = step_block[0]
-            key = s.rng_key
-            if layout.restart_off is not None:
-                k_restart = step_words[layout.restart_off : layout.restart_off + 2]
+        with _xprof.scope("step.pop"):
+            if popped is None:
+                ev_time = s.eq_time[idx]
+                ev_kind = s.eq_kind[idx]
+                ev_node = s.eq_node[idx]
+                ev_src = s.eq_src[idx]
+                ev_payload = s.eq_payload[idx]
             else:
-                k_restart = jnp.zeros((2,), jnp.uint32)
-        rand_u32 = step_words[: layout.handler_words]
-        if active is not None and layout.version == RNG_STREAM_LEGACY:
-            # v2's key evolves per step — freeze it with the lane
-            # (v3's lane key is immutable, nothing to gate)
-            key = jnp.where(active, key, s.rng_key)
+                ev_time, ev_kind, ev_node, ev_src, ev_payload = popped
 
-        def timer_branch(_):
-            nodes, outbox = m.on_timer(s.nodes, ev_node, ev_payload[0], new_now, rand_u32)
-            return (nodes, outbox, s.clogged, s.killed, s.storm_loss,
-                    s.delay_spike, s.paused_until, s.skew_q10, jnp.int32(-1))
-
-        def msg_branch(_):
-            nodes, outbox = m.on_message(s.nodes, ev_node, ev_src, ev_payload, new_now, rand_u32)
-            return (nodes, outbox, s.clogged, s.killed, s.storm_loss,
-                    s.delay_spike, s.paused_until, s.skew_q10, jnp.int32(-1))
-
-        def fault_branch(_):
-            op, a, b = ev_payload[0], ev_payload[1], ev_payload[2]
-            nn = s.killed.shape[0]
-            pair_val = op == F_CLOG_PAIR
-            touch_pair = (op == F_CLOG_PAIR) | (op == F_UNCLOG_PAIR)
-            dir_val = op == F_CLOG_DIR
-            touch_dir = (op == F_CLOG_DIR) | (op == F_UNCLOG_DIR)
-            if cfg.faults.allow_heal_asym:
-                # asymmetric partition: the apply op clogs the pair both
-                # ways (pair word ops); each F_HASYM_HEAL op unclogs the
-                # single direction arg1->arg2 (the dir word ops with
-                # dir_val False), so the two heals land independently
-                pair_val = pair_val | (op == F_HASYM)
-                touch_pair = touch_pair | (op == F_HASYM)
-                touch_dir = touch_dir | (op == F_HASYM_HEAL)
-            touch_group = (op == F_CLOG_GROUP) | (op == F_UNCLOG_GROUP)
-            idxs = jnp.arange(nn)
-            # group membership: `a` carries mask bits [0, 30), `b` bits
-            # [30, 60) — nodes inside the group partition from the rest
-            in_g = jnp.where(
-                idxs < 30,
-                (a >> jnp.clip(idxs, 0, 29)) & 1,
-                (b >> jnp.clip(idxs - 30, 0, 29)) & 1,
-            ).astype(bool)
-            if cfg.clog_packed:
-                # word-wise bit ops on the two-int32 rows: each fault
-                # event touches O(N) words, not an [N, N] outer product
-                w0, w1 = s.clogged[:, 0], s.clogged[:, 1]
-
-                def apply_bit(w0, w1, row_mask, bit_lo, bit_hi, val, touch):
-                    msk = touch & row_mask
-                    nw0 = jnp.where(val, w0 | bit_lo, w0 & ~bit_lo)
-                    nw1 = jnp.where(val, w1 | bit_hi, w1 & ~bit_hi)
-                    return jnp.where(msk, nw0, w0), jnp.where(msk, nw1, w1)
-
-                a_lo, a_hi = _clog_bit_words(a)
-                b_lo, b_hi = _clog_bit_words(b)
-                # pair partition: both directions
-                w0, w1 = apply_bit(w0, w1, idxs == a, b_lo, b_hi, pair_val, touch_pair)
-                w0, w1 = apply_bit(w0, w1, idxs == b, a_lo, a_hi, pair_val, touch_pair)
-                # directional clog: a->b only (Direction parity,
-                # network.rs:108)
-                w0, w1 = apply_bit(w0, w1, idxs == a, b_lo, b_hi, dir_val, touch_dir)
-                # group partition: row i's cross-boundary links are the
-                # group complement for members, the group for outsiders
-                # (bit i lands on neither side, so self-links are clean)
-                full_lo = jnp.int32((1 << min(nn, CLOG_WORD_BITS)) - 1)
-                full_hi = jnp.int32((1 << max(nn - CLOG_WORD_BITS, 0)) - 1)
-                cross_lo = jnp.where(in_g, ~a & full_lo, a & full_lo)
-                cross_hi = jnp.where(in_g, ~b & full_hi, b & full_hi)
-                g_on = op == F_CLOG_GROUP
-                nw0 = jnp.where(g_on, w0 | cross_lo, w0 & ~cross_lo)
-                nw1 = jnp.where(g_on, w1 | cross_hi, w1 & ~cross_hi)
-                w0 = jnp.where(touch_group, nw0, w0)
-                w1 = jnp.where(touch_group, nw1, w1)
-                clogged = jnp.stack([w0, w1], axis=1)
-            else:
-                # bool-matrix oracle: outer-equality masked writes
-                clogged = jnp.where(
-                    touch_pair,
-                    set2d(set2d(s.clogged, a, b, pair_val), b, a, pair_val),
-                    s.clogged,
-                )
-                clogged = jnp.where(touch_dir, set2d(clogged, a, b, dir_val), clogged)
-                cross = in_g[:, None] != in_g[None, :]
-                clogged = jnp.where(touch_group & cross, op == F_CLOG_GROUP, clogged)
-            a_mask = jnp.arange(nn) == a
-            kill_op = op == F_KILL
-            restart_op = op == F_RESTART
-            if cfg.faults.allow_torn:
-                # a torn fault is a kill whose restart goes through the
-                # torn_spec() storage contract instead of the model hook
-                kill_op = kill_op | (op == F_TORN)
-                restart_op = restart_op | (op == F_TORN_RESTART)
-            killed = jnp.where(
-                kill_op,
-                s.killed | a_mask,
-                jnp.where(restart_op, s.killed & ~a_mask, s.killed),
-            )
-            # loss storm: `a` is the storm rate in 1/65536 units
-            storm = jnp.where(
-                op == F_LOSS_STORM,
-                a,
-                jnp.where(op == F_LOSS_END, jnp.int32(0), s.storm_loss),
-            ).astype(jnp.int32)
-            # delay-spike window toggle (buggify analogue)
-            delay = jnp.where(
-                op == F_DELAY_SPIKE,
-                jnp.int32(1),
-                jnp.where(op == F_DELAY_END, jnp.int32(0), s.delay_spike),
-            ).astype(jnp.int32)
-            # pause window: arg2 (`b`) carries the resume time the
-            # schedule derivation baked in — deferral needs no clock
-            # state beyond this per-node word
-            paused = s.paused_until
-            if cfg.faults.allow_pause:
-                paused = jnp.where(
-                    (op == F_PAUSE) & a_mask,
-                    b,
-                    jnp.where((op == F_RESUME) & a_mask, jnp.int32(0), paused),
-                ).astype(jnp.int32)
-            # clock-skew window: arg2 (`b`) is the drawn q10 factor
-            skew = s.skew_q10
-            if cfg.faults.allow_skew:
-                skew = jnp.where(
-                    (op == F_SKEW) & a_mask,
-                    b,
-                    jnp.where((op == F_SKEW_END) & a_mask, jnp.int32(0), skew),
-                ).astype(jnp.int32)
-            # cond folded into the machine's own row masks — no full-tree
-            # select here (XLA CSEs it inside the fused loop, but eager
-            # step_batch paid ~30% for it, and masked writes are strictly
-            # less work for any backend)
-            nodes = m.restart_node_if(
-                s.nodes, a, op == F_RESTART, k_restart,
-                strict=cfg.faults.strict_restart,
-            )
-            if cfg.faults.allow_torn:
-                # torn/lost-write restart: the damage seed is the fault
-                # payload's schedule-drawn mask (b) salted by this
-                # step's torn RNG word — bit-deterministic on replay
-                torn_seed = b.astype(jnp.uint32) ^ step_words[layout.torn_off]
-                nodes = m.torn_restart_if(
-                    nodes, a, op == F_TORN_RESTART, k_restart, torn_seed
-                )
-            boot_node = jnp.where(restart_op, a, jnp.int32(-1))
-            return (nodes, m.empty_outbox(), clogged, killed, storm, delay,
-                    paused, skew, boot_node)
-
-        (nodes, outbox, clogged, killed, storm_loss, delay_spike,
-         paused_until, skew_q10, boot_node) = lax.switch(
-            ev_kind, [timer_branch, msg_branch, fault_branch], None
-        )
-
-        # Killed nodes process nothing (reference: killed node's tasks are
-        # dropped); fault events always apply. Deferred events (pause
-        # windows) are not processed either — they re-deliver at resume.
-        is_handler = ev_kind != EV_FAULT
-        effective = process & (node_alive | ~is_handler)
-        if defer is not None:
-            effective = effective & ~defer
-        nodes = tree_where(effective, nodes, s.nodes)
-        clogged = jnp.where(effective, clogged, s.clogged)
-        killed = jnp.where(effective, killed, s.killed)
-        storm_loss = jnp.where(effective, storm_loss, s.storm_loss)
-        delay_spike = jnp.where(effective, delay_spike, s.delay_spike)
-        if cfg.faults.allow_pause:
-            paused_until = jnp.where(effective, paused_until, s.paused_until)
-        else:
-            paused_until = s.paused_until
-        if cfg.faults.allow_skew:
-            skew_q10 = jnp.where(effective, skew_q10, s.skew_q10)
-        else:
-            skew_q10 = s.skew_q10
-        outbox_valid_msgs = outbox.msg_valid & effective
-        outbox_valid_timers = outbox.timer_valid & effective
-
-        # -- causal provenance fold (gate-off adds NO ops) ------------------
-        # A processed handler event folds its lineage into the handling
-        # node; a processed fault event plants its word on the nodes it
-        # touches — both endpoints for pair/dir/heal ops, node `a` for
-        # node ops (kill/restart/pause/skew/torn), every node for the
-        # global window/group ops (a loss storm touches every link; the
-        # over-approximation is the documented contract). Everything the
-        # node emits afterwards (messages, timers, the restart boot)
-        # inherits the node's updated word.
-        if cfg.provenance:
-            nn_p = s.killed.shape[0]
-            idxs_p = jnp.arange(nn_p)
-            p_op = ev_payload[0]
-            is_fault_ev = ev_kind == EV_FAULT
-            prov_pair_ops = (
-                (p_op == F_CLOG_PAIR) | (p_op == F_UNCLOG_PAIR)
-                | (p_op == F_CLOG_DIR) | (p_op == F_UNCLOG_DIR)
-            )
-            if cfg.faults.allow_heal_asym:
-                prov_pair_ops = prov_pair_ops | (p_op == F_HASYM) | (p_op == F_HASYM_HEAL)
-            prov_global_ops = (
-                (p_op == F_CLOG_GROUP) | (p_op == F_UNCLOG_GROUP)
-                | (p_op == F_LOSS_STORM) | (p_op == F_LOSS_END)
-                | (p_op == F_DELAY_SPIKE) | (p_op == F_DELAY_END)
-            )
-            touched = jnp.where(
-                is_fault_ev,
-                prov_global_ops
-                | (prov_pair_ops & ((idxs_p == ev_payload[1]) | (idxs_p == ev_payload[2])))
-                | (~prov_global_ops & ~prov_pair_ops & (idxs_p == ev_payload[1])),
-                idxs_p == ev_node,
-            )
-            add_word = ev_prov
-            if cfg.faults.strict_restart:
-                # a crash-with-amnesia wipe is its own attribution
-                # channel (bit 30): it has no schedule slot of its own
-                add_word = jnp.where(
-                    is_fault_ev & (p_op == F_RESTART),
-                    ev_prov | jnp.uint32(1 << PROV_BIT_AMNESIA),
-                    ev_prov,
-                )
-            node_prov = jnp.where(
-                touched & effective, s.node_prov | add_word, s.node_prov
-            )
-            # the word every push below inherits (fault events push only
-            # the restart boot timer, whose node is ev_node == a)
-            sender_prov = node_prov[ev_node]
-        else:
-            node_prov = s.node_prov
-            sender_prov = None
-
-        # -- push outbox messages with chaos (latency / loss / clog) --------
-        eq = {
-            "time": s.eq_time,
-            "seq": s.eq_seq,
-            "kind": s.eq_kind,
-            "node": s.eq_node,
-            "src": s.eq_src,
-            "payload": s.eq_payload,
-            "valid": eq_valid,
-        }
-        if cfg.provenance:
-            eq["prov"] = s.eq_prov
-        if defer is not None:
-            # deferred delivery: rewrite the (still-valid) popped slot's
-            # time to the node's resume point. Seq is untouched — at the
-            # resume instant `paused_until > now` is already false, so
-            # the event delivers regardless of its order relative to the
-            # F_RESUME event, and same-time deferred events keep their
-            # original relative order. No free slot is consumed, so
-            # deferral can never overflow the queue.
-            defer_slot = (jnp.arange(s.eq_valid.shape[0]) == idx) & defer
-            eq["time"] = jnp.where(defer_slot, node_resume_us, eq["time"])
             if cfg.provenance:
-                # the deferral is caused by the pause window: the target
-                # node's word (which carries the pause fault's bit since
-                # the F_PAUSE apply touched it) folds into the deferred
-                # event's lineage
-                eq["prov"] = jnp.where(
-                    defer_slot, eq["prov"] | s.node_prov[ev_node], eq["prov"]
-                )
-        next_seq = s.next_seq
-        failed = s.failed
-        fail_code = s.fail_code
-        msg_count = s.msg_count
+                # the popped event's lineage word (fault slots carry their
+                # bit from init; messages/timers carry their sender's word)
+                ev_prov = s.eq_prov[idx]
 
-        lat_span = max(1, cfg.latency_max_us - cfg.latency_min_us)
-        lat_bits = step_words[layout.lat_off : layout.lat_off + m.MAX_MSGS]
-        # Sections that are statically inert for this (config, machine)
-        # pair cost nothing: v3 doesn't even draw them; v2 draws them
-        # (the legacy block is part of the stream contract) but the
-        # consuming compute is elided — with loss_rate == 0 and storms
-        # unreachable the drop compare is constant-False, so eliding it
-        # is result-preserving in both versions.
-        if layout.loss_active:
-            drop_bits = step_words[layout.drop_off : layout.drop_off + m.MAX_MSGS]
-            # static config loss + active storm (storm rate 65535 ~= drop
-            # all), saturating at u32 max
-            base_threshold = jnp.uint32(int(cfg.packet_loss_rate * 0xFFFFFFFF))
-            storm_threshold = storm_loss.astype(jnp.uint32) * jnp.uint32(65537)
-            summed = base_threshold + storm_threshold
-            loss_threshold = jnp.where(
-                summed < storm_threshold, jnp.uint32(0xFFFFFFFF), summed
-            )
-        if layout.spike_active:
-            # spike gate + magnitude are INDEPENDENT words: conditioning
-            # the magnitude on the gate's sub-threshold bits would cap the
-            # extra latency at ~2.7 s instead of the documented 1-5 s
-            spike_bits = step_words[layout.spike_off : layout.spike_off + m.MAX_MSGS]
-            spike_mag_bits = step_words[
-                layout.spike_off + m.MAX_MSGS : layout.spike_off + 2 * m.MAX_MSGS
-            ]
-        if layout.dup_active:
-            # duplication gate + fresh-latency words (tail section of
-            # the block — recorded streams are untouched with dup off)
-            dup_bits = step_words[layout.dup_off : layout.dup_off + m.MAX_MSGS]
-            dup_lat_bits = step_words[
-                layout.dup_off + m.MAX_MSGS : layout.dup_off + 2 * m.MAX_MSGS
-            ]
-            n_dups = jnp.int32(0)
-        # the handling node's outbound clog row, read ONCE (pre-fault
-        # state, matching the unpacked path's s.clogged[ev_node, dst])
-        # and expanded to bool[N] so each message pays the same tiny
-        # gather as the bool-matrix path, not a shift/mask per slot
-        if cfg.clog_packed:
-            clog_row_bool = _clog_row_bools(s.clogged[ev_node], s.killed.shape[0])
-
-        for mi in range(m.MAX_MSGS):
-            want = outbox_valid_msgs[mi]
-            dst = outbox.msg_dst[mi]
-            if cfg.clog_packed:
-                blocked = clog_row_bool[dst]
-            else:
-                blocked = s.clogged[ev_node, dst]
-            if layout.loss_active:
-                blocked = blocked | (drop_bits[mi] < loss_threshold)
-            do_push = want & ~blocked
-            latency = jnp.int32(cfg.latency_min_us) + (
-                lat_bits[mi] % jnp.uint32(lat_span)
-            ).astype(jnp.int32)
-            if layout.spike_active:
-                # delay-spike window: ~10% of sends take +1-5 virtual s
-                # (the host buggify's numbers); the draws are consumed
-                # every step so windows don't perturb the stream shape
-                spiked = (delay_spike > 0) & (spike_bits[mi] < jnp.uint32(DELAY_PROB_U32))
-                extra = jnp.int32(DELAY_EXTRA_MIN_US) + (
-                    spike_mag_bits[mi] % jnp.uint32(DELAY_EXTRA_SPAN_US)
-                ).astype(jnp.int32)
-                latency = latency + jnp.where(spiked, extra, 0)
-            slot, has_free = find_free_slot(eq["valid"])
-            overflow = do_push & ~has_free
-            failed = failed | overflow
-            fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
-            do_push = do_push & has_free
-            eq = _push(
-                eq, slot, do_push, new_now + latency, next_seq, EV_MSG, dst,
-                ev_node, outbox.msg_payload[mi], prov=sender_prov,
-            )
-            next_seq = next_seq + jnp.where(do_push, 1, 0)
-            msg_count = msg_count + jnp.where(do_push, 1, 0)
-            if layout.dup_active:
-                # Bernoulli duplicate of a successfully pushed message,
-                # re-enqueued with an independently drawn latency (the
-                # idempotency chaos loss-only vocabularies can't
-                # express). Same overflow accounting as any push.
-                want_dup = do_push & (dup_bits[mi] < jnp.uint32(DUP_PROB_U32))
-                dslot, dfree = find_free_slot(eq["valid"])
-                doverflow = want_dup & ~dfree
-                failed = failed | doverflow
-                fail_code = jnp.where(doverflow, jnp.int32(OVERFLOW), fail_code)
-                want_dup = want_dup & dfree
-                dup_latency = jnp.int32(cfg.latency_min_us) + (
-                    dup_lat_bits[mi] % jnp.uint32(lat_span)
-                ).astype(jnp.int32)
-                eq = _push(
-                    eq, dslot, want_dup, new_now + dup_latency, next_seq,
-                    EV_MSG, dst, ev_node, outbox.msg_payload[mi],
-                    # the duplicate copy carries the dup attribution bit:
-                    # a violation whose lineage includes it names `dup`
-                    prov=(
-                        sender_prov | jnp.uint32(1 << PROV_BIT_DUP)
-                        if sender_prov is not None else None
-                    ),
-                )
-                next_seq = next_seq + jnp.where(want_dup, 1, 0)
-                msg_count = msg_count + jnp.where(want_dup, 1, 0)
-                n_dups = n_dups + want_dup.astype(jnp.int32)
-
-        # -- push timers (for the handling node) ----------------------------
-        slot0 = jnp.arange(m.PAYLOAD_WIDTH) == 0
-        if cfg.faults.allow_skew:
-            # clock-skew window: the handling node's armed timers are
-            # stretched/compressed by its active q10 factor (read from
-            # the pre-step state — handler events never change skew, and
-            # fault events arm no timers, so pre == post here)
-            node_skew_q10 = s.skew_q10[ev_node]
-        for ti in range(m.MAX_TIMERS):
-            want = outbox_valid_timers[ti]
-            slot, has_free = find_free_slot(eq["valid"])
-            overflow = want & ~has_free
-            failed = failed | overflow
-            fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
-            want = want & has_free
-            tpay = jnp.where(slot0, outbox.timer_id[ti], 0).astype(jnp.int32)
-            t_delay = outbox.timer_delay_us[ti]
-            if cfg.faults.allow_skew:
-                t_delay = jnp.where(
-                    node_skew_q10 > 0,
-                    skew_scale_us(t_delay, node_skew_q10),
-                    t_delay,
-                )
-            eq = _push(
-                eq, slot, want, new_now + t_delay, next_seq,
-                EV_TIMER, ev_node, jnp.int32(-1), tpay, prov=sender_prov,
-            )
-            next_seq = next_seq + jnp.where(want, 1, 0)
-
-        # -- restart boot timer ---------------------------------------------
-        want_boot = effective & (boot_node >= 0)
-        slot, has_free = find_free_slot(eq["valid"])
-        boot_overflow = want_boot & ~has_free
-        failed = failed | boot_overflow
-        fail_code = jnp.where(boot_overflow, jnp.int32(OVERFLOW), fail_code)
-        want_boot = want_boot & has_free
-        boot_pay = jnp.zeros((m.PAYLOAD_WIDTH,), jnp.int32)  # BOOT == 0
-        eq = _push(
-            eq, slot, want_boot, new_now, next_seq, EV_TIMER, boot_node,
-            jnp.int32(-1), boot_pay, prov=sender_prov,
-        )
-        next_seq = next_seq + jnp.where(want_boot, 1, 0)
-
-        # -- flight recorder (observability; gate-off adds NO ops) ----------
-        fr = s.fr
-        if cfg.flight_recorder:
-            stepped = jnp.bool_(True) if active is None else active
-            new_step = s.step + stepped.astype(jnp.int32)
-            # digest: fold the popped tuple + the step's whole RNG word
-            # block — exactly the inputs that determine this step — on
-            # every step that pops an event (same condition as the trace
-            # ring / replay trace). The megakernel hands the fold in
-            # pre-computed (same words, same order, same math — the
-            # fused pass runs the identical chain in VMEM).
-            if step_block is not None and len(step_block) == 3:
-                nd0, nd1 = step_block[1], step_block[2]
-            else:
-                nd0, nd1 = digest_fold(
-                    fr["d0"],
-                    fr["d1"],
-                    [ev_time, ev_kind, ev_node, ev_src]
-                    + [ev_payload[i] for i in range(m.PAYLOAD_WIDTH)]
-                    + [step_words[i] for i in range(layout.total_words)],
-                )
-            d0 = jnp.where(live, nd0, fr["d0"])
-            d1 = jnp.where(live, nd1, fr["d1"])
-            # checkpoint ring: every `fr_digest_every`-th step the lane
-            # actually executes lands (step, d0, d1) in slot
-            # (step/every - 1) % ring — the host decodes by sorting on
-            # step. Condition is "the step counter crossed a multiple",
-            # not "popped": the audit's host-side trail reads the digest
-            # at exact step multiples and must see the same checkpoints.
-            every, rr = cfg.fr_digest_every, cfg.fr_digest_ring
-            want_ck = stepped & (new_step % every == 0)
-            ck_slot = ((new_step // every - 1) % rr == jnp.arange(rr)) & want_ck
-            # fault-injection counters: one per FaultPlan kind, counted
-            # when an APPLY op (even payload[0]) is processed
-            is_inj = process & (ev_kind == EV_FAULT) & (ev_payload[0] % 2 == 0)
-            kind_idx = ev_payload[0] // 2
-            inj = fr["inj"] + (
-                (jnp.arange(len(FAULT_KIND_NAMES)) == kind_idx) & is_inj
-            ).astype(jnp.int32)
-            # non-scheduled chaos counters: duplicates pushed this step,
-            # crash-with-amnesia wipes applied (strict restarts)
-            fr_dup = fr["dup"]
-            if layout.dup_active:
-                fr_dup = fr_dup + n_dups
-            fr_amnesia = fr["amnesia"]
-            if cfg.faults.strict_restart:
-                fr_amnesia = fr_amnesia + (
-                    process & (ev_kind == EV_FAULT) & (ev_payload[0] == F_RESTART)
-                ).astype(jnp.int32)
-            # occupancy high-water marks on the post-step state (frozen
-            # lanes' state is unchanged, so their marks are stable).
-            # Queue occupancy is tracked INCREMENTALLY: the pop clears
-            # exactly one valid slot (when live and not deferred) and
-            # every successful push — messages, duplicates, timers, the
-            # restart boot — fills exactly one free slot and bumped
-            # next_seq, so the delta is (next_seq' - next_seq) minus the
-            # pop. Replaces a [Q]-wide re-sum of eq["valid"] per event
-            # with three scalar ops; equal to the old sum by
-            # construction (host-oracle differential asserts it).
-            popped_one = live if defer is None else (live & ~defer)
-            eq_n = (
-                fr["eq_n"]
-                - popped_one.astype(jnp.int32)
-                + (next_seq - s.next_seq)
-            )
-            n_clog = (
-                lax.population_count(clogged).sum()
-                if cfg.clog_packed
-                else clogged.sum()
-            ).astype(jnp.int32)
-            fr = {
-                "d0": d0,
-                "d1": d1,
-                "eq_n": eq_n,
-                "ck_step": jnp.where(ck_slot, new_step, fr["ck_step"]),
-                "ck_d0": jnp.where(ck_slot, d0, fr["ck_d0"]),
-                "ck_d1": jnp.where(ck_slot, d1, fr["ck_d1"]),
-                "inj": inj,
-                "dup": fr_dup,
-                "amnesia": fr_amnesia,
-                "q_hwm": jnp.maximum(fr["q_hwm"], eq_n),
-                "clog_hwm": jnp.maximum(fr["clog_hwm"], n_clog),
-                "kill_hwm": jnp.maximum(
-                    fr["kill_hwm"], killed.sum().astype(jnp.int32)
-                ),
-            }
-
-        # -- scenario coverage (observability; gate-off adds NO ops) --------
-        cov = s.cov
-        if cfg.coverage:
-            # abstract-state projection of the POST-step state: the
-            # scenario this event's processing REACHED (the model
-            # contract: Machine.coverage_projection, low 3 bits = its
-            # coarsest "phase" notion)
-            abs_word = m.coverage_projection(nodes, new_now)
-            # fault-environment context: killed count + active chaos
-            # windows — the same abstract state under partition vs storm
-            # is a different scenario
-            n_killed = jnp.clip(killed.sum().astype(jnp.int32), 0, 7)
-            clog_any = jnp.any(clogged != 0)
-            ctx = (
-                n_killed
-                | (clog_any.astype(jnp.int32) << 3)
-                | ((storm_loss > 0).astype(jnp.int32) << 4)
-                | ((delay_spike > 0).astype(jnp.int32) << 5)
-            )
-            # new chaos windows extend the context word only when their
-            # kind is enabled — legacy configs hash identical inputs
+            new_now = jnp.maximum(s.now_us, ev_time)
+            hz = cfg.horizon_us if horizon_us is None else horizon_us
+            # `live` = this lane pops an event this step (frozen lanes never
+            # do; their popped tuple is junk-but-deterministic and every use
+            # below is gated on live/process/effective)
+            live = any_valid if active is None else any_valid & active
+            horizon_hit = live & (new_now >= hz)
+            process = live & ~horizon_hit
+            node_alive = ~s.killed[ev_node]
+            # pause windows: a handler event targeting a paused (alive) node
+            # is DEFERRED — the popped slot stays valid and only its time
+            # moves to the node's resume point (the state survives, nothing
+            # is processed, nothing is dropped). Kill still dominates: a
+            # dead node's events are consumed as before. The deferred pop
+            # itself is a popped event (trace ring / digest / coverage see
+            # it) — host replay pops it identically, so the contract holds.
             if cfg.faults.allow_pause:
-                ctx = ctx | (jnp.any(paused_until > 0).astype(jnp.int32) << 6)
-            if cfg.faults.allow_skew:
-                ctx = ctx | (jnp.any(skew_q10 > 0).astype(jnp.int32) << 7)
-            # event discriminant: payload[0] for msg (message type) and
-            # fault (op) events; timers fold 0 — timer ids are
-            # epoch-encoded, and counting every restart epoch as a new
-            # scenario would inflate the map
-            op_word = jnp.where(ev_kind == EV_TIMER, jnp.int32(0), ev_payload[0])
-            band = cov_band(ev_kind, op_word, self.cov_band_bits)
-            if cfg.faults.strict_restart:
-                # a strict restart is a different scenario class than a
-                # plain kill/restart: route it to the amnesia band
-                band = jnp.where(
-                    (ev_kind == EV_FAULT) & (ev_payload[0] == F_RESTART),
-                    jnp.int32(COV_BAND_AMNESIA),
-                    band,
+                node_resume_us = s.paused_until[ev_node]
+                defer = (
+                    process
+                    & (ev_kind != EV_FAULT)
+                    & node_alive
+                    & (node_resume_us > new_now)
                 )
-            slot = cov_slot(
-                abs_word, ev_kind, ev_node, op_word, ctx, cfg.cov_slots_log2,
-                band_bits=self.cov_band_bits, band=band,
-            )
-            # same condition as the trace ring / digest: popped events.
-            # Buffered regime (cov_buffer > 0): append the slot index to
-            # the tiny per-lane ring instead of scattering into the
-            # 2 KiB map — the map never appears in the step program;
-            # run_segment folds the buffer at the flush cadence, at
-            # segment exit, and therefore at every freeze point. OR is
-            # commutative + idempotent, so the final map is
-            # bit-identical to the per-event fold (the cov_buffer=0
-            # oracle; tests/test_coverage.py differentials).
-            if self._cov_buffered:
-                buf, buf_n = cov_push(cov["buf"], cov["buf_n"], slot, live)
-                cov = dict(cov, buf=buf, buf_n=buf_n)
             else:
-                cov = {"map": cov_fold(cov["map"], slot, live)}
-            if layout.dup_active:
-                # synthetic dup band: a step that enqueued >= 1 duplicate
-                # is its own scenario class (one extra word fold, only
-                # when the gate is on)
-                dup_slot = cov_slot(
-                    abs_word, ev_kind, ev_node, n_dups, ctx,
-                    cfg.cov_slots_log2, band_bits=self.cov_band_bits,
-                    band=jnp.int32(COV_BAND_DUP),
-                )
-                dup_hit = live & (n_dups > 0)
-                if self._cov_buffered:
-                    buf, buf_n = cov_push(
-                        cov["buf"], cov["buf_n"], dup_slot, dup_hit
+                defer = None
+            pop_mask = (jnp.arange(s.eq_valid.shape[0]) == idx) & live
+            if defer is not None:
+                pop_mask = pop_mask & ~defer
+            eq_valid = s.eq_valid & ~pop_mask
+
+        with _xprof.scope("step.recorder"):
+            # on-device trace ring: record every popped event (same condition
+            # as the replay trace: popped, processed or not)
+            ring = s.ring
+            if cfg.trace_ring:
+                slot = (jnp.arange(cfg.trace_ring) == s.step % cfg.trace_ring) & live
+                ring = {
+                    "step": jnp.where(slot, s.step, ring["step"]),
+                    "time": jnp.where(slot, ev_time, ring["time"]),
+                    "kind": jnp.where(slot, ev_kind, ring["kind"]),
+                    "node": jnp.where(slot, ev_node, ring["node"]),
+                    "src": jnp.where(slot, ev_src, ring["src"]),
+                    "payload": jnp.where(slot[:, None], ev_payload[None, :], ring["payload"]),
+                }
+
+        with _xprof.scope("step.rng"):
+            # One batched draw covers the step's randomness (handler words,
+            # per-message latency draws, and whatever chaos draws this
+            # config can consume). The block layout and draw count are the
+            # versioned stream contract (ops/step_rng.py): v2 is the legacy
+            # split-chain (two threefry invocations, fixed block), v3 is
+            # counter-based off the immutable lane key and the step index
+            # (ONE threefry invocation, block sized to the enabled config).
+            layout = self._rng_layout
+            if step_block is None:
+                key, step_words, k_restart = draw_step_words(s.rng_key, s.step, layout)
+            else:
+                # megakernel path: the word block arrived from the fused
+                # Pallas pass. v3 semantics exactly — the lane key is
+                # immutable and the restart key is the block's restart
+                # slice (step_words_v3's contract).
+                step_words = step_block[0]
+                key = s.rng_key
+                if layout.restart_off is not None:
+                    k_restart = step_words[layout.restart_off : layout.restart_off + 2]
+                else:
+                    k_restart = jnp.zeros((2,), jnp.uint32)
+            rand_u32 = step_words[: layout.handler_words]
+            if active is not None and layout.version == RNG_STREAM_LEGACY:
+                # v2's key evolves per step — freeze it with the lane
+                # (v3's lane key is immutable, nothing to gate)
+                key = jnp.where(active, key, s.rng_key)
+
+        with _xprof.scope("step.handlers"):
+            def timer_branch(_):
+                nodes, outbox = m.on_timer(s.nodes, ev_node, ev_payload[0], new_now, rand_u32)
+                return (nodes, outbox, s.clogged, s.killed, s.storm_loss,
+                        s.delay_spike, s.paused_until, s.skew_q10, jnp.int32(-1))
+
+            def msg_branch(_):
+                nodes, outbox = m.on_message(s.nodes, ev_node, ev_src, ev_payload, new_now, rand_u32)
+                return (nodes, outbox, s.clogged, s.killed, s.storm_loss,
+                        s.delay_spike, s.paused_until, s.skew_q10, jnp.int32(-1))
+
+            def fault_branch(_):
+                op, a, b = ev_payload[0], ev_payload[1], ev_payload[2]
+                nn = s.killed.shape[0]
+                pair_val = op == F_CLOG_PAIR
+                touch_pair = (op == F_CLOG_PAIR) | (op == F_UNCLOG_PAIR)
+                dir_val = op == F_CLOG_DIR
+                touch_dir = (op == F_CLOG_DIR) | (op == F_UNCLOG_DIR)
+                if cfg.faults.allow_heal_asym:
+                    # asymmetric partition: the apply op clogs the pair both
+                    # ways (pair word ops); each F_HASYM_HEAL op unclogs the
+                    # single direction arg1->arg2 (the dir word ops with
+                    # dir_val False), so the two heals land independently
+                    pair_val = pair_val | (op == F_HASYM)
+                    touch_pair = touch_pair | (op == F_HASYM)
+                    touch_dir = touch_dir | (op == F_HASYM_HEAL)
+                touch_group = (op == F_CLOG_GROUP) | (op == F_UNCLOG_GROUP)
+                idxs = jnp.arange(nn)
+                # group membership: `a` carries mask bits [0, 30), `b` bits
+                # [30, 60) — nodes inside the group partition from the rest
+                in_g = jnp.where(
+                    idxs < 30,
+                    (a >> jnp.clip(idxs, 0, 29)) & 1,
+                    (b >> jnp.clip(idxs - 30, 0, 29)) & 1,
+                ).astype(bool)
+                if cfg.clog_packed:
+                    # word-wise bit ops on the two-int32 rows: each fault
+                    # event touches O(N) words, not an [N, N] outer product
+                    w0, w1 = s.clogged[:, 0], s.clogged[:, 1]
+
+                    def apply_bit(w0, w1, row_mask, bit_lo, bit_hi, val, touch):
+                        msk = touch & row_mask
+                        nw0 = jnp.where(val, w0 | bit_lo, w0 & ~bit_lo)
+                        nw1 = jnp.where(val, w1 | bit_hi, w1 & ~bit_hi)
+                        return jnp.where(msk, nw0, w0), jnp.where(msk, nw1, w1)
+
+                    a_lo, a_hi = _clog_bit_words(a)
+                    b_lo, b_hi = _clog_bit_words(b)
+                    # pair partition: both directions
+                    w0, w1 = apply_bit(w0, w1, idxs == a, b_lo, b_hi, pair_val, touch_pair)
+                    w0, w1 = apply_bit(w0, w1, idxs == b, a_lo, a_hi, pair_val, touch_pair)
+                    # directional clog: a->b only (Direction parity,
+                    # network.rs:108)
+                    w0, w1 = apply_bit(w0, w1, idxs == a, b_lo, b_hi, dir_val, touch_dir)
+                    # group partition: row i's cross-boundary links are the
+                    # group complement for members, the group for outsiders
+                    # (bit i lands on neither side, so self-links are clean)
+                    full_lo = jnp.int32((1 << min(nn, CLOG_WORD_BITS)) - 1)
+                    full_hi = jnp.int32((1 << max(nn - CLOG_WORD_BITS, 0)) - 1)
+                    cross_lo = jnp.where(in_g, ~a & full_lo, a & full_lo)
+                    cross_hi = jnp.where(in_g, ~b & full_hi, b & full_hi)
+                    g_on = op == F_CLOG_GROUP
+                    nw0 = jnp.where(g_on, w0 | cross_lo, w0 & ~cross_lo)
+                    nw1 = jnp.where(g_on, w1 | cross_hi, w1 & ~cross_hi)
+                    w0 = jnp.where(touch_group, nw0, w0)
+                    w1 = jnp.where(touch_group, nw1, w1)
+                    clogged = jnp.stack([w0, w1], axis=1)
+                else:
+                    # bool-matrix oracle: outer-equality masked writes
+                    clogged = jnp.where(
+                        touch_pair,
+                        set2d(set2d(s.clogged, a, b, pair_val), b, a, pair_val),
+                        s.clogged,
                     )
+                    clogged = jnp.where(touch_dir, set2d(clogged, a, b, dir_val), clogged)
+                    cross = in_g[:, None] != in_g[None, :]
+                    clogged = jnp.where(touch_group & cross, op == F_CLOG_GROUP, clogged)
+                a_mask = jnp.arange(nn) == a
+                kill_op = op == F_KILL
+                restart_op = op == F_RESTART
+                if cfg.faults.allow_torn:
+                    # a torn fault is a kill whose restart goes through the
+                    # torn_spec() storage contract instead of the model hook
+                    kill_op = kill_op | (op == F_TORN)
+                    restart_op = restart_op | (op == F_TORN_RESTART)
+                killed = jnp.where(
+                    kill_op,
+                    s.killed | a_mask,
+                    jnp.where(restart_op, s.killed & ~a_mask, s.killed),
+                )
+                # loss storm: `a` is the storm rate in 1/65536 units
+                storm = jnp.where(
+                    op == F_LOSS_STORM,
+                    a,
+                    jnp.where(op == F_LOSS_END, jnp.int32(0), s.storm_loss),
+                ).astype(jnp.int32)
+                # delay-spike window toggle (buggify analogue)
+                delay = jnp.where(
+                    op == F_DELAY_SPIKE,
+                    jnp.int32(1),
+                    jnp.where(op == F_DELAY_END, jnp.int32(0), s.delay_spike),
+                ).astype(jnp.int32)
+                # pause window: arg2 (`b`) carries the resume time the
+                # schedule derivation baked in — deferral needs no clock
+                # state beyond this per-node word
+                paused = s.paused_until
+                if cfg.faults.allow_pause:
+                    paused = jnp.where(
+                        (op == F_PAUSE) & a_mask,
+                        b,
+                        jnp.where((op == F_RESUME) & a_mask, jnp.int32(0), paused),
+                    ).astype(jnp.int32)
+                # clock-skew window: arg2 (`b`) is the drawn q10 factor
+                skew = s.skew_q10
+                if cfg.faults.allow_skew:
+                    skew = jnp.where(
+                        (op == F_SKEW) & a_mask,
+                        b,
+                        jnp.where((op == F_SKEW_END) & a_mask, jnp.int32(0), skew),
+                    ).astype(jnp.int32)
+                # cond folded into the machine's own row masks — no full-tree
+                # select here (XLA CSEs it inside the fused loop, but eager
+                # step_batch paid ~30% for it, and masked writes are strictly
+                # less work for any backend)
+                nodes = m.restart_node_if(
+                    s.nodes, a, op == F_RESTART, k_restart,
+                    strict=cfg.faults.strict_restart,
+                )
+                if cfg.faults.allow_torn:
+                    # torn/lost-write restart: the damage seed is the fault
+                    # payload's schedule-drawn mask (b) salted by this
+                    # step's torn RNG word — bit-deterministic on replay
+                    torn_seed = b.astype(jnp.uint32) ^ step_words[layout.torn_off]
+                    nodes = m.torn_restart_if(
+                        nodes, a, op == F_TORN_RESTART, k_restart, torn_seed
+                    )
+                boot_node = jnp.where(restart_op, a, jnp.int32(-1))
+                return (nodes, m.empty_outbox(), clogged, killed, storm, delay,
+                        paused, skew, boot_node)
+
+            (nodes, outbox, clogged, killed, storm_loss, delay_spike,
+             paused_until, skew_q10, boot_node) = lax.switch(
+                ev_kind, [timer_branch, msg_branch, fault_branch], None
+            )
+
+            # Killed nodes process nothing (reference: killed node's tasks are
+            # dropped); fault events always apply. Deferred events (pause
+            # windows) are not processed either — they re-deliver at resume.
+            is_handler = ev_kind != EV_FAULT
+            effective = process & (node_alive | ~is_handler)
+            if defer is not None:
+                effective = effective & ~defer
+            nodes = tree_where(effective, nodes, s.nodes)
+            clogged = jnp.where(effective, clogged, s.clogged)
+            killed = jnp.where(effective, killed, s.killed)
+            storm_loss = jnp.where(effective, storm_loss, s.storm_loss)
+            delay_spike = jnp.where(effective, delay_spike, s.delay_spike)
+            if cfg.faults.allow_pause:
+                paused_until = jnp.where(effective, paused_until, s.paused_until)
+            else:
+                paused_until = s.paused_until
+            if cfg.faults.allow_skew:
+                skew_q10 = jnp.where(effective, skew_q10, s.skew_q10)
+            else:
+                skew_q10 = s.skew_q10
+            outbox_valid_msgs = outbox.msg_valid & effective
+            outbox_valid_timers = outbox.timer_valid & effective
+
+        with _xprof.scope("step.provenance"):
+            # -- causal provenance fold (gate-off adds NO ops) ------------------
+            # A processed handler event folds its lineage into the handling
+            # node; a processed fault event plants its word on the nodes it
+            # touches — both endpoints for pair/dir/heal ops, node `a` for
+            # node ops (kill/restart/pause/skew/torn), every node for the
+            # global window/group ops (a loss storm touches every link; the
+            # over-approximation is the documented contract). Everything the
+            # node emits afterwards (messages, timers, the restart boot)
+            # inherits the node's updated word.
+            if cfg.provenance:
+                nn_p = s.killed.shape[0]
+                idxs_p = jnp.arange(nn_p)
+                p_op = ev_payload[0]
+                is_fault_ev = ev_kind == EV_FAULT
+                prov_pair_ops = (
+                    (p_op == F_CLOG_PAIR) | (p_op == F_UNCLOG_PAIR)
+                    | (p_op == F_CLOG_DIR) | (p_op == F_UNCLOG_DIR)
+                )
+                if cfg.faults.allow_heal_asym:
+                    prov_pair_ops = prov_pair_ops | (p_op == F_HASYM) | (p_op == F_HASYM_HEAL)
+                prov_global_ops = (
+                    (p_op == F_CLOG_GROUP) | (p_op == F_UNCLOG_GROUP)
+                    | (p_op == F_LOSS_STORM) | (p_op == F_LOSS_END)
+                    | (p_op == F_DELAY_SPIKE) | (p_op == F_DELAY_END)
+                )
+                touched = jnp.where(
+                    is_fault_ev,
+                    prov_global_ops
+                    | (prov_pair_ops & ((idxs_p == ev_payload[1]) | (idxs_p == ev_payload[2])))
+                    | (~prov_global_ops & ~prov_pair_ops & (idxs_p == ev_payload[1])),
+                    idxs_p == ev_node,
+                )
+                add_word = ev_prov
+                if cfg.faults.strict_restart:
+                    # a crash-with-amnesia wipe is its own attribution
+                    # channel (bit 30): it has no schedule slot of its own
+                    add_word = jnp.where(
+                        is_fault_ev & (p_op == F_RESTART),
+                        ev_prov | jnp.uint32(1 << PROV_BIT_AMNESIA),
+                        ev_prov,
+                    )
+                node_prov = jnp.where(
+                    touched & effective, s.node_prov | add_word, s.node_prov
+                )
+                # the word every push below inherits (fault events push only
+                # the restart boot timer, whose node is ev_node == a)
+                sender_prov = node_prov[ev_node]
+            else:
+                node_prov = s.node_prov
+                sender_prov = None
+
+        with _xprof.scope("step.outbox"):
+            # -- push outbox messages with chaos (latency / loss / clog) --------
+            eq = {
+                "time": s.eq_time,
+                "seq": s.eq_seq,
+                "kind": s.eq_kind,
+                "node": s.eq_node,
+                "src": s.eq_src,
+                "payload": s.eq_payload,
+                "valid": eq_valid,
+            }
+            if cfg.provenance:
+                eq["prov"] = s.eq_prov
+            if defer is not None:
+                # deferred delivery: rewrite the (still-valid) popped slot's
+                # time to the node's resume point. Seq is untouched — at the
+                # resume instant `paused_until > now` is already false, so
+                # the event delivers regardless of its order relative to the
+                # F_RESUME event, and same-time deferred events keep their
+                # original relative order. No free slot is consumed, so
+                # deferral can never overflow the queue.
+                defer_slot = (jnp.arange(s.eq_valid.shape[0]) == idx) & defer
+                eq["time"] = jnp.where(defer_slot, node_resume_us, eq["time"])
+                if cfg.provenance:
+                    # the deferral is caused by the pause window: the target
+                    # node's word (which carries the pause fault's bit since
+                    # the F_PAUSE apply touched it) folds into the deferred
+                    # event's lineage
+                    eq["prov"] = jnp.where(
+                        defer_slot, eq["prov"] | s.node_prov[ev_node], eq["prov"]
+                    )
+            next_seq = s.next_seq
+            failed = s.failed
+            fail_code = s.fail_code
+            msg_count = s.msg_count
+
+            lat_span = max(1, cfg.latency_max_us - cfg.latency_min_us)
+            lat_bits = step_words[layout.lat_off : layout.lat_off + m.MAX_MSGS]
+            # Sections that are statically inert for this (config, machine)
+            # pair cost nothing: v3 doesn't even draw them; v2 draws them
+            # (the legacy block is part of the stream contract) but the
+            # consuming compute is elided — with loss_rate == 0 and storms
+            # unreachable the drop compare is constant-False, so eliding it
+            # is result-preserving in both versions.
+            if layout.loss_active:
+                drop_bits = step_words[layout.drop_off : layout.drop_off + m.MAX_MSGS]
+                # static config loss + active storm (storm rate 65535 ~= drop
+                # all), saturating at u32 max
+                base_threshold = jnp.uint32(int(cfg.packet_loss_rate * 0xFFFFFFFF))
+                storm_threshold = storm_loss.astype(jnp.uint32) * jnp.uint32(65537)
+                summed = base_threshold + storm_threshold
+                loss_threshold = jnp.where(
+                    summed < storm_threshold, jnp.uint32(0xFFFFFFFF), summed
+                )
+            if layout.spike_active:
+                # spike gate + magnitude are INDEPENDENT words: conditioning
+                # the magnitude on the gate's sub-threshold bits would cap the
+                # extra latency at ~2.7 s instead of the documented 1-5 s
+                spike_bits = step_words[layout.spike_off : layout.spike_off + m.MAX_MSGS]
+                spike_mag_bits = step_words[
+                    layout.spike_off + m.MAX_MSGS : layout.spike_off + 2 * m.MAX_MSGS
+                ]
+            if layout.dup_active:
+                # duplication gate + fresh-latency words (tail section of
+                # the block — recorded streams are untouched with dup off)
+                dup_bits = step_words[layout.dup_off : layout.dup_off + m.MAX_MSGS]
+                dup_lat_bits = step_words[
+                    layout.dup_off + m.MAX_MSGS : layout.dup_off + 2 * m.MAX_MSGS
+                ]
+                n_dups = jnp.int32(0)
+            # the handling node's outbound clog row, read ONCE (pre-fault
+            # state, matching the unpacked path's s.clogged[ev_node, dst])
+            # and expanded to bool[N] so each message pays the same tiny
+            # gather as the bool-matrix path, not a shift/mask per slot
+            if cfg.clog_packed:
+                clog_row_bool = _clog_row_bools(s.clogged[ev_node], s.killed.shape[0])
+
+            for mi in range(m.MAX_MSGS):
+                want = outbox_valid_msgs[mi]
+                dst = outbox.msg_dst[mi]
+                if cfg.clog_packed:
+                    blocked = clog_row_bool[dst]
+                else:
+                    blocked = s.clogged[ev_node, dst]
+                if layout.loss_active:
+                    blocked = blocked | (drop_bits[mi] < loss_threshold)
+                do_push = want & ~blocked
+                latency = jnp.int32(cfg.latency_min_us) + (
+                    lat_bits[mi] % jnp.uint32(lat_span)
+                ).astype(jnp.int32)
+                if layout.spike_active:
+                    # delay-spike window: ~10% of sends take +1-5 virtual s
+                    # (the host buggify's numbers); the draws are consumed
+                    # every step so windows don't perturb the stream shape
+                    spiked = (delay_spike > 0) & (spike_bits[mi] < jnp.uint32(DELAY_PROB_U32))
+                    extra = jnp.int32(DELAY_EXTRA_MIN_US) + (
+                        spike_mag_bits[mi] % jnp.uint32(DELAY_EXTRA_SPAN_US)
+                    ).astype(jnp.int32)
+                    latency = latency + jnp.where(spiked, extra, 0)
+                slot, has_free = find_free_slot(eq["valid"])
+                overflow = do_push & ~has_free
+                failed = failed | overflow
+                fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
+                do_push = do_push & has_free
+                eq = _push(
+                    eq, slot, do_push, new_now + latency, next_seq, EV_MSG, dst,
+                    ev_node, outbox.msg_payload[mi], prov=sender_prov,
+                )
+                next_seq = next_seq + jnp.where(do_push, 1, 0)
+                msg_count = msg_count + jnp.where(do_push, 1, 0)
+                if layout.dup_active:
+                    # Bernoulli duplicate of a successfully pushed message,
+                    # re-enqueued with an independently drawn latency (the
+                    # idempotency chaos loss-only vocabularies can't
+                    # express). Same overflow accounting as any push.
+                    want_dup = do_push & (dup_bits[mi] < jnp.uint32(DUP_PROB_U32))
+                    dslot, dfree = find_free_slot(eq["valid"])
+                    doverflow = want_dup & ~dfree
+                    failed = failed | doverflow
+                    fail_code = jnp.where(doverflow, jnp.int32(OVERFLOW), fail_code)
+                    want_dup = want_dup & dfree
+                    dup_latency = jnp.int32(cfg.latency_min_us) + (
+                        dup_lat_bits[mi] % jnp.uint32(lat_span)
+                    ).astype(jnp.int32)
+                    eq = _push(
+                        eq, dslot, want_dup, new_now + dup_latency, next_seq,
+                        EV_MSG, dst, ev_node, outbox.msg_payload[mi],
+                        # the duplicate copy carries the dup attribution bit:
+                        # a violation whose lineage includes it names `dup`
+                        prov=(
+                            sender_prov | jnp.uint32(1 << PROV_BIT_DUP)
+                            if sender_prov is not None else None
+                        ),
+                    )
+                    next_seq = next_seq + jnp.where(want_dup, 1, 0)
+                    msg_count = msg_count + jnp.where(want_dup, 1, 0)
+                    n_dups = n_dups + want_dup.astype(jnp.int32)
+
+        with _xprof.scope("step.timers"):
+            # -- push timers (for the handling node) ----------------------------
+            slot0 = jnp.arange(m.PAYLOAD_WIDTH) == 0
+            if cfg.faults.allow_skew:
+                # clock-skew window: the handling node's armed timers are
+                # stretched/compressed by its active q10 factor (read from
+                # the pre-step state — handler events never change skew, and
+                # fault events arm no timers, so pre == post here)
+                node_skew_q10 = s.skew_q10[ev_node]
+            for ti in range(m.MAX_TIMERS):
+                want = outbox_valid_timers[ti]
+                slot, has_free = find_free_slot(eq["valid"])
+                overflow = want & ~has_free
+                failed = failed | overflow
+                fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
+                want = want & has_free
+                tpay = jnp.where(slot0, outbox.timer_id[ti], 0).astype(jnp.int32)
+                t_delay = outbox.timer_delay_us[ti]
+                if cfg.faults.allow_skew:
+                    t_delay = jnp.where(
+                        node_skew_q10 > 0,
+                        skew_scale_us(t_delay, node_skew_q10),
+                        t_delay,
+                    )
+                eq = _push(
+                    eq, slot, want, new_now + t_delay, next_seq,
+                    EV_TIMER, ev_node, jnp.int32(-1), tpay, prov=sender_prov,
+                )
+                next_seq = next_seq + jnp.where(want, 1, 0)
+
+            # -- restart boot timer ---------------------------------------------
+            want_boot = effective & (boot_node >= 0)
+            slot, has_free = find_free_slot(eq["valid"])
+            boot_overflow = want_boot & ~has_free
+            failed = failed | boot_overflow
+            fail_code = jnp.where(boot_overflow, jnp.int32(OVERFLOW), fail_code)
+            want_boot = want_boot & has_free
+            boot_pay = jnp.zeros((m.PAYLOAD_WIDTH,), jnp.int32)  # BOOT == 0
+            eq = _push(
+                eq, slot, want_boot, new_now, next_seq, EV_TIMER, boot_node,
+                jnp.int32(-1), boot_pay, prov=sender_prov,
+            )
+            next_seq = next_seq + jnp.where(want_boot, 1, 0)
+
+        with _xprof.scope("step.recorder"):
+            # -- flight recorder (observability; gate-off adds NO ops) ----------
+            fr = s.fr
+            if cfg.flight_recorder:
+                stepped = jnp.bool_(True) if active is None else active
+                new_step = s.step + stepped.astype(jnp.int32)
+                # digest: fold the popped tuple + the step's whole RNG word
+                # block — exactly the inputs that determine this step — on
+                # every step that pops an event (same condition as the trace
+                # ring / replay trace). The megakernel hands the fold in
+                # pre-computed (same words, same order, same math — the
+                # fused pass runs the identical chain in VMEM).
+                if step_block is not None and len(step_block) == 3:
+                    nd0, nd1 = step_block[1], step_block[2]
+                else:
+                    nd0, nd1 = digest_fold(
+                        fr["d0"],
+                        fr["d1"],
+                        [ev_time, ev_kind, ev_node, ev_src]
+                        + [ev_payload[i] for i in range(m.PAYLOAD_WIDTH)]
+                        + [step_words[i] for i in range(layout.total_words)],
+                    )
+                d0 = jnp.where(live, nd0, fr["d0"])
+                d1 = jnp.where(live, nd1, fr["d1"])
+                # checkpoint ring: every `fr_digest_every`-th step the lane
+                # actually executes lands (step, d0, d1) in slot
+                # (step/every - 1) % ring — the host decodes by sorting on
+                # step. Condition is "the step counter crossed a multiple",
+                # not "popped": the audit's host-side trail reads the digest
+                # at exact step multiples and must see the same checkpoints.
+                every, rr = cfg.fr_digest_every, cfg.fr_digest_ring
+                want_ck = stepped & (new_step % every == 0)
+                ck_slot = ((new_step // every - 1) % rr == jnp.arange(rr)) & want_ck
+                # fault-injection counters: one per FaultPlan kind, counted
+                # when an APPLY op (even payload[0]) is processed
+                is_inj = process & (ev_kind == EV_FAULT) & (ev_payload[0] % 2 == 0)
+                kind_idx = ev_payload[0] // 2
+                inj = fr["inj"] + (
+                    (jnp.arange(len(FAULT_KIND_NAMES)) == kind_idx) & is_inj
+                ).astype(jnp.int32)
+                # non-scheduled chaos counters: duplicates pushed this step,
+                # crash-with-amnesia wipes applied (strict restarts)
+                fr_dup = fr["dup"]
+                if layout.dup_active:
+                    fr_dup = fr_dup + n_dups
+                fr_amnesia = fr["amnesia"]
+                if cfg.faults.strict_restart:
+                    fr_amnesia = fr_amnesia + (
+                        process & (ev_kind == EV_FAULT) & (ev_payload[0] == F_RESTART)
+                    ).astype(jnp.int32)
+                # occupancy high-water marks on the post-step state (frozen
+                # lanes' state is unchanged, so their marks are stable).
+                # Queue occupancy is tracked INCREMENTALLY: the pop clears
+                # exactly one valid slot (when live and not deferred) and
+                # every successful push — messages, duplicates, timers, the
+                # restart boot — fills exactly one free slot and bumped
+                # next_seq, so the delta is (next_seq' - next_seq) minus the
+                # pop. Replaces a [Q]-wide re-sum of eq["valid"] per event
+                # with three scalar ops; equal to the old sum by
+                # construction (host-oracle differential asserts it).
+                popped_one = live if defer is None else (live & ~defer)
+                eq_n = (
+                    fr["eq_n"]
+                    - popped_one.astype(jnp.int32)
+                    + (next_seq - s.next_seq)
+                )
+                n_clog = (
+                    lax.population_count(clogged).sum()
+                    if cfg.clog_packed
+                    else clogged.sum()
+                ).astype(jnp.int32)
+                fr = {
+                    "d0": d0,
+                    "d1": d1,
+                    "eq_n": eq_n,
+                    "ck_step": jnp.where(ck_slot, new_step, fr["ck_step"]),
+                    "ck_d0": jnp.where(ck_slot, d0, fr["ck_d0"]),
+                    "ck_d1": jnp.where(ck_slot, d1, fr["ck_d1"]),
+                    "inj": inj,
+                    "dup": fr_dup,
+                    "amnesia": fr_amnesia,
+                    "q_hwm": jnp.maximum(fr["q_hwm"], eq_n),
+                    "clog_hwm": jnp.maximum(fr["clog_hwm"], n_clog),
+                    "kill_hwm": jnp.maximum(
+                        fr["kill_hwm"], killed.sum().astype(jnp.int32)
+                    ),
+                }
+
+        with _xprof.scope("step.coverage"):
+            # -- scenario coverage (observability; gate-off adds NO ops) --------
+            cov = s.cov
+            if cfg.coverage:
+                # abstract-state projection of the POST-step state: the
+                # scenario this event's processing REACHED (the model
+                # contract: Machine.coverage_projection, low 3 bits = its
+                # coarsest "phase" notion)
+                abs_word = m.coverage_projection(nodes, new_now)
+                # fault-environment context: killed count + active chaos
+                # windows — the same abstract state under partition vs storm
+                # is a different scenario
+                n_killed = jnp.clip(killed.sum().astype(jnp.int32), 0, 7)
+                clog_any = jnp.any(clogged != 0)
+                ctx = (
+                    n_killed
+                    | (clog_any.astype(jnp.int32) << 3)
+                    | ((storm_loss > 0).astype(jnp.int32) << 4)
+                    | ((delay_spike > 0).astype(jnp.int32) << 5)
+                )
+                # new chaos windows extend the context word only when their
+                # kind is enabled — legacy configs hash identical inputs
+                if cfg.faults.allow_pause:
+                    ctx = ctx | (jnp.any(paused_until > 0).astype(jnp.int32) << 6)
+                if cfg.faults.allow_skew:
+                    ctx = ctx | (jnp.any(skew_q10 > 0).astype(jnp.int32) << 7)
+                # event discriminant: payload[0] for msg (message type) and
+                # fault (op) events; timers fold 0 — timer ids are
+                # epoch-encoded, and counting every restart epoch as a new
+                # scenario would inflate the map
+                op_word = jnp.where(ev_kind == EV_TIMER, jnp.int32(0), ev_payload[0])
+                band = cov_band(ev_kind, op_word, self.cov_band_bits)
+                if cfg.faults.strict_restart:
+                    # a strict restart is a different scenario class than a
+                    # plain kill/restart: route it to the amnesia band
+                    band = jnp.where(
+                        (ev_kind == EV_FAULT) & (ev_payload[0] == F_RESTART),
+                        jnp.int32(COV_BAND_AMNESIA),
+                        band,
+                    )
+                slot = cov_slot(
+                    abs_word, ev_kind, ev_node, op_word, ctx, cfg.cov_slots_log2,
+                    band_bits=self.cov_band_bits, band=band,
+                )
+                # same condition as the trace ring / digest: popped events.
+                # Buffered regime (cov_buffer > 0): append the slot index to
+                # the tiny per-lane ring instead of scattering into the
+                # 2 KiB map — the map never appears in the step program;
+                # run_segment folds the buffer at the flush cadence, at
+                # segment exit, and therefore at every freeze point. OR is
+                # commutative + idempotent, so the final map is
+                # bit-identical to the per-event fold (the cov_buffer=0
+                # oracle; tests/test_coverage.py differentials).
+                if self._cov_buffered:
+                    buf, buf_n = cov_push(cov["buf"], cov["buf_n"], slot, live)
                     cov = dict(cov, buf=buf, buf_n=buf_n)
                 else:
-                    cov = {"map": cov_fold(cov["map"], dup_slot, dup_hit)}
+                    cov = {"map": cov_fold(cov["map"], slot, live)}
+                if layout.dup_active:
+                    # synthetic dup band: a step that enqueued >= 1 duplicate
+                    # is its own scenario class (one extra word fold, only
+                    # when the gate is on)
+                    dup_slot = cov_slot(
+                        abs_word, ev_kind, ev_node, n_dups, ctx,
+                        cfg.cov_slots_log2, band_bits=self.cov_band_bits,
+                        band=jnp.int32(COV_BAND_DUP),
+                    )
+                    dup_hit = live & (n_dups > 0)
+                    if self._cov_buffered:
+                        buf, buf_n = cov_push(
+                            cov["buf"], cov["buf_n"], dup_slot, dup_hit
+                        )
+                        cov = dict(cov, buf=buf, buf_n=buf_n)
+                    else:
+                        cov = {"map": cov_fold(cov["map"], dup_slot, dup_hit)}
 
-        # -- invariants / termination ---------------------------------------
-        ok, code = m.invariant(nodes, new_now)
-        inv_fail = process & ~ok
-        if cfg.provenance:
-            # the violation's provenance: the handling node's lineage
-            # cone at the step whose transition broke the invariant
-            # (its word already folds the popped event's). Captured at
-            # the FIRST failure only — that is the violation the fail
-            # code names.
-            fail_prov = jnp.where(
-                inv_fail & ~s.failed, sender_prov | ev_prov, s.fail_prov
-            )
-        else:
-            fail_prov = s.fail_prov
-        failed = failed | inv_fail
-        fail_code = jnp.where(inv_fail, code, fail_code)
-        if active is None:
-            done = s.done | ~any_valid | horizon_hit | m.is_done(nodes, new_now)
-        else:
-            done = (
-                s.done
-                | (active & ~any_valid)
-                | horizon_hit
-                | (active & m.is_done(nodes, new_now))
-            )
+        with _xprof.scope("step.invariants"):
+            # -- invariants / termination ---------------------------------------
+            ok, code = m.invariant(nodes, new_now)
+            inv_fail = process & ~ok
+            if cfg.provenance:
+                # the violation's provenance: the handling node's lineage
+                # cone at the step whose transition broke the invariant
+                # (its word already folds the popped event's). Captured at
+                # the FIRST failure only — that is the violation the fail
+                # code names.
+                fail_prov = jnp.where(
+                    inv_fail & ~s.failed, sender_prov | ev_prov, s.fail_prov
+                )
+            else:
+                fail_prov = s.fail_prov
+            failed = failed | inv_fail
+            fail_code = jnp.where(inv_fail, code, fail_code)
+            if active is None:
+                done = s.done | ~any_valid | horizon_hit | m.is_done(nodes, new_now)
+            else:
+                done = (
+                    s.done
+                    | (active & ~any_valid)
+                    | horizon_hit
+                    | (active & m.is_done(nodes, new_now))
+                )
 
-        return LaneState(
-            now_us=new_now if active is None else jnp.where(active, new_now, s.now_us),
-            next_seq=next_seq,
-            step=s.step + (1 if active is None else active.astype(jnp.int32)),
-            rng_key=key,
-            done=done,
-            failed=failed,
-            fail_code=fail_code,
-            horizon_hit=s.horizon_hit | horizon_hit,
-            msg_count=msg_count,
-            storm_loss=storm_loss,
-            delay_spike=delay_spike,
-            eq_time=eq["time"],
-            eq_seq=eq["seq"],
-            eq_kind=eq["kind"],
-            eq_node=eq["node"],
-            eq_src=eq["src"],
-            eq_payload=eq["payload"],
-            eq_valid=eq["valid"],
-            clogged=clogged,
-            killed=killed,
-            paused_until=paused_until,
-            skew_q10=skew_q10,
-            node_prov=node_prov,
-            eq_prov=eq.get("prov", s.eq_prov),
-            fail_prov=fail_prov,
-            nodes=nodes,
-            ring=ring,
-            fr=fr,
-            cov=cov,
-        )
+            return LaneState(
+                now_us=new_now if active is None else jnp.where(active, new_now, s.now_us),
+                next_seq=next_seq,
+                step=s.step + (1 if active is None else active.astype(jnp.int32)),
+                rng_key=key,
+                done=done,
+                failed=failed,
+                fail_code=fail_code,
+                horizon_hit=s.horizon_hit | horizon_hit,
+                msg_count=msg_count,
+                storm_loss=storm_loss,
+                delay_spike=delay_spike,
+                eq_time=eq["time"],
+                eq_seq=eq["seq"],
+                eq_kind=eq["kind"],
+                eq_node=eq["node"],
+                eq_src=eq["src"],
+                eq_payload=eq["payload"],
+                eq_valid=eq["valid"],
+                clogged=clogged,
+                killed=killed,
+                paused_until=paused_until,
+                skew_q10=skew_q10,
+                node_prov=node_prov,
+                eq_prov=eq.get("prov", s.eq_prov),
+                fail_prov=fail_prov,
+                nodes=nodes,
+                ring=ring,
+                fr=fr,
+                cov=cov,
+            )
 
     # -- batch runners -------------------------------------------------------
 
@@ -1848,15 +1859,17 @@ class Engine:
             # VMEM pass; the rest of the step consumes them via
             # step_block and draws/folds nothing itself
             fr_on = self.config.flight_recorder
-            idx, any_valid, popped, words, digest = step_megakernel(
-                state.eq_time, state.eq_seq, state.eq_valid,
-                state.eq_kind, state.eq_node, state.eq_src, state.eq_payload,
-                state.rng_key, state.step, self._rng_layout.total_words,
-                d0=state.fr["d0"] if fr_on else None,
-                d1=state.fr["d1"] if fr_on else None,
-                digest_fold=digest_fold if fr_on else None,
-                interpret=self._pallas_interpret,
-            )
+            with _xprof.scope("step.pop"):
+                idx, any_valid, popped, words, digest = step_megakernel(
+                    state.eq_time, state.eq_seq, state.eq_valid,
+                    state.eq_kind, state.eq_node, state.eq_src,
+                    state.eq_payload,
+                    state.rng_key, state.step, self._rng_layout.total_words,
+                    d0=state.fr["d0"] if fr_on else None,
+                    d1=state.fr["d1"] if fr_on else None,
+                    digest_fold=digest_fold if fr_on else None,
+                    interpret=self._pallas_interpret,
+                )
             block = (words,) + digest
             return jax.vmap(
                 lambda st, i, a, act, p, blk: self._lane_step_popped(
@@ -1866,19 +1879,22 @@ class Engine:
         if self.use_pallas_pop:
             # fused pop+gather: the popped event tuple leaves the kernel
             # in the same VMEM pass as the argmin
-            idx, any_valid, popped = pop_gather_batch(
-                state.eq_time, state.eq_seq, state.eq_valid,
-                state.eq_kind, state.eq_node, state.eq_src, state.eq_payload,
-                use_pallas=True, interpret=self._pallas_interpret,
-            )
+            with _xprof.scope("step.pop"):
+                idx, any_valid, popped = pop_gather_batch(
+                    state.eq_time, state.eq_seq, state.eq_valid,
+                    state.eq_kind, state.eq_node, state.eq_src,
+                    state.eq_payload,
+                    use_pallas=True, interpret=self._pallas_interpret,
+                )
             return jax.vmap(
                 lambda st, i, a, act, p: self._lane_step_popped(
                     st, i, a, popped=p, active=act
                 )
             )(state, idx, any_valid, active, popped)
-        idx, any_valid = pop_earliest_batch(
-            state.eq_time, state.eq_seq, state.eq_valid, use_pallas=False
-        )
+        with _xprof.scope("step.pop"):
+            idx, any_valid = pop_earliest_batch(
+                state.eq_time, state.eq_seq, state.eq_valid, use_pallas=False
+            )
         return jax.vmap(
             lambda st, i, a, act: self._lane_step_popped(st, i, a, active=act)
         )(state, idx, any_valid, active)
@@ -2043,13 +2059,12 @@ class Engine:
                 "gates aot to mesh=None)"
             )
         # jax.sharding.Mesh hashes by (devices, axis names), so two
-        # calls with equal meshes share one quartet. The xprof gate is
-        # part of the key: phase scopes are inserted at TRACE time, so
-        # flipping MADSIM_TPU_XPROF between runs must re-trace rather
-        # than serve an un(der)-annotated cached quartet.
+        # calls with equal meshes share one quartet. The phase scopes
+        # (perf/xprof.scope) are always in the traced program, so no
+        # measurement gate selects a quartet: the run that is profiled
+        # compiles the program that was timed.
         key = (segment_steps, max_steps, ring_capacity, batch, donate,
-               segments_per_dispatch, use_scan, aot, mesh,
-               _xprof.enabled())
+               segments_per_dispatch, use_scan, aot, mesh)
         if key in cache:
             return cache[key]
 
@@ -2798,17 +2813,20 @@ class Engine:
         # fn traces + compiles synchronously before the async dispatch,
         # so it is labelled "compile" (near-zero wall on a warm
         # persistent cache), later calls "dispatch"/"init".
+        from ..perf import compile_log
         from ..perf.recorder import current_recorder
 
         perf = current_recorder()
         perf_warmed = self.__dict__.setdefault("_perf_warmed", set())
 
-        def _span_name(fn, hot_name):
+        def _span_of(fn, hot_name, program):
             # membership by object identity — the jitted fns are cached
             # on the engine, so the set holds no extra lifetime
-            return "compile" if fn not in perf_warmed else hot_name
+            if fn in perf_warmed:
+                return {"span": hot_name}
+            return {"span": "compile", "program": program}
 
-        def _dispatch(what, fn, *fn_args, span=None):
+        def _dispatch(what, fn, *fn_args, span=None, **span_args):
             def on_retry(attempt, exc, delay_s):
                 stats["dispatch_retries"] += 1
                 import logging
@@ -2818,26 +2836,27 @@ class Engine:
                     "in %.2fs): %s", what, attempt, delay_s, exc,
                 )
 
-            # Device-profile attribution (perf/xprof, MADSIM_TPU_XPROF):
-            # every executor operation lands in a jax.profiler capture
-            # as a named "madsim.<phase>" slice; the dispatch/poll loops
-            # stamp the clock-sync markers the merged plane aligns on.
-            # Gate off => the shared nullcontext: nothing inserted,
-            # bit-identity preserved by construction.
-            name = span or what
-            if perf is None:
-                with _xprof.annotation(name):
-                    return retry_transient(
-                        lambda: fn(*fn_args), what=what, on_retry=on_retry
-                    )
-            with perf.span(name), _xprof.annotation(name):
+            # One name per executor operation: the recorder's span,
+            # which an annotating recorder (PerfRecorder(annotate=True))
+            # also writes into a jax.profiler capture as
+            # "madsim.<name>" on the clock of the device ops.
+            if perf is None and "program" not in span_args:
+                return retry_transient(
+                    lambda: fn(*fn_args), what=what, on_retry=on_retry
+                )
+            with contextlib.ExitStack() as stack:
+                if "program" in span_args:
+                    # a first call: its compile stages go by this name
+                    stack.enter_context(compile_log.program(span_args["program"]))
+                if perf is not None:
+                    stack.enter_context(perf.span(span or what, **span_args))
                 return retry_transient(
                     lambda: fn(*fn_args), what=what, on_retry=on_retry
                 )
 
         carry = _dispatch(
             "carry init", init_carry, seeds,
-            span=_span_name(init_carry, "init"),
+            **_span_of(init_carry, "init", "init_carry"),
         )
         perf_warmed.add(init_carry)
 
@@ -2869,7 +2888,7 @@ class Engine:
             abandoned.extend(int(s) for s in a_seeds[: int(a_n)])
             reset = _dispatch(
                 "ring reset", reset_rings, c,
-                span=_span_name(reset_rings, "dispatch"),
+                **_span_of(reset_rings, "dispatch", "reset_rings"),
             )
             perf_warmed.add(reset_rings)
             return reset
@@ -2914,7 +2933,7 @@ class Engine:
                 _xprof.sync_marker("dispatch")
                 carry = _dispatch(
                     "supersegment dispatch", supersegment, carry, need,
-                    span=_span_name(supersegment, "dispatch"),
+                    **_span_of(supersegment, "dispatch", "supersegment"),
                 )
                 perf_warmed.add(supersegment)
                 stats["dispatches"] += 1
@@ -2934,7 +2953,7 @@ class Engine:
                 _xprof.sync_marker("dispatch")
                 carry = _dispatch(
                     "segment dispatch", segment, carry,
-                    span=_span_name(segment, "dispatch"),
+                    **_span_of(segment, "dispatch", "segment"),
                 )
                 perf_warmed.add(segment)
                 stats["dispatches"] += 1
@@ -2955,7 +2974,7 @@ class Engine:
 
             with (
                 perf.span("harvest") if perf else contextlib.nullcontext()
-            ), _xprof.annotation("harvest"):
+            ):
                 fr_vec = jax.device_get(carry.fr_metrics)
             fr_stats = {"flight_recorder": fr_metrics_dict(fr_vec)}
         cov_stats = {}
@@ -2968,7 +2987,7 @@ class Engine:
 
             with (
                 perf.span("harvest") if perf else contextlib.nullcontext()
-            ), _xprof.annotation("harvest"):
+            ):
                 cov_words = jax.device_get(carry.cov_map)
             cov_map_np = unpack_map(
                 np.asarray(cov_words),

@@ -11,6 +11,7 @@ the property the reference gets from reproduce-by-seed
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from typing import Any, Callable, List, Optional
@@ -18,6 +19,8 @@ from typing import Any, Callable, List, Optional
 import jax
 
 from ..ops import pop_earliest
+from ..perf import compile_log
+from ..perf.recorder import maybe_note, maybe_span
 from .core import EV_FAULT, EV_MSG, EV_TIMER, Engine, LaneState
 
 _KIND_NAMES = {EV_TIMER: "timer", EV_MSG: "msg", EV_FAULT: "fault"}
@@ -192,7 +195,8 @@ def _fast_outcome_fn(engine: Engine):
 
     cache = _replay_cache(engine)
     key = ("fast-outcome", _trace_affecting_key(engine))
-    if key not in cache:
+    fresh = key not in cache
+    if fresh:
 
         def run(state: LaneState, horizon_us, n_steps):
             def body(_i, s):
@@ -206,7 +210,16 @@ def _fast_outcome_fn(engine: Engine):
             return lax.fori_loop(0, n_steps, body, state)
 
         cache[key] = jax.jit(run)
-    return cache[key]
+    return cache[key], fresh
+
+
+@contextlib.contextmanager
+def _first_call(program: str):
+    """The first call of a jitted replay fn: trace + lower + compile-or-
+    read before the dispatch — the executor's `compile` span convention
+    (core.py `_dispatch`), its stages filed under `program`."""
+    with compile_log.program(program), maybe_span("compile", program=program):
+        yield
 
 
 def replay_outcome(engine: Engine, seed: int, max_steps: int = 10_000) -> ReplayResult:
@@ -216,14 +229,18 @@ def replay_outcome(engine: Engine, seed: int, max_steps: int = 10_000) -> Replay
     workhorse."""
     import jax.numpy as jnp
 
-    with jax.default_device(cpu_device()):
+    with maybe_span("replay", seed=int(seed), traced=False), \
+            jax.default_device(cpu_device()):
         state = engine.init_lane(seed)
-        state = _fast_outcome_fn(engine)(
-            state,
-            jnp.int32(engine.config.horizon_us),
-            jnp.int32(max_steps),
-        )
-        return ReplayResult(state=jax.device_get(state), trace=[])
+        fn, fresh = _fast_outcome_fn(engine)
+        args = (state, jnp.int32(engine.config.horizon_us), jnp.int32(max_steps))
+        if fresh:
+            with _first_call("replay.run"):
+                out = fn(*args)  # returns once compiled and enqueued
+        with maybe_span("replay_run"):
+            state = jax.device_get(out if fresh else fn(*args))
+        maybe_note(steps=int(state.step))
+        return ReplayResult(state=state, trace=[])
 
 
 def replay(
@@ -244,7 +261,8 @@ def replay(
     """
     if not trace and on_step is None:
         return replay_outcome(engine, seed, max_steps=max_steps)
-    with jax.default_device(cpu_device()):
+    with maybe_span("replay", seed=int(seed), traced=True), \
+            jax.default_device(cpu_device()):
         state = engine.init_lane(seed)
         # jit the single-lane step: still bit-identical (XLA integer ops are
         # exact and threefry is backend-stable), but the replay materializes
@@ -252,29 +270,37 @@ def replay(
         # Cached on the machine so repeated replays don't recompile.
         cache = _replay_cache(engine)
         skey = ("trace-step", _trace_affecting_key(engine), engine.config.horizon_us)
-        if skey not in cache:
+        fresh = skey not in cache
+        if fresh:
             cache[skey] = jax.jit(engine.lane_step)
         step_fn = cache[skey]
         events: List[TraceEvent] = []
         step = 0
         prov_on = engine.config.provenance
-        while not bool(state.done | state.failed) and step < max_steps:
-            idx, any_valid = pop_earliest(state.eq_time, state.eq_seq, state.eq_valid)
-            ev = TraceEvent(
-                step=step,
-                time_us=int(state.eq_time[idx]),
-                kind=_KIND_NAMES.get(int(state.eq_kind[idx]), "?"),
-                node=int(state.eq_node[idx]),
-                src=int(state.eq_src[idx]),
-                payload=tuple(int(x) for x in state.eq_payload[idx]),
-                seq=int(state.eq_seq[idx]),
-                prov=int(state.eq_prov[idx]) if prov_on else 0,
-            ) if bool(any_valid) else None
-            state = step_fn(state)
-            if ev is not None:
-                if trace:
-                    events.append(ev)
-                if on_step is not None:
-                    on_step(ev, state)
-            step += 1
+        # one span for the whole loop: no per-event span, the loop's
+        # Python stays uninstrumented
+        with maybe_span("replay_run"):
+            while not bool(state.done | state.failed) and step < max_steps:
+                idx, any_valid = pop_earliest(state.eq_time, state.eq_seq, state.eq_valid)
+                ev = TraceEvent(
+                    step=step,
+                    time_us=int(state.eq_time[idx]),
+                    kind=_KIND_NAMES.get(int(state.eq_kind[idx]), "?"),
+                    node=int(state.eq_node[idx]),
+                    src=int(state.eq_src[idx]),
+                    payload=tuple(int(x) for x in state.eq_payload[idx]),
+                    seq=int(state.eq_seq[idx]),
+                    prov=int(state.eq_prov[idx]) if prov_on else 0,
+                ) if bool(any_valid) else None
+                with _first_call("replay.step") if fresh \
+                        else contextlib.nullcontext():
+                    state = step_fn(state)
+                fresh = False
+                if ev is not None:
+                    if trace:
+                        events.append(ev)
+                    if on_step is not None:
+                        on_step(ev, state)
+                step += 1
+        maybe_note(steps=step)
         return ReplayResult(state=state, trace=events)

@@ -36,6 +36,7 @@ import dataclasses
 from typing import Optional
 
 from ..kinds import FLAG_BY_KIND
+from ..perf.recorder import maybe_note, maybe_span
 from .core import Engine, EngineConfig
 from .replay import ReplayResult, replay
 
@@ -89,11 +90,19 @@ class ShrinkResult:
         )
 
 
-def _fails_same(engine: Engine, seed: int, max_steps: int, code: int) -> Optional[ReplayResult]:
-    rp = replay(engine, seed, max_steps=max_steps, trace=False)
-    if rp.failed and rp.fail_code == code:
-        return rp
-    return None
+def _candidate(
+    engine: Engine, cand_cfg: Optional[EngineConfig], seed: int,
+    max_steps: int, code: Optional[int], stage: str,
+) -> Optional[ReplayResult]:
+    """One verification replay under `cand_cfg` (None: the engine as it
+    is), the candidate `Engine`'s construction included in its span.
+    Returns the replay when it fails with `code` (any code when None)."""
+    with maybe_span("shrink_candidate", stage=stage):
+        eng = engine if cand_cfg is None else Engine(engine.machine, cand_cfg)
+        rp = replay(eng, seed, max_steps=max_steps, trace=False)
+        accepted = rp.failed and code in (None, rp.fail_code)
+        maybe_note(accepted=bool(accepted))
+    return rp if accepted else None
 
 
 def shrink(
@@ -117,8 +126,8 @@ def shrink(
 
     Raises ValueError if the seed does not fail under the given engine.
     """
-    base = replay(engine, seed, max_steps=max_steps, trace=False)
-    if not base.failed:
+    base = _candidate(engine, None, seed, max_steps, None, "base")
+    if base is None:
         raise ValueError(
             f"seed {seed} does not fail under this config (within "
             f"{max_steps} steps) — nothing to shrink"
@@ -150,7 +159,7 @@ def shrink(
         cand_cfg = dataclasses.replace(
             cfg, faults=dataclasses.replace(cfg.faults, n_faults=f)
         )
-        rp = _fails_same(Engine(engine.machine, cand_cfg), seed, max_steps, code)
+        rp = _candidate(engine, cand_cfg, seed, max_steps, code, "faults")
         return cand_cfg, rp
 
     guessed = False
@@ -178,7 +187,7 @@ def shrink(
     if cfg.packet_loss_rate > 0:
         cand_cfg = dataclasses.replace(cfg, packet_loss_rate=0.0)
         attempts += 1
-        rp = _fails_same(Engine(engine.machine, cand_cfg), seed, max_steps, code)
+        rp = _candidate(engine, cand_cfg, seed, max_steps, code, "loss")
         if rp is not None:
             cfg, best = cand_cfg, rp
 
@@ -207,8 +216,8 @@ def shrink(
             if bulk_faults.n_faults == 0 or bulk_faults.enabled_kinds():
                 cand_cfg = dataclasses.replace(cfg, faults=bulk_faults)
                 attempts += 1
-                rp = _fails_same(
-                    Engine(engine.machine, cand_cfg), seed, max_steps, code
+                rp = _candidate(
+                    engine, cand_cfg, seed, max_steps, code, "kinds"
                 )
                 if rp is not None:
                     cfg, best = cand_cfg, rp
@@ -223,7 +232,7 @@ def shrink(
             continue
         cand_cfg = dataclasses.replace(cfg, faults=cand_faults)
         attempts += 1
-        rp = _fails_same(Engine(engine.machine, cand_cfg), seed, max_steps, code)
+        rp = _candidate(engine, cand_cfg, seed, max_steps, code, "kinds")
         if rp is not None:
             cfg, best = cand_cfg, rp
             kinds_removed.append(kind_name)
@@ -234,7 +243,7 @@ def shrink(
     if fail_t + 1 < cfg.horizon_us:
         cand_cfg = dataclasses.replace(cfg, horizon_us=fail_t + 1)
         attempts += 1
-        rp = _fails_same(Engine(engine.machine, cand_cfg), seed, max_steps, code)
+        rp = _candidate(engine, cand_cfg, seed, max_steps, code, "horizon")
         if rp is not None:
             cfg, best = cand_cfg, rp
 

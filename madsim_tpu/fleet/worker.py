@@ -292,9 +292,11 @@ class FleetWorker:
         # `--perf-timeline` recorder still sees everything: the unit's
         # spans are absorbed back into it after the unit.
         outer = current_recorder()
-        unit_rec = PerfRecorder(meta={
-            "trace_id": job.id, "job": job.id, "worker": self.worker_id,
-        })
+        # a device capture (MADSIM_TPU_XPROF=1) gets the spans too
+        unit_rec = PerfRecorder(
+            trace_id=job.id, annotate=xprof.enabled(),
+            meta={"trace_id": job.id, "job": job.id, "worker": self.worker_id},
+        )
         offset_us = outer._now_us() if outer is not None else 0.0
         wall_t0 = time.time()
         # crash flush: a SIGTERM'd (or atexit'd) worker dumps the
@@ -405,7 +407,8 @@ class FleetWorker:
             spans_out.append(
                 {"name": s["name"], "ts": round(s["ts"], 1),
                  "dur": None if s["dur"] is None else round(s["dur"], 1),
-                 "depth": s["depth"], "args": s["args"]})
+                 "depth": s["depth"], "id": s["id"], "parent": s["parent"],
+                 "args": s["args"]})
         if not spans_out:
             return
         doc = {

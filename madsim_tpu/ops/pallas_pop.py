@@ -162,6 +162,7 @@ def pop_earliest_pallas(eq_time, eq_seq, eq_valid, interpret: bool = False) -> T
             jax.ShapeDtypeStruct((padded, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="madsim_pop",
     )(eq_time, eq_seq, eq_valid)
     return idx[:lanes, 0], any_valid[:lanes, 0] != 0
 
@@ -194,6 +195,7 @@ def pop_gather_pallas(
         out_specs=[out_spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((padded, 1), jnp.int32)] * n_out,
         interpret=interpret,
+        name="madsim_pop_gather",
     )(*ins)
     outs = [o[:lanes, 0] for o in outs]
     idx, any_valid, ev_time, ev_kind, ev_node, ev_src = outs[:6]
@@ -353,6 +355,7 @@ def step_megakernel(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="madsim_step_mega",
     )(*ins)
     idx, any_valid, ev_time = (o[:lanes, 0] for o in outs[:3])
     val_cols = [o[:lanes, 0] for o in outs[3 : 3 + n_vals]]
@@ -422,6 +425,7 @@ def cov_flush_pallas(cov_map, buf, n, interpret: bool = False):
         out_specs=pl.BlockSpec((LANE_BLOCK, w), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, w), jnp.int32),
         interpret=interpret,
+        name="madsim_cov_flush",
     )(*ins)
     return out[:lanes]
 

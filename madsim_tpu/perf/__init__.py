@@ -17,7 +17,13 @@ file-by-file here because measuring the wall clock IS the contract).
 """
 
 from .ab import ABResult, bootstrap_ci, interleaved_ab, paired_stats, sign_test_p
-from .recorder import PerfRecorder, current_recorder, maybe_count, maybe_span
+from .recorder import (
+    PerfRecorder,
+    current_recorder,
+    maybe_count,
+    maybe_note,
+    maybe_span,
+)
 
 __all__ = [
     "ABResult",
@@ -26,6 +32,7 @@ __all__ = [
     "current_recorder",
     "interleaved_ab",
     "maybe_count",
+    "maybe_note",
     "maybe_span",
     "paired_stats",
     "sign_test_p",

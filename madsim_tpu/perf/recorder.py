@@ -18,20 +18,51 @@ export is Chrome `trace_event` JSON: one process, one "host" thread
 row, `ph: "X"` slices whose nesting the Perfetto UI draws by
 containment.
 
-Span taxonomy (what the instrumented engine emits):
+Span taxonomy — one tree from a CLI command down to one replay. Every
+span records its own ``id``, the id of the span that encloses it
+(``parent``, -1 at the top) and the recorder's ``trace_id``, so a reader computes self time
+as duration minus children without guessing from ``depth``:
 
-=================  =========================================================
-``compile``        first invocation of a jitted streaming fn (trace +
-                   compile + first dispatch; near-zero on a warm
-                   persistent compile cache)
-``dispatch``       an async supersegment/segment dispatch (returns as
-                   soon as the work is enqueued — short by design)
-``counters_poll``  the blocking device->host counters read (where a
-                   device-bound run spends its wall time)
-``ring_drain``     failing/abandoned ring harvest + reset
-``harvest``        final flight-recorder / coverage-map transfer
+=====================  =====================================================
+``engine_build``       `_build_engine`: imports, model init, first backend
+                       touch
+``warmup_dispatch``    `_stream_batches`' unmeasured `run_stream(1, ...)`
+``run_stream``         one `Engine.run_stream` call (args: n_seeds, batch)
+``compile``            first invocation of a jitted fn (trace + lower +
+                       compile-or-read + first dispatch; near-zero on a
+                       warm persistent cache); arg ``program``:
+                       ``init_carry`` / ``supersegment`` / ``segment`` /
+                       ``reset_rings`` (executor), ``replay.run`` /
+                       ``replay.step`` (replay)
+``init``               a later `init_carry` dispatch
+``dispatch``           an async supersegment/segment dispatch (returns as
+                       soon as the work is enqueued — short by design)
+``counters_poll``      the blocking device->host counters read (where a
+                       device-bound run spends its wall time)
+``ring_drain``         failing/abandoned ring harvest + reset
+``harvest``            final flight-recorder / coverage-map transfer
 ``checkpoint_write`` / ``stats_emit`` — host persistence riding a hunt
-=================  =========================================================
+``hunt_report``        `cmd_hunt`: the stream's return to the first shrink
+                       (prints, coverage file, corpus load)
+``shrink_candidate``   one replay attempt of `engine.shrink`, the
+                       candidate `Engine`'s construction included (args:
+                       ``stage`` base/faults/loss/kinds/horizon,
+                       ``accepted``)
+``replay``             one CPU replay (args: seed, traced, steps)
+``replay_run``         inside ``replay``: dispatch to result on the host
+``corpus_record``      `cmd_hunt`: the digest-trail replay and the filing
+``regress_entry`` / ``audit_entry`` — `regress` / `audit`, per corpus entry
+=====================  =====================================================
+
+Counters (`maybe_count`): ``compile.trace`` / ``compile.lower`` /
+``compile.backend`` / ``compile.cache_miss`` / ``compile.cache_hit`` —
+one per jax compile-stage event while a recorder is active
+(`perf/compile_log.py`, which also keeps them by program).
+
+`PerfRecorder(annotate=True)` also writes every span into a running
+`jax.profiler` capture as a ``madsim.<name>`` TraceAnnotation, on the
+clock of the device ops: `--perf-timeline` with `--xla-profile`, `prof`
+and the fleet worker's capture turn it on.
 
 The summary classifies a run: mostly ``compile`` => compile-bound (warm
 the cache); mostly ``counters_poll``/``ring_drain`` => device-bound
@@ -48,13 +79,19 @@ from __future__ import annotations
 # virtual time stays in the engine.
 import contextlib
 import contextvars
+import itertools
 import json
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 _CURRENT: contextvars.ContextVar[Optional["PerfRecorder"]] = contextvars.ContextVar(
     "madsim_tpu_perf_recorder", default=None
 )
+
+# default trace ids: process, wall clock, ordinal — unique without
+# drawing OS entropy (lint D002)
+_TRACE_SEQ = itertools.count()
 
 # one shared, re-entered null context for the recorder-off path: no
 # allocation per call in the engine hot loop
@@ -73,6 +110,21 @@ def maybe_span(name: str, **args: Any):
     if rec is None:
         return _NULL_CTX
     return rec.span(name, **args)
+
+
+def _annotation(name: str):
+    """The span's twin in a running `jax.profiler` capture."""
+    import jax
+
+    return jax.profiler.TraceAnnotation("madsim." + name)
+
+
+def maybe_note(**args: Any) -> None:
+    """Add args to the innermost open span of the active recorder (what
+    a span learns only at its end: `steps`, `accepted`); no-op otherwise."""
+    rec = _CURRENT.get()
+    if rec is not None:
+        rec.note(**args)
 
 
 def maybe_count(name: str, n: int = 1) -> None:
@@ -95,15 +147,23 @@ class PerfRecorder:
         self,
         meta: Optional[Dict[str, Any]] = None,
         clock: Callable[[], float] = time.perf_counter,
+        annotate: bool = False,
+        trace_id: Optional[str] = None,
     ):
         self.meta = dict(meta or {})
         self._clock = clock
-        self.spans: List[dict] = []  # {"name", "ts", "dur", "depth", "args"}
+        # one id per CLI command / fleet job: spans of one request share it
+        self.trace_id = trace_id or (
+            f"{os.getpid():x}-{time.time_ns():x}-{next(_TRACE_SEQ)}")
+        self.annotate = annotate
+        # closed spans and instants, in closing order: {"name", "ts",
+        # "dur", "depth", "id", "parent", "trace_id", "args"}
+        self.spans: List[dict] = []
+        self._next_id = 0
         self._open: List[dict] = []  # in-flight spans (crash-flush path)
         self.counters: Dict[str, int] = {}
         self._t0: Optional[float] = None
         self._t_end: Optional[float] = None
-        self._depth = 0
         self._token = None
 
     # -- lifecycle ----------------------------------------------------------
@@ -129,33 +189,37 @@ class PerfRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, **args: Any):
-        """Record one host span; spans nest (`depth` is recorded so the
-        summary can attribute wall time to OUTERMOST spans only)."""
-        start = self._now_us()
-        self._depth += 1
-        self._open.append(
-            {"name": name, "ts": start, "depth": self._depth - 1,
-             "args": args})
+        """Record one host span; spans nest. A span takes its ``id`` when
+        it opens and names the enclosing span's id as ``parent`` (-1 at
+        the top); it joins `spans` when it closes. Code inside the span
+        adds to its ``args`` with `note`."""
+        rec = self._stamp(name, args)
+        self._open.append(rec)
+        note = _annotation(name) if self.annotate else _NULL_CTX
         try:
-            yield self
+            with note:
+                yield self
         finally:
-            self._depth -= 1
             self._open.pop()  # spans unwind LIFO, exceptions included
-            self.spans.append(
-                {
-                    "name": name,
-                    "ts": start,
-                    "dur": max(self._now_us() - start, 0.0),
-                    "depth": self._depth,
-                    "args": args,
-                }
-            )
+            rec["dur"] = max(self._now_us() - rec["ts"], 0.0)
+            self.spans.append(rec)
+
+    def _stamp(self, name: str, args: dict) -> dict:
+        self._next_id += 1
+        return {
+            "name": name, "ts": self._now_us(), "dur": None,
+            "depth": len(self._open), "id": self._next_id - 1,
+            "parent": self._open[-1]["id"] if self._open else -1,
+            "trace_id": self.trace_id, "args": args,
+        }
+
+    def note(self, **args: Any) -> None:
+        """Add args to the innermost open span (no-op with none open)."""
+        if self._open:
+            self._open[-1]["args"].update(args)
 
     def instant(self, name: str, **args: Any) -> None:
-        self.spans.append(
-            {"name": name, "ts": self._now_us(), "dur": None,
-             "depth": self._depth, "args": args}
-        )
+        self.spans.append(self._stamp(name, args))
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + n
@@ -172,9 +236,8 @@ class PerfRecorder:
         now = (self._t_end - self._t0) * 1e6 if self._t_end is not None \
             else self._now_us()
         return [
-            {"name": s["name"], "ts": s["ts"],
-             "dur": max(now - s["ts"], 0.0), "depth": s["depth"],
-             "args": dict(s["args"], partial=True)}
+            dict(s, dur=max(now - s["ts"], 0.0),
+                 args=dict(s["args"], partial=True))
             for s in self._open
         ]
 
@@ -186,14 +249,14 @@ class PerfRecorder:
         dump for cross-process correlation — under an outer
         `--perf-timeline` recorder without double-instrumenting.
         Returns the number of spans absorbed."""
+        base = self._next_id  # keeps ids unique, parents pointing right
         for s in other.spans:
-            self.spans.append({
-                "name": s["name"],
-                "ts": s["ts"] + ts_offset_us,
-                "dur": s["dur"],
-                "depth": s["depth"],
-                "args": dict(s["args"]),
-            })
+            self.spans.append(dict(
+                s, ts=s["ts"] + ts_offset_us, id=s["id"] + base,
+                parent=s["parent"] + base if s["parent"] >= 0 else -1,
+                args=dict(s["args"]),
+            ))
+        self._next_id += other._next_id
         for name, n in other.counters.items():
             self.count(name, n)
         return len(other.spans)
@@ -208,12 +271,10 @@ class PerfRecorder:
         end = self._t_end if self._t_end is not None else self._clock()
         return (end - self._t0) * 1e6
 
-    def _level(self, depth_zero: bool) -> List[dict]:
+    def _top(self) -> List[dict]:
+        """The closed top-level spans, by start time."""
         return sorted(
-            (
-                s for s in self.spans
-                if (s["depth"] == 0) == depth_zero and s["dur"] is not None
-            ),
+            (s for s in self.spans if s["depth"] == 0 and s["dur"] is not None),
             key=lambda s: s["ts"],
         )
 
@@ -247,8 +308,7 @@ class PerfRecorder:
         compute threads, that time starves the host thread between
         inner spans rather than accruing to the blocking poll — the
         1-core reference box ALWAYS looks like this)."""
-        top = self._level(True)
-        inner = self._level(False)
+        top = self._top()
         by_name: Dict[str, dict] = {}
         for s in sorted(self.spans, key=lambda s: s["ts"]):
             if s["dur"] is None:
@@ -257,18 +317,22 @@ class PerfRecorder:
             d["total_us"] += s["dur"]
             d["count"] += 1
         top_union = self._union_us(top)
-        # device_wait is scoped to the streaming spans: uncovered
-        # interior of a `run_stream` span is the device executing (or
-        # starving the host thread on a shared-core box); uncovered
-        # interior of anything else is just that span's own host work
-        rs = [s for s in top if s["name"] == "run_stream"]
-        inner_in_rs = [
-            s for s in inner
-            if any(
-                r["ts"] <= s["ts"] < r["ts"] + r["dur"] for r in rs
-            )
-        ]
-        device_wait = max(self._union_us(rs) - self._union_us(inner_in_rs), 0.0)
+        # device_wait is scoped to the streaming spans: interior of a
+        # `run_stream` span (at any depth: the warm-up's sits under
+        # `warmup_dispatch`) that none of its child spans covers is the
+        # device executing (or starving the host thread on a shared-core
+        # box); uncovered interior of anything else is just that span's
+        # own host work
+        children: Dict[int, List[dict]] = {}
+        for s in self.spans:
+            if s["dur"] is not None and s["parent"] >= 0:
+                children.setdefault(s["parent"], []).append(s)
+        device_wait = sum(
+            max(r["dur"] - self._union_us(
+                sorted(children.get(r["id"], ()), key=lambda s: s["ts"])), 0.0)
+            for r in self.spans
+            if r["name"] == "run_stream" and r["dur"] is not None
+        )
         gap_us = 0.0
         prev_end = None
         for s in top:
@@ -319,7 +383,17 @@ class PerfRecorder:
         }
         bound = max(buckets, key=lambda k: buckets[k])
         parts = ", ".join(f"{k.split('-bound')[0]} {v:.2f}s" for k, v in buckets.items())
-        return f"{bound} ({parts} of {s['wall_s']:.2f}s wall)"
+        line = f"{bound} ({parts} of {s['wall_s']:.2f}s wall)"
+        if bound == "compile-bound" and self._clock is time.perf_counter:
+            # which stage of which program (perf/compile_log.py keeps
+            # jax's compile-stage events on the same clock)
+            from . import compile_log
+
+            log = compile_log.current()
+            worst = log and compile_log.slowest(log.snapshot(self._t0, self._t_end))
+            if worst:
+                line += f"; slowest: {worst}"
+        return line
 
     # -- export -------------------------------------------------------------
 

@@ -9,25 +9,30 @@ an `jax.profiler` capture of a hunt shows anonymous XLA fusions, and
 backend compilation are three different problems (ROADMAP [perf]:
 "TRACE-dominated" warm starts). This module closes both gaps:
 
-* **Device-phase attribution** — `annotation(name)` (host-side
-  `jax.profiler.TraceAnnotation`) and `scope(name)` (trace-time
-  `jax.named_scope`) wrap the stream quartet's phases and the
-  registered collectives so a profiler capture names simulation phases
-  (``madsim.step``, ``madsim.harvest``, ``madsim.collective.cov-map-or``,
-  …) instead of fusion soup. Gated OFF by default
-  (``MADSIM_TPU_XPROF``): when off, both return one shared
-  `nullcontext` — literally nothing is inserted into the traced
-  program or the host loop, so streams, goldens and compile-cache keys
-  are byte-identical to an uninstrumented build, the same discipline
-  as the coverage/fr gates. (When ON, `scope` changes HLO *metadata*
-  — same math, different persistent-cache entries — which is exactly
-  why the gate defaults off.)
+* **Device-phase attribution** — `scope(name)` (trace-time
+  `jax.named_scope("madsim.<name>")`) wraps every cost-model row of a
+  step (``madsim.step.pop`` … ``madsim.step.invariants``), the stream
+  quartet's segment-level phases and the registered collectives, so a
+  profiler capture names simulation phases (``madsim.step.handlers``,
+  ``madsim.harvest``, ``madsim.collective.cov-map-or``, …) instead of
+  fusion soup. The scopes are ALWAYS in the traced program: a name
+  stack lives in MLIR locations, which jax strips from a module before
+  hashing it for the persistent compile cache, so they move no cache
+  key and no compiled code — the run that is profiled runs the program
+  that was timed. The host side of the same picture is the recorder's
+  (`perf/recorder.py`): `PerfRecorder(annotate=True)` writes every host
+  span into the capture as ``madsim.<span>`` on the device ops' clock.
+  ``MADSIM_TPU_XPROF`` keeps one operator meaning: CAPTURE a device
+  profile (`prof`, the fleet worker's per-unit capture). It selects no
+  program.
 
 * **Compile autopsy** — `compile_autopsy(jitted, avals)` splits a cold
   compile into trace_s / lower_s / backend_s via the AOT stages API
   and attaches `.cost_analysis()` flops/bytes and
   `.memory_analysis()` peak bytes, keyed per `cache_subkey` by the
-  callers (bench.py, `prof compile`, `/metrics`).
+  callers (bench.py, `prof compile`, `/metrics`). (The same three
+  stages of the compiles a run really makes, by program, are
+  `perf/compile_log.py`'s: no second compile needed.)
 
 * **The merged plane** — `merge_plane(host_doc, device_events,
   virtual_doc)` aligns the host timeline, the device profile and a
@@ -54,8 +59,8 @@ from __future__ import annotations
 # madsim: allow-file(D001) — this module's *contract* is wall-clock
 # profiling: it times compile stages, stamps wall-epoch clock-sync
 # markers and drives jax.profiler captures. Nothing here can reach
-# simulation state; the gate is off by default and gate-off inserts
-# literally nothing (one shared nullcontext).
+# simulation state: a named scope is trace-time metadata, a capture
+# and its sync markers are host-side.
 import contextlib
 import glob
 import gzip
@@ -78,56 +83,47 @@ PHASE_PREFIX = "madsim."
 #: (seq in args); device-profile slices are named "madsim.sync:<seq>".
 SYNC_NAME = "madsim.sync"
 
-#: the stream quartet's phases, as named in the device profile
-#: (annotation targets in engine/core.py; pinned by tests + CI smoke)
+#: every device scope the program traces in, as named in the device
+#: profile (``madsim.<name>``; scope sites in engine/core.py, pinned by
+#: tests). Host-side names (dispatch, counters_poll, ...) are the
+#: recorder's spans, not listed here.
 DEVICE_PHASES = (
-    "step",            # per-event advance (run_segment interior)
-    "refill",          # harvested-lane refill (ranks + seed counter)
-    "harvest",         # completion count + ring appends + folds
-    "fr_fold",         # flight-recorder digest fold
-    "cov_fold",        # coverage-map OR fold
-    "counters",        # the small counters vector rebuild
-    "ring_append",     # failing/abandoned ring append
-    "dispatch",        # host: async supersegment enqueue
-    "counters_poll",   # host: the blocking device->host counters read
-    "ring_drain",      # host: ring harvest + reset
+    "step",             # run_segment's event loop (its own control ops)
+    "step.pop",         # queue pop + gather, or the Pallas kernel doing both
+    "step.rng",         # the step's RNG word block
+    "step.handlers",    # timer / message / fault branches + write-back
+    "step.provenance",  # lineage fold (only where the gate adds ops)
+    "step.outbox",      # message pushes: latency, loss, clog, duplicates
+    "step.timers",      # timer pushes + the restart boot timer
+    "step.recorder",    # trace ring + flight recorder
+    "step.coverage",    # projection, slot hash, buffer append
+    "step.invariants",  # invariant, termination, next-state assembly
+    "cov_flush",        # buffered coverage slots folded into the maps
+    "refill",           # harvested-lane refill (ranks + seed counter)
+    "harvest",          # completion count + ring appends + folds
+    "fr_fold",          # flight-recorder digest fold
+    "cov_fold",         # coverage-map OR fold
+    "counters",         # the small counters vector rebuild
+    "ring_append",      # failing/abandoned ring append
 )
-
-# one shared, re-entered null context for the gate-off path: no
-# allocation, no insertion — bit-identity off by construction
-_NULL_CTX = contextlib.nullcontext()
 
 _SYNC_SEQ = itertools.count()
 
 
 def enabled() -> bool:
-    """The MADSIM_TPU_XPROF gate. Read at every call site (annotations)
-    and at trace time (scopes) — engine/core.py folds it into the
-    stream-fns cache key so flipping the env between runs re-traces."""
+    """The MADSIM_TPU_XPROF gate: capture a device profile (`prof`, the
+    fleet worker's per-unit capture). It selects no program — the phase
+    scopes are always traced in — so it is read only on the host."""
     return os.environ.get(ENV_GATE, "") not in ("", "0")
 
 
-def annotation(name: str):
-    """Host-side device-profile marker: a `jax.profiler.TraceAnnotation`
-    named ``madsim.<name>`` when the gate is on, the shared no-op
-    context otherwise. Wrap host-side executor operations (dispatch,
-    poll, drain) — the annotation lands in the profiler capture, NOT
-    in the traced program, so it can never perturb compiled code."""
-    if not enabled():
-        return _NULL_CTX
-    import jax
-
-    return jax.profiler.TraceAnnotation(PHASE_PREFIX + name)
-
-
 def scope(name: str):
-    """Trace-time phase scope: `jax.named_scope("madsim.<name>")` when
-    the gate is on (names the HLO metadata so profiler captures and
-    compiler dumps attribute ops to simulation phases), the shared
-    no-op context otherwise (zero trace-time footprint: the lowered
-    program is byte-identical to an uninstrumented build)."""
-    if not enabled():
-        return _NULL_CTX
+    """Trace-time phase scope: `jax.named_scope("madsim.<name>")`, on
+    always. A name stack lives in MLIR locations, which jax strips
+    before it hashes a module for the persistent compile cache
+    (`jax._src.cache_key`, `strip-debuginfo`), so the scopes move no
+    cache key and change no compiled code: the run that is profiled
+    runs the program that was timed."""
     import jax
 
     return jax.named_scope(PHASE_PREFIX + name)

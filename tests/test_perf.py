@@ -27,6 +27,7 @@ from madsim_tpu.perf.recorder import (
     PerfRecorder,
     current_recorder,
     maybe_count,
+    maybe_note,
     maybe_span,
 )
 
@@ -90,6 +91,84 @@ def test_recorder_device_wait_scoped_to_run_stream():
     assert s["device_wait_s"] == pytest.approx(0.7)
     assert s["spans"]["run_stream"]["total_s"] == pytest.approx(2.75)
     assert "compile-bound" in rec.verdict()
+
+
+def test_recorder_spans_name_their_parent_and_trace():
+    """Every span carries its id, the id of the span that encloses it
+    (-1 at the top) and the recorder's trace_id, so self time is
+    duration minus children with no guessing from depth; `run_stream`'s
+    device_wait is found through the parent index at any depth (the
+    warm-up's run_stream sits under `warmup_dispatch`)."""
+    clk = FakeClock()
+    rec = PerfRecorder(clock=clk, trace_id="job-7")
+    with rec:
+        with rec.span("warmup_dispatch"):
+            with rec.span("run_stream"):
+                with rec.span("dispatch"):
+                    clk.tick(0.1)
+                clk.tick(0.6)  # device executing: device_wait
+            clk.tick(0.05)  # warmup_dispatch's own host work
+        rec.instant("mark")
+        with maybe_span("hunt_report") as got:
+            assert got is rec  # a span yields its recorder (subclasses rely on it)
+            maybe_note(lines=3)  # what a span learns inside goes to its args
+            clk.tick(0.2)
+        maybe_note(lost=1)  # no span open: dropped, not an error
+    by_name = {s["name"]: s for s in rec.spans}
+    assert by_name["warmup_dispatch"]["parent"] == -1
+    assert by_name["run_stream"]["parent"] == by_name["warmup_dispatch"]["id"]
+    assert by_name["dispatch"]["parent"] == by_name["run_stream"]["id"]
+    assert by_name["mark"]["parent"] == -1 and by_name["mark"]["dur"] is None
+    assert by_name["hunt_report"]["args"] == {"lines": 3}
+    assert len({s["id"] for s in rec.spans}) == len(rec.spans)
+    assert {s["trace_id"] for s in rec.spans} == {"job-7"}
+    assert PerfRecorder().trace_id != PerfRecorder().trace_id
+    s = rec.summary()
+    assert s["device_wait_s"] == pytest.approx(0.6)
+    # absorbed spans keep a valid tree under fresh ids
+    outer = PerfRecorder(clock=clk)
+    with outer:
+        with outer.span("fleet_unit"):
+            clk.tick(0.1)
+        outer.absorb(rec, ts_offset_us=5.0)
+    ids = {s["id"]: s for s in outer.spans}
+    assert len(ids) == len(outer.spans)
+    moved = next(s for s in outer.spans if s["name"] == "dispatch")
+    assert ids[moved["parent"]]["name"] == "run_stream"
+
+
+@pytest.mark.parametrize("annotate", [True, False])
+def test_recorder_annotates_each_span_once_or_never(annotate, monkeypatch):
+    """`PerfRecorder(annotate=True)` writes every span into a running
+    profiler capture as ONE `madsim.<name>` TraceAnnotation, entered and
+    left with the span; the default writes none (the benchmark's
+    recorder wraps spans itself and must not get them twice)."""
+    import jax
+
+    seen = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    rec = PerfRecorder(clock=FakeClock(), annotate=annotate)
+    with rec:
+        with rec.span("shrink_candidate", stage="base"):
+            with rec.span("replay"):
+                pass
+        maybe_count("compile.trace")
+    assert [s["name"] for s in rec.spans] == ["replay", "shrink_candidate"]
+    assert seen == ([
+        ("enter", "madsim.shrink_candidate"), ("enter", "madsim.replay"),
+        ("exit", "madsim.replay"), ("exit", "madsim.shrink_candidate"),
+    ] if annotate else [])
 
 
 def test_recorder_contextvar_scoping():
@@ -443,6 +522,136 @@ def test_perf_timeline_e2e_explore_stream(tmp_path):
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert xs == sorted(xs, key=lambda e: e["ts"])
     assert math.isfinite(sum(e["dur"] for e in xs))
+
+
+#: the host taxonomy a hunt -> regress -> audit must show (ISSUE 25,
+#: table A), each with the args a reader keys on
+HUNT_TREE = {
+    "warmup_dispatch": (), "run_stream": ("n_seeds",), "hunt_report": (),
+    "shrink_candidate": ("stage", "accepted"),
+    "replay": ("seed", "traced", "steps"), "compile": ("program",),
+    "replay_run": (), "corpus_record": (), "regress_entry": ("seed",),
+    "audit_entry": ("seed",),
+}
+
+
+def test_hunt_span_tree_reaches_one_replay(tmp_path):
+    """A tiny `hunt --stream --limit 1` + `regress` + `audit` under one
+    recorder: every span of the taxonomy is there with its args and a
+    valid parent, each shrink attempt is one `shrink_candidate`, each
+    replay program is compiled under a `compile` span naming it, and the
+    named spans cover >= 95% of the commands' wall."""
+    import time
+
+    import jax  # noqa: F401 — a process's first jax import is not the command's
+
+    from madsim_tpu.__main__ import main
+
+    corpus = str(tmp_path / "corpus.json")
+    hunt = ["hunt", "--machine", "demo-nodedup-mvcc", "--stream",
+            "--seeds", "64", "--seed", "0", "--limit", "1",
+            "--corpus", corpus, "--horizon", "8", "--queue", "48",
+            "--faults", "3", "--fault-kinds", "pair,kill,dir,group,storm",
+            "--fault-tmax", "3000000", "--max-steps", "4000", "--batch", "64"]
+    rec = PerfRecorder()
+    with rec:
+        t0 = time.perf_counter()
+        assert main(hunt) == 1  # found and filed
+        assert main(["regress", "--corpus", corpus]) == 0
+        assert main(["audit", "--corpus", corpus]) == 0
+        wall_us = (time.perf_counter() - t0) * 1e6
+    by_id = {s["id"]: s for s in rec.spans}
+    names = {s["name"] for s in rec.spans}
+    assert set(HUNT_TREE) <= names, sorted(set(HUNT_TREE) - names)
+    for s in rec.spans:
+        assert s["parent"] == -1 or s["parent"] in by_id, s
+        assert set(HUNT_TREE.get(s["name"], ())) <= set(s["args"]), s
+        if s["parent"] >= 0:  # a child lies inside its parent
+            p = by_id[s["parent"]]
+            assert p["ts"] <= s["ts"] and \
+                s["ts"] + s["dur"] <= p["ts"] + p["dur"] + 1.0, (s, p)
+    parent_of = lambda s: by_id[s["parent"]]["name"] if s["parent"] >= 0 else None
+    cands = [s for s in rec.spans if s["name"] == "shrink_candidate"]
+    assert cands[0]["args"]["stage"] == "base"
+    assert {c["args"]["stage"] for c in cands} <= {
+        "base", "faults", "loss", "kinds", "horizon"}
+    replays = [s for s in rec.spans if s["name"] == "replay"]
+    assert {parent_of(r) for r in replays} == {
+        "shrink_candidate", "corpus_record", "regress_entry", "audit_entry"}
+    assert len([r for r in replays if parent_of(r) == "shrink_candidate"]) \
+        == len(cands)  # `attempts` is a count of spans
+    assert {s["args"]["program"] for s in rec.spans if s["name"] == "compile"} \
+        >= {"supersegment", "init_carry", "replay.run"}
+    assert all(parent_of(s) == "replay" for s in rec.spans
+               if s["name"] in ("replay_run",))
+    top = sorted((s for s in rec.spans if s["parent"] == -1 and s["dur"]),
+                 key=lambda s: s["ts"])
+    assert PerfRecorder._union_us(top) >= 0.95 * wall_us, (
+        PerfRecorder._union_us(top), wall_us)
+    # compile stages while a recorder is active land as counters too
+    assert rec.counters.get("compile.backend", 0) >= 1
+    assert rec.counters.get("compile.trace", 0) >= 1
+
+
+def test_compile_log_counts_stages_by_program():
+    """One trace + one lower + one backend event per new program, filed
+    under the site's name inside `program(...)` and under jax's function
+    name outside; a replay's compile goes to `replay.run`; a snapshot is
+    bounded by its window and a stage's total is a union (a nested jit
+    is not counted twice)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from madsim_tpu.perf import compile_log
+
+    log = compile_log.install()
+    assert compile_log.install() is log  # idempotent: one listener
+
+    def madsim_test_only_fn(x):  # no nested jit: primitives only
+        return x * 3 + 1
+
+    t0 = time.perf_counter()
+    n0 = len(log.events)
+    jax.jit(madsim_test_only_fn)(jnp.arange(7, dtype=jnp.int32)).block_until_ready()
+    mine = [e for e in log.events[n0:] if e[2] == "madsim_test_only_fn"]
+    assert sorted(e[1] for e in mine) == ["backend", "lower", "trace"]
+    snap = compile_log.snapshot(t0, time.perf_counter())
+    assert snap["by_program"]["madsim_test_only_fn"]["requests"] == 1
+    assert snap["requests"] >= 1
+    assert snap["trace_s"] > 0 and snap["backend_s"] > 0
+    assert compile_log.snapshot(0.0, t0 - 1.0)["by_program"].get(
+        "madsim_test_only_fn") is None
+
+    def other_fn(x):
+        return jnp.sin(x) + jnp.cumsum(x)  # cumsum is a nested jit
+
+    ones = jnp.ones((5,))  # made outside: an eager op is a program too
+    t1 = time.perf_counter()
+    with compile_log.program("site.name"):
+        jax.jit(other_fn)(ones).block_until_ready()
+    snap = compile_log.snapshot(t1, time.perf_counter())
+    site = snap["by_program"]["site.name"]
+    assert site["requests"] == 1 and "other_fn" not in snap["by_program"]
+    assert site["trace_s"] <= time.perf_counter() - t1  # a union, not a sum
+    assert compile_log.slowest(snap).split(" of ")[1].startswith("site.name")
+
+    # the replay's program, by the name of the site that first calls it
+    from madsim_tpu.__main__ import build_machine
+    from madsim_tpu.engine import Engine, EngineConfig, FaultPlan
+    from madsim_tpu.engine.replay import replay_outcome
+
+    eng = Engine(build_machine("echo", 0), EngineConfig(
+        horizon_us=500_000, queue_capacity=16, faults=FaultPlan(n_faults=0)))
+    t2 = time.perf_counter()
+    replay_outcome(eng, 3, max_steps=50)
+    first = compile_log.snapshot(t2, time.perf_counter())["by_program"]
+    assert first["replay.run"]["requests"] == 1
+    t3 = time.perf_counter()
+    replay_outcome(eng, 4, max_steps=50)  # same machine: no new program
+    assert "replay.run" not in compile_log.snapshot(
+        t3, time.perf_counter())["by_program"]
 
 
 def test_perf_timeline_written_on_failure(tmp_path):

@@ -1,10 +1,11 @@
-"""The three-clock profiler (madsim_tpu/perf/xprof): off-by-default
-gate discipline, device-trace parsing, the compile autopsy, the golden
+"""The three-clock profiler (madsim_tpu/perf/xprof): always-on phase
+scopes that move no cache key, a gate that only captures, device-trace
+parsing, the compile autopsy, the golden
 clock-alignment fixture for merge_plane, and the fleet /profile
 endpoint's degraded/full paths.
 
-Everything except the one compile-autopsy test is jax-free host math —
-hand-built trace documents with known clock offsets, no device work.
+The merge/parse half is jax-free host math — hand-built trace documents
+with known clock offsets; the scope tests lower a tiny engine on the CPU.
 """
 
 import gzip
@@ -15,35 +16,139 @@ import pytest
 
 from madsim_tpu.perf import xprof
 
-# -- gate discipline ---------------------------------------------------------
+# -- the gate captures; it selects no program ---------------------------------
+
+ENGINE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "madsim_tpu", "engine")
+
+STEP_SCOPES = ("step.pop", "step.rng", "step.handlers", "step.outbox",
+               "step.timers", "step.recorder", "step.coverage",
+               "step.invariants")
 
 
-def test_gate_off_inserts_nothing(monkeypatch):
-    """OFF (the default) must be bit-identity by construction: every
-    context helper returns the ONE shared nullcontext (no allocation,
-    nothing inserted into traced programs or host loops) and
-    sync_marker is a no-op returning None."""
+def _tiny_engine(megakernel=False, provenance=False):
+    from madsim_tpu.__main__ import build_machine
+    from madsim_tpu.engine import Engine, EngineConfig, FaultPlan
+
+    cfg = EngineConfig(
+        horizon_us=1_000_000, queue_capacity=16, faults=FaultPlan(n_faults=1),
+        rng_stream=3, flight_recorder=True, coverage=True,
+        provenance=provenance, pallas_megakernel=megakernel,
+    )
+    return Engine(build_machine("echo", 0), cfg,
+                  use_pallas_pop=True if megakernel else None)
+
+
+def _lowered_supersegment(eng, debug_info=True) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    init_carry, _seg, supersegment, _reset = eng._stream_fns(32, 200, 16, 8)
+    carry = jax.eval_shape(init_carry, jax.ShapeDtypeStruct((8,), jnp.uint32))
+    need = jax.ShapeDtypeStruct((), jnp.int32)
+    return supersegment.lower(carry, need).as_text(debug_info=debug_info)
+
+
+def test_gate_captures_and_selects_no_program(monkeypatch):
+    """MADSIM_TPU_XPROF means "capture a device profile" and nothing
+    else: set or unset, `scope` is a real named scope, the engine serves
+    the same `_stream_fns` cache entry and lowers the same text. Only
+    the capture side (sync markers) reads it."""
     monkeypatch.delenv(xprof.ENV_GATE, raising=False)
     assert not xprof.enabled()
-    assert xprof.annotation("step") is xprof._NULL_CTX
-    assert xprof.scope("step") is xprof._NULL_CTX
-    assert xprof.collective_scope("cov-map-or") is xprof._NULL_CTX
     assert xprof.sync_marker("anywhere") is None
-    monkeypatch.setenv(xprof.ENV_GATE, "0")
-    assert not xprof.enabled()
+    eng = _tiny_engine()
+    fns_off = eng._stream_fns(32, 200, 16, 8)
+    text_off = _lowered_supersegment(eng, debug_info=False)
     monkeypatch.setenv(xprof.ENV_GATE, "1")
     assert xprof.enabled()
+    assert eng._stream_fns(32, 200, 16, 8) is fns_off
+    assert len(eng._stream_cache) == 1
+    assert _lowered_supersegment(_tiny_engine(), debug_info=False) == text_off
+    assert "madsim.step.pop" in _lowered_supersegment(eng)
+    monkeypatch.setenv(xprof.ENV_GATE, "0")
+    assert not xprof.enabled()
 
 
-def test_stream_fns_cache_keyed_on_gate():
-    """Flipping MADSIM_TPU_XPROF between runs must re-trace: the
-    engine folds the gate into its stream-fns cache key (source pin —
-    a stale cache entry would silently serve unannotated programs
-    under a live gate, or vice versa)."""
-    src = open(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "madsim_tpu", "engine", "core.py")).read()
-    assert "xprof.enabled()" in src
+def test_engine_reads_no_measurement_gate():
+    """Source pin: nothing under madsim_tpu/engine reads the gate or
+    annotates beside a recorder span (one name per site: the span,
+    which an annotating recorder writes into the capture)."""
+    for name in os.listdir(ENGINE_DIR):
+        if name.endswith(".py"):
+            src = open(os.path.join(ENGINE_DIR, name)).read()
+            assert "xprof.enabled()" not in src, name
+            assert "_xprof.annotation" not in src, name
+
+
+@pytest.mark.parametrize("megakernel", [False, True],
+                         ids=["xla-step", "megakernel"])
+def test_lowered_stream_names_every_step_scope(megakernel):
+    """Every cost-model row of the step is a `madsim.step.*` scope in the
+    lowered supersegment, on both step paths; every name the program
+    emits is listed in DEVICE_PHASES (collectives apart)."""
+    import re
+
+    text = _lowered_supersegment(_tiny_engine(megakernel, provenance=True))
+    found = set(re.findall(r"madsim\.([A-Za-z0-9_.\-]+)", text))
+    for name in STEP_SCOPES + ("step.provenance", "step", "refill",
+                               "harvest", "cov_flush", "counters"):
+        assert name in found, (name, sorted(found))
+    assert {n for n in found if not n.startswith("collective.")} \
+        <= set(xprof.DEVICE_PHASES), sorted(found)
+
+
+def test_persistent_cache_key_does_not_move_with_scopes(tmp_path, monkeypatch):
+    """THE reason the scopes can be on always: a name stack lives in MLIR
+    locations, and jax strips debug info before hashing a module for the
+    persistent cache. Fill a fresh cache with `jax.named_scope` patched
+    to a null context, then run the real program: zero cache misses."""
+    import contextlib
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    misses, hits = [], []
+
+    def on_event(event, **_kw):
+        if event == "/jax/compilation_cache/cache_misses":
+            misses.append(event)
+        elif event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    jax.monitoring.register_event_listener(on_event)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+
+    def stream_once():
+        jax.clear_caches()
+        return _tiny_engine().run_stream(
+            8, batch=8, segment_steps=32, max_steps=200)
+
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        with monkeypatch.context() as m:
+            m.setattr(jax, "named_scope",
+                      lambda _name: contextlib.nullcontext())
+            bare = stream_once()
+        assert misses, "the bare run must have filled the cache"
+        del misses[:], hits[:]
+        scoped = stream_once()
+        assert not misses and hits, (len(misses), len(hits))
+        assert scoped["completed"] == bare["completed"]
+        assert scoped["failing"] == bare["failing"]
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        jax.clear_caches()
 
 
 # -- device-trace parsing ----------------------------------------------------
