@@ -23,6 +23,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -32,63 +33,43 @@ import sys
 def build_machine(name: str, nodes: int = 0):
     """CLI machine registry — also the resolver corpus entries use to
     rebuild their machine from (name, nodes). The demo-* entries are
-    deliberately buggy variants (each models a classic bug class) so the
-    hunt -> shrink -> replay -> corpus workflow is demonstrable without
-    writing a protocol first."""
+    deliberately buggy variants (each models a classic bug class, and
+    lives beside its model) so the hunt -> shrink -> replay -> corpus
+    workflow is demonstrable without writing a protocol first.
+
+    One object per (name, nodes) per process: the compiled-replay cache
+    hangs on the machine object (engine/replay.py `_replay_cache`), so
+    `hunt`, `regress`, `audit` and the fleet worker's jobs ask for a
+    replay program their process already lowered instead of lowering it
+    again per call. Sound because a machine is never mutated after
+    construction, apart from the caches it carries (the engine reads
+    its constants and calls its handlers; nothing writes to it)."""
+    return _registry_machine(name, int(nodes or 0))
+
+
+@functools.lru_cache(maxsize=None)
+def _registry_machine(name: str, nodes: int):
     from .models.echo import EchoMachine
-    from .models.etcd import EtcdMachine
-    from .models.etcd_mvcc import EtcdMvccMachine
-    from .models.gossip import GossipMachine
+    from .models.etcd import DoubleGrantEtcd, EtcdMachine
+    from .models.etcd_mvcc import (
+        EtcdMvccMachine, NoDedupMvcc, PrematureGiveupMvcc,
+    )
+    from .models.gossip import DupAckGossip, GossipMachine
     from .models.kafka_group import KafkaGroupMachine, NoFencingGroupMachine
     from .models.kv import KvMachine
     from .models.mq import MqMachine
     from .models.multipaxos import MultiPaxosMachine, NoPromiseCheckMultiPaxos
     from .models.paxos import NoPromiseCheckPaxos, PaxosMachine
-    from .models.raft import RaftMachine
+    from .models.raft import (
+        DupVoteRaft, OvercommitRaft, QuorumOffByOneRaft, RaftMachine,
+        VolatileCommitRaft,
+    )
     from .models.raft_compact import RaftCompactMachine, TornSnapshotRaftCompact
-    from .models.s3 import S3Machine
+    from .models.s3 import (
+        AbortLeakS3, ArrivalOrderS3, EarlyExpiryS3, NoDedupS3, S3Machine,
+        TombstoneLeakS3,
+    )
     from .models.twopc import TwoPcMachine
-
-    class DoubleGrantEtcd(EtcdMachine):
-        CHECK_OWNER_ON_CAMPAIGN = False  # non-atomic election txn
-
-    class OvercommitRaft(RaftMachine):
-        COMMIT_TO_LOG_LEN = True  # Raft §5.3 commit-bound bug
-
-    class QuorumOffByOneRaft(RaftMachine):
-        QUORUM_OFF_BY_ONE = True  # commit below majority (needs group faults)
-
-    class VolatileCommitRaft(RaftMachine):
-        PERSIST_COMMIT_NOT_LOG = True  # durable commitIndex, volatile log
-        #                                (caught only by --strict-restart)
-
-    class DupVoteRaft(RaftMachine):
-        DUP_VOTE_COUNT = True  # per-message vote tally (caught by dup chaos)
-
-    class NoDedupMvcc(EtcdMvccMachine):
-        NO_DEDUP = True  # retransmits double-apply (needs storms/dir clogs)
-
-    class PrematureGiveupMvcc(EtcdMvccMachine):
-        PREMATURE_GIVEUP = True  # deadline-RPC timeout mishandling
-        #                          (reachable only by the delay kind)
-
-    class ArrivalOrderS3(S3Machine):
-        CONCAT_ARRIVAL_ORDER = True  # complete concats in upload order
-
-    class AbortLeakS3(S3Machine):
-        ABORT_KEEPS_PARTS = True  # abort leaks the session's parts
-
-    class EarlyExpiryS3(S3Machine):
-        LC_EARLY_HALF = True  # lifecycle expires at half the configured age
-
-    class TombstoneLeakS3(S3Machine):
-        LC_TOMBSTONE_LEAK = True  # expiry clears existence but not content
-
-    class NoDedupS3(S3Machine):
-        NO_DEDUP = True  # retried puts double-apply
-
-    class DupAckGossip(GossipMachine):
-        DUP_ACK_COUNT = True  # quorum tally counts duplicate acks
 
     machines = {
         "echo": lambda: EchoMachine(rounds=10),

@@ -76,6 +76,18 @@ def fr_variant(engine: Engine, every: int, ring: int) -> Engine:
     return Engine(engine.machine, cfg, use_pallas_pop=engine.use_pallas_pop)
 
 
+def trail_ring(max_steps: int, every: int) -> int:
+    """Ring slots for a trail of up to `max_steps` steps: the
+    `max_steps // every + 2` that never wraps, rounded up to a power of
+    two, floor 8. The ring is a SHAPE of the replay program, and a
+    corpus entry's `max_steps` is the step count of the seed it shrank:
+    sized exactly, every find would lower a program of its own; in
+    buckets, the finds of one machine share a handful. The trail does
+    not see the size — a ring that never wraps holds each checkpoint
+    once, and `decode_checkpoint_ring` skips the unused slots."""
+    return max(8, 1 << (max_steps // every + 1).bit_length())
+
+
 def collect_trail(
     engine: Engine,
     seed: int,
@@ -94,7 +106,7 @@ def collect_trail(
         or engine.config.fr_digest_every != every
         or engine.config.fr_digest_ring * every <= max_steps
     ):
-        eng = fr_variant(engine, every, max_steps // every + 2)
+        eng = fr_variant(engine, every, trail_ring(max_steps, every))
     rp = replay(eng, seed, max_steps=max_steps, trace=False)
     fr = rp.state.fr
     return DigestTrail(

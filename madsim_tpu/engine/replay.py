@@ -20,7 +20,7 @@ import jax
 
 from ..ops import pop_earliest
 from ..perf import compile_log
-from ..perf.recorder import maybe_note, maybe_span
+from ..perf.recorder import maybe_count, maybe_note, maybe_span
 from .core import EV_FAULT, EV_MSG, EV_TIMER, Engine, LaneState
 
 _KIND_NAMES = {EV_TIMER: "timer", EV_MSG: "msg", EV_FAULT: "fault"}
@@ -151,38 +151,112 @@ def _replay_cache(engine: Engine) -> dict:
     wrapping the same machine shares it (shrink builds a fresh Engine per
     candidate config; without sharing, each candidate pays a multi-second
     lane_step compile — the measured 10x collapse of high-find-rate
-    hunts was exactly this, not the stream drain)."""
+    hunts was exactly this, not the stream drain). The CLI registry
+    hands out one machine object per name per process
+    (`__main__.build_machine`), so `record_entry`, `regress`, `audit` and
+    a fleet worker's next job share it too; a library user's own
+    `Machine` carries its own cache, and no two machine objects ever
+    share one.
+
+    What the key promises: two engines over one machine get the same
+    program exactly when `_trace_affecting_key` is equal for both, and
+    then nothing `lane_step` reads — off the config, the engine or the
+    state's shapes — differs between them. The jitted closure keeps a
+    twin of the FIRST engine that asked, so the key has to be complete:
+    every `EngineConfig` / `FaultPlan` field is named in one of the
+    tables below (a test enumerates the dataclasses), and what
+    `Engine.__init__` derives for the step (`_rng_layout`,
+    `cov_band_bits`) is in the key by value or follows from fields that
+    are (`_cov_buffered`)."""
     return engine.machine.__dict__.setdefault("_replay_jit_cache", {})
 
 
+# Where each EngineConfig / FaultPlan field stands with the step program.
+# `lane_step` reads the _KEY_* fields directly: they are in the key by
+# value, the recorder's and coverage's sizes only while their gate is on
+# (a gate that is off traces none of them, and corpus entries drop both).
+_KEY_CONFIG = (
+    "queue_capacity", "latency_min_us", "latency_max_us",
+    "packet_loss_rate", "handler_rand_words", "trace_ring", "clog_packed",
+    "provenance", "flight_recorder", "coverage",
+)
+_KEY_IF_RECORDER = ("fr_digest_every", "fr_digest_ring")
+_KEY_IF_COVERAGE = ("cov_slots_log2", "cov_buffer")
+# the PR-5/PR-6 chaos gates compiled INTO the step (defer logic, skew
+# scaling, amnesia/torn restarts, asymmetric-heal word ops)
+_KEY_FAULTS = (
+    "allow_pause", "allow_skew", "strict_restart", "allow_torn",
+    "allow_heal_asym",
+)
+# Fields the step reads only through an attribute Engine.__init__
+# derives from them; the key holds that attribute, so two settings that
+# give the same step (loss already possible, one more storm kind) share
+# a program as they always did.
+_KEY_DERIVED = {
+    "rng_stream": "_rng_layout",       # stream version + word-block layout
+    "allow_kill": "_rng_layout",       # the restart key's section
+    "allow_storm": "_rng_layout",      # loss section (with packet_loss_rate)
+    "allow_delay": "_rng_layout",      # spike section
+    "allow_dup": "_rng_layout",        # dup section (and cov_band_bits)
+    "cov_band_bits_min": "cov_band_bits",  # read while coverage is on
+}
+# Fields no step program depends on: they shape the initial state's
+# VALUES (`init_lane` runs eagerly, outside the program, and the fault
+# schedule's slots live inside the fixed queue_capacity), ride as traced
+# scalars, or only steer the host / the batched executor.
+_INIT_ONLY = frozenset({
+    "horizon_us",         # traced scalar (the eager step path keys it itself)
+    "faults",             # the FaultPlan: placed field by field
+    "n_faults",           # how many schedule slots init_lane fills
+    "allow_partition",    # the legacy kinds: which ops the schedule draws
+    "allow_dir_clog",     # (the fault branch handles every op regardless)
+    "allow_group",
+    "storm_loss_u16",     # a payload value in the schedule
+    "t_min_us", "t_max_us", "dur_min_us", "dur_max_us",  # schedule draws
+    "pallas_megakernel",  # step_batch's kernel choice: never in lane_step
+    "compile_cache_dir",  # host-side
+})
+
+
 def _trace_affecting_key(engine: Engine) -> tuple:
-    """Config fields that change the lane_step trace. horizon_us is
-    deliberately absent: the replay paths pass it as a traced value."""
+    """Everything the lane_step trace depends on besides the machine
+    (tables above). `Engine.use_pallas_pop` / `use_megakernel` are absent
+    like `pallas_megakernel`: they pick step_batch's batched pop, which
+    no single-lane replay runs."""
     cfg = engine.config
     return (
-        cfg.queue_capacity,
-        cfg.latency_min_us,
-        cfg.latency_max_us,
-        cfg.packet_loss_rate,
-        cfg.handler_rand_words,
-        cfg.trace_ring,
-        cfg.clog_packed,
-        cfg.flight_recorder,
-        cfg.fr_digest_every,
-        cfg.fr_digest_ring,
-        # PR-5/PR-6 chaos gates compiled INTO the step (defer logic,
-        # skew scaling, amnesia/torn restarts, asymmetric-heal word
-        # ops) — unlike the legacy kinds, which only shape the schedule
-        # in the initial state
-        cfg.faults.allow_pause,
-        cfg.faults.allow_skew,
-        cfg.faults.strict_restart,
-        cfg.faults.allow_torn,
-        cfg.faults.allow_heal_asym,
-        cfg.provenance,  # lineage words compiled into the step
-        engine._rng_layout,  # stream version + word-block layout (incl. dup)
-        engine.use_pallas_pop,
+        tuple(getattr(cfg, f) for f in _KEY_CONFIG),
+        tuple(getattr(cfg, f) for f in _KEY_IF_RECORDER)
+        if cfg.flight_recorder else None,
+        (*(getattr(cfg, f) for f in _KEY_IF_COVERAGE), engine.cov_band_bits)
+        if cfg.coverage else None,
+        tuple(getattr(cfg.faults, f) for f in _KEY_FAULTS),
+        engine._rng_layout,
     )
+
+
+def _program(engine: Engine, key: tuple, build: Callable[[], Any]):
+    """The cached replay program under `key`, built on a miss; whether
+    it was a miss is the caller's cue to time its first call as a
+    `compile`. Hits and misses are counted and noted on the open
+    `replay` span."""
+    cache = _replay_cache(engine)
+    fresh = key not in cache
+    if fresh:
+        cache[key] = build()
+    maybe_count("replay.program_miss" if fresh else "replay.program_hit")
+    maybe_note(program_hit=not fresh)
+    return cache[key], fresh
+
+
+def _bare_twin(engine: Engine) -> Engine:
+    """What a program's closure keeps in place of its first asker: the
+    same machine, config and pop path, none of what the asker accrued.
+    A hunt's engine carries its compiled stream programs, and the cache
+    outlives it by the life of the machine — of the process, for a
+    registry machine."""
+    return Engine(engine.machine, engine.config,
+                  use_pallas_pop=engine.use_pallas_pop)
 
 
 def _fast_outcome_fn(engine: Engine):
@@ -193,24 +267,23 @@ def _fast_outcome_fn(engine: Engine):
     compile serves every shrink candidate and every seed."""
     from jax import lax
 
-    cache = _replay_cache(engine)
-    key = ("fast-outcome", _trace_affecting_key(engine))
-    fresh = key not in cache
-    if fresh:
+    def build():
+        twin = _bare_twin(engine)
 
         def run(state: LaneState, horizon_us, n_steps):
             def body(_i, s):
                 return lax.cond(
                     s.done | s.failed,
                     lambda x: x,
-                    lambda x: engine.lane_step(x, horizon_us=horizon_us),
+                    lambda x: twin.lane_step(x, horizon_us=horizon_us),
                     s,
                 )
 
             return lax.fori_loop(0, n_steps, body, state)
 
-        cache[key] = jax.jit(run)
-    return cache[key], fresh
+        return jax.jit(run)
+
+    return _program(engine, ("fast-outcome", _trace_affecting_key(engine)), build)
 
 
 @contextlib.contextmanager
@@ -268,12 +341,9 @@ def replay(
         # exact and threefry is backend-stable), but the replay materializes
         # the full state between events so hooks can inspect anything.
         # Cached on the machine so repeated replays don't recompile.
-        cache = _replay_cache(engine)
         skey = ("trace-step", _trace_affecting_key(engine), engine.config.horizon_us)
-        fresh = skey not in cache
-        if fresh:
-            cache[skey] = jax.jit(engine.lane_step)
-        step_fn = cache[skey]
+        step_fn, fresh = _program(
+            engine, skey, lambda: jax.jit(_bare_twin(engine).lane_step))
         events: List[TraceEvent] = []
         step = 0
         prov_on = engine.config.provenance

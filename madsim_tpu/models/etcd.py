@@ -394,3 +394,9 @@ class EtcdMachine(Machine):
             | (leases << 6)
             | (writes_b << 8)
         ).astype(jnp.uint32)
+
+
+class DoubleGrantEtcd(EtcdMachine):
+    """Bug variant (`demo-doublegrant-etcd`): a non-atomic election txn."""
+
+    CHECK_OWNER_ON_CAMPAIGN = False

@@ -523,3 +523,17 @@ class EtcdMvccMachine(Machine):
             "applied": nodes.applied[SERVER],
             "ops_acked": jnp.sum(nodes.acked[1:]),
         }
+
+
+class NoDedupMvcc(EtcdMvccMachine):
+    """Bug variant (`demo-nodedup-mvcc`): retransmits double-apply
+    (needs storms / directional clogs)."""
+
+    NO_DEDUP = True
+
+
+class PrematureGiveupMvcc(EtcdMvccMachine):
+    """Bug variant (`demo-giveup-mvcc`): deadline-RPC timeout
+    mishandling (reachable only by the delay kind)."""
+
+    PREMATURE_GIVEUP = True

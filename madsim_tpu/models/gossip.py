@@ -247,3 +247,10 @@ class GossipMachine(Machine):
             "coverage": nodes.holds.sum(dtype=jnp.int32),
             "acks": nodes.ack_cnt[origins, jnp.arange(self.R)].sum(dtype=jnp.int32),
         }
+
+
+class DupAckGossip(GossipMachine):
+    """Bug variant (`demo-dupack-gossip`): the quorum tally counts
+    duplicate acks."""
+
+    DUP_ACK_COUNT = True

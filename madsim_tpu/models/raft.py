@@ -562,3 +562,31 @@ class RaftMachine(Machine):
             | (term_delta << 8)
             | (candidates << 10)
         ).astype(jnp.uint32)
+
+
+class OvercommitRaft(RaftMachine):
+    """Bug variant (`demo-overcommit-raft`): the Raft §5.3 commit-bound
+    bug."""
+
+    COMMIT_TO_LOG_LEN = True
+
+
+class QuorumOffByOneRaft(RaftMachine):
+    """Bug variant (`demo-quorumoffbyone-raft`): commits below a
+    majority (needs group faults)."""
+
+    QUORUM_OFF_BY_ONE = True
+
+
+class VolatileCommitRaft(RaftMachine):
+    """Bug variant (`demo-volatilecommit-raft`): durable commitIndex,
+    volatile log (caught only by --strict-restart)."""
+
+    PERSIST_COMMIT_NOT_LOG = True
+
+
+class DupVoteRaft(RaftMachine):
+    """Bug variant (`demo-dupvote-raft`): a per-message vote tally
+    (caught by dup chaos)."""
+
+    DUP_VOTE_COUNT = True

@@ -477,3 +477,37 @@ class S3Machine(Machine):
             "writes_applied": jnp.sum(nodes.writes_applied[SERVER]),
             "ops_acked": jnp.sum(nodes.acked[1:]),
         }
+
+
+class ArrivalOrderS3(S3Machine):
+    """Bug variant (`demo-arrivalorder-s3`): complete concatenates parts
+    in upload order."""
+
+    CONCAT_ARRIVAL_ORDER = True
+
+
+class AbortLeakS3(S3Machine):
+    """Bug variant (`demo-abortleak-s3`): abort leaks the session's
+    parts."""
+
+    ABORT_KEEPS_PARTS = True
+
+
+class EarlyExpiryS3(S3Machine):
+    """Bug variant (`demo-earlyexpiry-s3`): lifecycle expires at half
+    the configured age."""
+
+    LC_EARLY_HALF = True
+
+
+class TombstoneLeakS3(S3Machine):
+    """Bug variant (`demo-tombstoneleak-s3`): expiry clears existence
+    but not content."""
+
+    LC_TOMBSTONE_LEAK = True
+
+
+class NoDedupS3(S3Machine):
+    """Bug variant (`demo-nodedup-s3`): retried puts double-apply."""
+
+    NO_DEDUP = True

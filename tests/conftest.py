@@ -12,6 +12,9 @@ deserialize instead of rebuild; `JAX_COMPILATION_CACHE_DIR` or
 """
 
 import os
+import sys
+
+import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
@@ -27,3 +30,15 @@ def pytest_configure(config):
         "slow: long-running suites (full engine sweeps, soak); excluded "
         "from the tier-1 fast gate via -m 'not slow'",
     )
+
+
+@pytest.fixture(autouse=True)
+def _fresh_machine_registry():
+    """`build_machine` hands out one machine object per name per process,
+    and a machine carries the replay programs compiled on it: every test
+    starts with none, so what a test compiles (and asserts it compiles)
+    does not depend on which tests its worker ran before."""
+    cli = sys.modules.get("madsim_tpu.__main__")
+    if cli is not None:
+        cli._registry_machine.cache_clear()
+    yield

@@ -102,7 +102,7 @@ def test_fast_outcome_replay_matches_eager_replay(raft_engine):
     recompiles were the measured hunt-throughput collapse)."""
     import dataclasses as dc
 
-    from madsim_tpu.engine.replay import replay_outcome
+    from madsim_tpu.engine.replay import _replay_cache, replay_outcome
 
     for seed in (0, 3, 66531 % 7):
         eager = replay(raft_engine, seed, max_steps=3000, trace=True)
@@ -119,7 +119,7 @@ def test_fast_outcome_replay_matches_eager_replay(raft_engine):
     # same machine, different horizon/fault-count config: no new cache
     # entry for the fast path (horizon + max_steps are traced, n_faults
     # only shapes init) — candidate verification is compile-free
-    cache = raft_engine.machine.__dict__["_replay_jit_cache"]
+    cache = _replay_cache(raft_engine)
     n_before = len(cache)
     cand_cfg = dc.replace(
         raft_engine.config,
