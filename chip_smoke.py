@@ -353,10 +353,10 @@ def device_trail(entry, build_machine):
     default step path, recorder on at the entry's cadence."""
     import jax.numpy as jnp
 
-    from madsim_tpu.engine import Engine, audit
+    from madsim_tpu.engine import Engine, audit, corpus
 
     eng = audit.fr_variant(
-        Engine(build_machine(entry.machine, entry.nodes), entry.config),
+        Engine(corpus.entry_machine(entry, build_machine), entry.config),
         entry.digest_every, entry.max_steps // entry.digest_every + 2,
     )
     res = eng.make_runner(max_steps=entry.max_steps)(

@@ -30,25 +30,27 @@ import os
 import sys
 
 
-def build_machine(name: str, nodes: int = 0):
+def build_machine(name: str, nodes: int = 0, log_capacity: int = 0):
     """CLI machine registry — also the resolver corpus entries use to
     rebuild their machine from (name, nodes). The demo-* entries are
     deliberately buggy variants (each models a classic bug class, and
     lives beside its model) so the hunt -> shrink -> replay -> corpus
     workflow is demonstrable without writing a protocol first.
 
-    One object per (name, nodes) per process: the compiled-replay cache
+    `log_capacity` (0 = the registry's 8) sizes the raft machines' log
+    (`--log-capacity`; a corpus entry records it). One object per
+    (name, nodes, log_capacity) per process: the compiled-replay cache
     hangs on the machine object (engine/replay.py `_replay_cache`), so
     `hunt`, `regress`, `audit` and the fleet worker's jobs ask for a
     replay program their process already lowered instead of lowering it
     again per call. Sound because a machine is never mutated after
     construction, apart from the caches it carries (the engine reads
     its constants and calls its handlers; nothing writes to it)."""
-    return _registry_machine(name, int(nodes or 0))
+    return _registry_machine(name, int(nodes or 0), int(log_capacity or 0))
 
 
 @functools.lru_cache(maxsize=None)
-def _registry_machine(name: str, nodes: int):
+def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
     from .models.echo import EchoMachine
     from .models.etcd import DoubleGrantEtcd, EtcdMachine
     from .models.etcd_mvcc import (
@@ -61,8 +63,8 @@ def _registry_machine(name: str, nodes: int):
     from .models.multipaxos import MultiPaxosMachine, NoPromiseCheckMultiPaxos
     from .models.paxos import NoPromiseCheckPaxos, PaxosMachine
     from .models.raft import (
-        DupVoteRaft, OvercommitRaft, QuorumOffByOneRaft, RaftMachine,
-        VolatileCommitRaft,
+        DupVoteRaft, Fig8Raft, OvercommitRaft, QuorumOffByOneRaft,
+        RaftMachine, VolatileCommitRaft,
     )
     from .models.raft_compact import RaftCompactMachine, TornSnapshotRaftCompact
     from .models.s3 import (
@@ -71,9 +73,10 @@ def _registry_machine(name: str, nodes: int):
     )
     from .models.twopc import TwoPcMachine
 
+    cap = log_capacity or 8
     machines = {
         "echo": lambda: EchoMachine(rounds=10),
-        "raft": lambda: RaftMachine(num_nodes=nodes or 5, log_capacity=8),
+        "raft": lambda: RaftMachine(num_nodes=nodes or 5, log_capacity=cap),
         "kv": lambda: KvMachine(num_nodes=nodes or 4),
         "mq": lambda: MqMachine(num_nodes=nodes or 4),
         "etcd": lambda: EtcdMachine(num_nodes=nodes or 4),
@@ -87,23 +90,26 @@ def _registry_machine(name: str, nodes: int):
             num_nodes=nodes or 4, target_gens=99, target_writes=9999
         ),
         "demo-overcommit-raft": lambda: OvercommitRaft(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "demo-nofencing-group": lambda: NoFencingGroupMachine(num_nodes=nodes or 4),
         "demo-quorumoffbyone-raft": lambda: QuorumOffByOneRaft(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "demo-volatilecommit-raft": lambda: VolatileCommitRaft(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "demo-dupvote-raft": lambda: DupVoteRaft(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
+        ),
+        "demo-fig8-raft": lambda: Fig8Raft(
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "raft-compact": lambda: RaftCompactMachine(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "demo-tornsnapshot-raft": lambda: TornSnapshotRaftCompact(
-            num_nodes=nodes or 5, log_capacity=8
+            num_nodes=nodes or 5, log_capacity=cap
         ),
         "demo-nodedup-mvcc": lambda: NoDedupMvcc(num_nodes=nodes or 4),
         "demo-giveup-mvcc": lambda: PrematureGiveupMvcc(num_nodes=nodes or 4),
@@ -121,7 +127,10 @@ def _registry_machine(name: str, nodes: int):
     }
     if name not in machines:
         sys.exit(f"unknown machine {name!r}; choose from {sorted(machines)}")
-    return machines[name]()
+    machine = machines[name]()
+    if log_capacity and getattr(machine, "log_capacity", None) != log_capacity:
+        sys.exit(f"--log-capacity sizes a raft machine's log; {name!r} has none")
+    return machine
 
 
 def _build_engine(args):
@@ -138,8 +147,11 @@ def _build_engine(args):
 
 
 def _build_engine_inner(args, Engine, EngineConfig, FaultPlan):
-    machine = build_machine(args.machine, args.nodes)
+    machine = build_machine(
+        args.machine, args.nodes, getattr(args, "log_capacity", None) or 0
+    )
     cfg = EngineConfig(
+        **_latency_fields(args),
         # guided hunts pin the 4-bit coverage band layout so the slot
         # space stays identical across fault-vocabulary escalations
         # (madsim_tpu/search); 0 keeps the derived layout — bit-for-bit
@@ -168,6 +180,7 @@ def _build_engine_inner(args, Engine, EngineConfig, FaultPlan):
             dur_max_us=800_000,
             strict_restart=bool(getattr(args, "strict_restart", False)),
             **_fault_kind_flags(args),
+            **_churn_fields(args),
         ),
     )
     if _device_count(args) > 1:
@@ -176,6 +189,64 @@ def _build_engine_inner(args, Engine, EngineConfig, FaultPlan):
         # caller forces onto a mesh)
         return Engine.on_xla_step_path(machine, cfg)
     return Engine(machine, cfg)
+
+
+def _latency_fields(args) -> dict:
+    """`--latency MIN_US,MAX_US`: the send latency's uniform range
+    (unset: the engine's 1-10 ms)."""
+    raw = getattr(args, "latency", None)
+    if not raw:
+        return {}
+    try:
+        lo, hi = (int(x) for x in raw.split(","))
+    except ValueError:
+        sys.exit(f"--latency {raw!r}: expected MIN_US,MAX_US")
+    if not 0 <= lo < hi:
+        sys.exit(f"--latency {raw!r}: need 0 <= MIN_US < MAX_US")
+    return {"latency_min_us": lo, "latency_max_us": hi}
+
+
+def _churn_fields(args) -> dict:
+    """`--churn NAME --churn-until S`: the fault process beside the
+    schedule (engine/core.py `ChurnPlan`; unset: none)."""
+    name = getattr(args, "churn", None)
+    if not name:
+        return {}
+    from .engine.core import CHURN_PRESETS
+
+    if name not in CHURN_PRESETS:
+        sys.exit(f"unknown --churn {name!r}; choose from {sorted(CHURN_PRESETS)}")
+    until = getattr(args, "churn_until", None)
+    if not until or until <= 0:
+        sys.exit("--churn needs --churn-until S > 0: the virtual second at "
+                 "which every node is reconnected")
+    return {"churn": CHURN_PRESETS[name], "churn_until_us": round(until * 1e6)}
+
+
+def deployment_flags_str(churn, churn_until, log_capacity, latency) -> str:
+    """The flags of `_latency_fields` / `_churn_fields` / the log size,
+    as a repro line carries them ('' for each that is unset; ends in a
+    space otherwise)."""
+    return (
+        (f"--churn {churn} --churn-until {churn_until} " if churn else "")
+        + (f"--log-capacity {log_capacity} " if log_capacity else "")
+        + (f"--latency {latency} " if latency else "")
+    )
+
+
+def config_deployment_flags_str(cfg, log_capacity: int = 0) -> str:
+    """`deployment_flags_str` of an EngineConfig (a shrunk one)."""
+    from .engine.core import CHURN_PRESETS, EngineConfig
+
+    f = cfg.faults
+    churn = next((k for k, v in CHURN_PRESETS.items() if v == f.churn), None)
+    base = EngineConfig()
+    lat = (cfg.latency_min_us, cfg.latency_max_us)
+    return deployment_flags_str(
+        churn, f.churn_until_us / 1e6, log_capacity,
+        None if lat == (base.latency_min_us, base.latency_max_us)
+        else f"{lat[0]},{lat[1]}",
+    )
 
 
 def _fault_kind_flags(args) -> dict:
@@ -223,6 +294,10 @@ def _repro_line(args, seed) -> str:
         f"--fault-kinds {getattr(args, 'fault_kinds', 'pair,kill')} "
         f"--rng-stream {getattr(args, 'rng_stream', 2)} "
         + ("--strict-restart " if getattr(args, "strict_restart", False) else "")
+        + deployment_flags_str(
+            getattr(args, "churn", None), getattr(args, "churn_until", None),
+            getattr(args, "log_capacity", None), getattr(args, "latency", None),
+        )
         + (
             f"--devices {args.devices} "
             if getattr(args, "devices", 0)
@@ -319,6 +394,10 @@ def _print_fr_stats(stats) -> None:
         )
         if fr.get(key)
     )
+    if fr.get("churn"):
+        c = fr["churn"]
+        extra += (f", churn {c['ticks']} ticks / {c['disconnects']} "
+                  f"disconnects / {c['reconnects']} reconnects")
     print(
         f"flight recorder: faults injected [{inj or 'none'}]{extra}, "
         f"queue hwm {fr['queue_hwm']}, clogged-links hwm {fr['clog_links_hwm']}, "
@@ -968,6 +1047,7 @@ def cmd_hunt(args) -> int:
         entry = corpus.CorpusEntry(
             machine=args.machine,
             nodes=args.nodes,
+            log_capacity=getattr(args, "log_capacity", None) or 0,
             seed=seed,
             fail_code=code,
             status=corpus.STATUS_OPEN,
@@ -1263,6 +1343,8 @@ def cmd_shrink(args) -> int:
         # kinds from the SHRUNK plan — ablation may have dropped some
         f"--fault-kinds {fault_kinds_str(f)} "
         + ("--strict-restart " if f.strict_restart else "")
+        + config_deployment_flags_str(
+            sr.shrunk, getattr(args, "log_capacity", None) or 0)
         + f"--rng-stream {sr.shrunk.rng_stream}"
     )
     return 0
@@ -2134,6 +2216,29 @@ def main(argv=None) -> int:
             "illegally-kept volatile state becomes findable",
         )
         p.add_argument(
+            "--churn", default=None, metavar="NAME",
+            help="a fault PROCESS beside the scheduled faults: ticks that "
+            "draw their faults as they fire, one queue slot however many. "
+            "fig8 = 6.824 TestFigure8Unreliable2C's loop: every U[0,13) ms "
+            "(10%% of ticks U[0,500) ms) disconnect the leader w.p. 1/2, "
+            "reconnect a random node while under a majority is connected; "
+            "needs --churn-until",
+        )
+        p.add_argument(
+            "--churn-until", type=float, default=None, metavar="S",
+            help="virtual second at which --churn reconnects every node "
+            "and stops",
+        )
+        p.add_argument(
+            "--log-capacity", type=int, default=None, metavar="N",
+            help="log entries a node of a raft machine can hold (default "
+            "8: a lane ends when every node has committed a full log)",
+        )
+        p.add_argument(
+            "--latency", default=None, metavar="MIN_US,MAX_US",
+            help="uniform send latency in virtual us (default 1000,10000)",
+        )
+        p.add_argument(
             "--rng-stream", type=int, default=2, choices=(2, 3),
             help="per-step RNG stream version: 2 = legacy split-chain "
             "(default; replays every recorded seed), 3 = counter-based "
@@ -2725,6 +2830,10 @@ def main(argv=None) -> int:
     q.add_argument("--fault-kinds", default="pair,kill")
     q.add_argument("--rng-stream", type=int, default=2, choices=(2, 3))
     q.add_argument("--strict-restart", action="store_true")
+    q.add_argument("--churn", default="", metavar="NAME")
+    q.add_argument("--churn-until", type=float, default=0.0, metavar="S")
+    q.add_argument("--log-capacity", type=int, default=0, metavar="N")
+    q.add_argument("--latency", default="", metavar="MIN_US,MAX_US")
     q.add_argument("--coverage", action="store_true")
     q.add_argument("--provenance", action="store_true")
     q.add_argument("--flight-recorder", action="store_true")

@@ -217,6 +217,10 @@ CARRY_AXES: Dict[str, Dict[str, str]] = {
                 "eq_src", "eq_payload", "eq_valid", "clogged", "killed",
                 "paused_until", "skew_q10", "node_prov", "eq_prov",
                 "fail_prov", "nodes", "ring", "fr", "cov",
+                # the churn process's per-lane book (FaultPlan.churn): its
+                # stream key, the disconnected set, the applied-fault
+                # counters — {} (no leaf) while the process is off
+                "churn",
             )
         },
         # dotted rows: documented sub-leaves of a dict-typed leaf (the

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional
 
 from .engine.core import (
     EV_FAULT,
+    F_CHURN_TICK,
     F_CLOG_DIR,
     F_CLOG_GROUP,
     F_CLOG_PAIR,
@@ -54,6 +55,152 @@ from .engine.core import (
 )
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# -- the churn process's plain reference (no jax) ------------------------------
+#
+# `FaultPlan.churn` draws its faults as they fire, and the victim of a
+# disconnect is read off the simulation (the leader), so `fault_schedule`
+# — a pure function of (seed, FaultPlan) — cannot give them. What IS a
+# function of the seed alone is re-derived here in plain Python ints:
+# the tick times, the coins and the reconnect picks. The victim comes
+# from a callback. The device side of the comparison is
+# `applied_churn_faults`: what the CPU replay's lanes really applied.
+
+CHURN_DISCONNECT = "disconnect"
+CHURN_RECONNECT = "reconnect"
+_CHURN_KEY_TAG = 0x4D414443  # engine/core.py CHURN_KEY_TAG
+_M32 = 0xFFFFFFFF
+
+
+def _threefry2x32(k0: int, k1: int, x0: int, x1: int) -> tuple:
+    """Threefry-2x32, 20 rounds (Salmon et al., SC'11), on Python ints."""
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    rot = ((13, 15, 26, 6), (17, 29, 16, 24))
+    x0 = (x0 + ks[0]) & _M32
+    x1 = (x1 + ks[1]) & _M32
+    for block in range(5):
+        for r in rot[block % 2]:
+            x0 = (x0 + x1) & _M32
+            x1 = ((x1 << r) | (x1 >> (32 - r))) & _M32
+            x1 ^= x0
+        x0 = (x0 + ks[(block + 1) % 3]) & _M32
+        x1 = (x1 + ks[(block + 2) % 3] + block + 1) & _M32
+    return x0, x1
+
+
+def churn_draw(seed: int, draw: int) -> list:
+    """The six words of draw `draw` of a seed's churn stream: Threefry
+    over the counters 8*draw + [0, 6), paired (j, j + 3) as
+    `jax.extend.random.threefry_2x32` pairs the halves of its counts.
+    [disconnect coin, reconnect pick, long-sleep coin, sleep, victim
+    pick (machines without a hook), spare]."""
+    c = [(8 * draw + j) & _M32 for j in range(6)]
+    pairs = [
+        _threefry2x32(seed & _M32, _CHURN_KEY_TAG, c[j], c[j + 3])
+        for j in range(3)
+    ]
+    return [p[0] for p in pairs] + [p[1] for p in pairs]
+
+
+class ChurnReference:
+    """The churn process of one seed, stepped tick by tick: the tester's
+    loop in plain Python. `plan` is anything with ChurnPlan's fields."""
+
+    def __init__(self, seed: int, plan, n: int, until_us: int):
+        self.seed, self.plan, self.n, self.until_us = seed, plan, n, until_us
+        self.majority = plan.majority or n // 2 + 1
+        self.down: set = set()
+        self.tick = 0
+        self.t_us = self._sleep(churn_draw(seed, 0))  # when tick 0 fires
+
+    def _sleep(self, words) -> int:
+        is_long = words[2] % 1000 < self.plan.long_sleep_permille
+        return words[3] % (
+            self.plan.long_sleep_us if is_long else self.plan.short_sleep_us
+        )
+
+    @property
+    def over(self) -> bool:
+        """The next event is the heal at `until_us`, not a tick."""
+        return self.t_us >= self.until_us
+
+    def connected(self) -> list:
+        return [i not in self.down for i in range(self.n)]
+
+    def fire(self, victim) -> list:
+        """Apply the tick due at `t_us` — `victim` is the node the
+        machine's hook names (-1: none; None: no hook, draw a connected
+        node uniformly) — and move on to the next. Returns the faults
+        applied: [(t_us, op, node)]."""
+        words = churn_draw(self.seed, self.tick + 1)
+        t, out = self.t_us, []
+        if victim is None:
+            up = [i for i in range(self.n) if i not in self.down]
+            victim = up[words[4] % len(up)] if up else -1
+        if words[0] % 1000 < self.plan.disconnect_permille and victim >= 0:
+            self.down.add(victim)
+            out.append((t, CHURN_DISCONNECT, victim))
+        if self.n - len(self.down) < self.majority:
+            pick = words[1] % self.n
+            if pick in self.down:
+                self.down.discard(pick)
+                out.append((t, CHURN_RECONNECT, pick))
+        self.tick += 1
+        self.t_us = t + self._sleep(words)
+        return out
+
+    def heal(self) -> list:
+        out = [(self.until_us, CHURN_RECONNECT, i) for i in sorted(self.down)]
+        self.down.clear()
+        return out
+
+
+def churn_reference(seed: int, plan, leader_at, *, n: int, until_us: int,
+                    horizon_us: Optional[int] = None) -> list:
+    """The faults the churn process of `seed` applies, [(t_us, op,
+    node)] in order: tick times, coins and reconnect picks from the
+    seed, the victim of tick i from `leader_at(t_us, i, connected)`
+    (`connected`: bool per node, by the process's own book; return -1
+    for no leader, None to let the process draw a connected node).
+    Events at or past `horizon_us` are never applied, as on the lane."""
+    ref = ChurnReference(seed, plan, n, until_us)
+    out: list = []
+    while not ref.over:
+        if horizon_us is not None and ref.t_us >= horizon_us:
+            return out
+        out += ref.fire(leader_at(ref.t_us, ref.tick, ref.connected()))
+    if horizon_us is None or until_us < horizon_us:
+        out += ref.heal()
+    return out
+
+
+def applied_churn_faults(engine: Engine, seed: int, max_steps: int = 10_000,
+                         on_tick=None) -> list:
+    """What the lane of `seed` really applied, read off the CPU replay's
+    state trail: [(t_us, op, node)] in order. `on_tick(tick, t_us,
+    state_before)`, when given, sees the state each churn tick found
+    (where a test reads the leader from)."""
+    from .engine.core import F_CHURN_HEAL, F_CHURN_TICK
+    from .engine.replay import replay
+
+    out: list = []
+    before = [engine.init_lane(seed)]
+
+    def hook(ev, state) -> None:
+        if ev.kind == "fault" and ev.payload[0] in (F_CHURN_TICK, F_CHURN_HEAL) \
+                and ev.time_us < engine.config.horizon_us:
+            if on_tick is not None and ev.payload[0] == F_CHURN_TICK:
+                on_tick(ev.payload[1], ev.time_us, before[0])
+            cut, back = (int(x) for x in state.churn["last"])
+            n = engine.machine.NUM_NODES
+            out.extend((ev.time_us, CHURN_DISCONNECT, i)
+                       for i in range(n) if (cut >> i) & 1)
+            out.extend((ev.time_us, CHURN_RECONNECT, i)
+                       for i in range(n) if (back >> i) & 1)
+        before[0] = state
+
+    replay(engine, seed, max_steps=max_steps, on_step=hook, trace=False)
+    return out
 
 
 def _load_raft_host():
@@ -78,7 +225,10 @@ def fault_schedule(engine: Engine, seed: int) -> List[Dict[str, int]]:
     state = engine.init_lane(seed)
     kind = np.asarray(state.eq_kind)
     valid = np.asarray(state.eq_valid)
-    sel = valid & (kind == EV_FAULT)
+    # the churn process's slot is not a scheduled fault: what it applies
+    # is read off the run (`applied_churn_faults`)
+    sel = valid & (kind == EV_FAULT) & (
+        np.asarray(state.eq_payload)[:, 0] < F_CHURN_TICK)
     t = np.asarray(state.eq_time)[sel]
     seq = np.asarray(state.eq_seq)[sel]
     pay = np.asarray(state.eq_payload)[sel]
@@ -96,8 +246,26 @@ def run_host_raft(
     horizon_us: int = 5_000_000,
     node_cls=None,
     base_loss: float = 0.0,
+    latency_us: Optional[tuple] = None,
+    churn_faults: Optional[List[tuple]] = None,
+    churn: Optional[tuple] = None,
+    closing_commit_s: float = 0.0,
 ) -> Dict:
     """Run the host-engine example Raft under the pinned `schedule`.
+
+    The churn process (`FaultPlan.churn`) comes in one of two ways.
+    `churn_faults` is a lane's applied stream (`applied_churn_faults`:
+    [(t_us, op, node)]), replayed at the same virtual times — a
+    disconnect is `clog_node`, labrpc's rule exactly: a link carries
+    traffic iff both ends are connected. `churn=(plan, until_us)` runs
+    the process itself against THIS engine (`ChurnReference`: the same
+    ticks, coins and picks from `seed`, the victim this engine's own
+    connected leader). `latency_us=(min, max)` sets the fabric's send
+    latency. With `closing_commit_s` the harness then does what the
+    test's closing `one(cmd, servers)` does, on a net without loss (see
+    `closing_commit`): hands one more entry to whoever leads until all
+    `n` nodes have committed it, for at most that many virtual seconds
+    ("closing_committed" in the result).
 
     `base_loss` mirrors the device engine's static
     `EngineConfig.packet_loss_rate`: it is installed in the host fabric at
@@ -125,19 +293,93 @@ def run_host_raft(
         # NetSim.config is the outer Config; the fabric reads
         # Network.config == config.net (net/network.py:154) — mutate THAT.
         net.config.net.packet_loss_rate = base_loss
+        if latency_us is not None:
+            net.config.net.send_latency_min_ns = latency_us[0] * 1000
+            net.config.net.send_latency_max_ns = latency_us[1] * 1000
         state: dict = {"loss_trace": [(0, base_loss)]}
         peers = [f"10.3.0.{i+1}:{5000+i}" for i in range(n)]
         nodes = []
+        objs: dict = {}  # node index -> its live RaftNode (no kills here)
+
+        def boot(i):
+            objs[i] = cls(i, peers, state)
+            return objs[i].run()
+
         for i in range(n):
             node = (
                 handle.create_node()
                 .name(f"draft-{i}")
                 .ip(f"10.3.0.{i+1}")
-                .init(lambda i=i: cls(i, peers, state).run())
+                .init(lambda i=i: boot(i))
                 .build()
             )
             nodes.append(node)
         ids = [nd.id for nd in nodes]
+        down: set = set()
+
+        def apply_churn(t_us, op, node):
+            if op == CHURN_DISCONNECT:
+                net.clog_node(ids[node])
+                down.add(node)
+            else:
+                net.unclog_node(ids[node])
+                down.discard(node)
+            state.setdefault("churn_applied", []).append((t_us, op, node))
+
+        async def churn_task():
+            start = sim_time.now()
+
+            async def until(t_us):
+                delta = start + t_us / 1e6 - sim_time.now()
+                if delta > 0:
+                    await sim_time.sleep(delta)
+
+            if churn_faults is not None:
+                for t_us, op, node in churn_faults:
+                    await until(t_us)
+                    apply_churn(t_us, op, node)
+                return
+            plan, until_us = churn
+            ref = ChurnReference(seed, plan, n, until_us)
+            while not ref.over:
+                await until(ref.t_us)
+                lead = next(
+                    (i for i in range(n) if i not in ref.down
+                     and i in objs and objs[i].role == ex.LEADER), -1)
+                for ev in ref.fire(lead):
+                    apply_churn(*ev)
+            await until(until_us)
+            for ev in ref.heal():
+                apply_churn(*ev)
+
+        async def closing_commit() -> bool:
+            """labrpc's `one(cmd, servers)`: an entry handed to the
+            leader, again after every change of leader, until all `n`
+            nodes have committed it. On a net made reliable first — a
+            departure from the test, which keeps its 10% loss: the
+            example's leader calls its peers one after another, so under
+            loss a heartbeat round outlasts an election timeout, terms
+            change five times a second and a lagging follower (one
+            `next_idx` step a round, reset by every new leader) never
+            catches up. What is asked here is that the churn left
+            nothing behind that a quiet net cannot repair."""
+            net.config.net.packet_loss_rate = 0.0
+            deadline = sim_time.now() + closing_commit_s
+            while sim_time.now() < deadline:
+                lead = next((o for o in objs.values() if o.role == ex.LEADER), None)
+                if lead is not None:
+                    entry = (lead.term, f"closing-{sim_time.now():.6f}")
+                    lead.log.append(entry)
+                    lead.persist()
+                    idx = len(lead.log) - 1
+                    t1 = min(deadline, sim_time.now() + 2.0)
+                    while sim_time.now() < t1:
+                        await sim_time.sleep(0.05)
+                        if all(o.commit >= idx and len(o.log) > idx
+                               and o.log[idx] == entry for o in objs.values()):
+                            return True
+                await sim_time.sleep(0.05)
+            return False
 
         async def chaos():
             applied = state.setdefault("chaos_applied", [])
@@ -193,7 +435,10 @@ def run_host_raft(
                 applied.append((ev["t_us"], op, a, b))
 
         spawn(chaos())
+        if churn_faults is not None or churn is not None:
+            spawn(churn_task())
         await sim_time.sleep(horizon_us / 1e6)
+        closing_ok = await closing_commit() if closing_commit_s else None
 
         violation: Optional[str] = None
         for _term, leaders in state.get("leaders_by_term", {}).items():
@@ -219,6 +464,8 @@ def run_host_raft(
             "chaos_applied": list(state.get("chaos_applied", [])),
             "loss_trace": list(state.get("loss_trace", [])),
             "delay_trace": list(state.get("delay_trace", [])),
+            "churn_applied": list(state.get("churn_applied", [])),
+            "closing_committed": closing_ok,
         }
 
     return Runtime(seed=seed).block_on(scenario())
@@ -249,8 +496,13 @@ def differential_raft(
     n: int = 5,
     host_node_cls=None,
     max_steps: int = 3000,
+    closing_commit_s: float = 0.0,
 ) -> Dict:
-    """Run every seed on both engines under the device's fault schedule.
+    """Run every seed on both engines under the device's fault schedule
+    — and, where the plan has a churn process, under the faults the
+    device lane applied (`applied_churn_faults`), at the same virtual
+    times; `closing_commit_s` then asks the host for the test's closing
+    commit on all nodes.
 
     Returns per-seed rows plus aggregates:
       {"rows": [...], "device_violations": int, "host_violations": int,
@@ -259,14 +511,25 @@ def differential_raft(
     """
     horizon = engine.config.horizon_us
     base_loss = float(getattr(engine.config, "packet_loss_rate", 0.0))
+    churn_on = engine.config.faults.churn is not None
     rows = []
     for seed in seeds:
         seed = int(seed)
         sched = fault_schedule(engine, seed)
         dev = run_device_raft(engine, seed, max_steps=max_steps)
+        churn_kw = {}
+        if churn_on:
+            # the process's faults are not in the schedule (the victim
+            # is read off the run): the bridge reads what the lane applied
+            churn_kw = {
+                "churn_faults": applied_churn_faults(engine, seed, max_steps),
+                "latency_us": (engine.config.latency_min_us,
+                               engine.config.latency_max_us),
+                "closing_commit_s": closing_commit_s,
+            }
         host = run_host_raft(
             seed, sched, n=n, horizon_us=horizon, node_cls=host_node_cls,
-            base_loss=base_loss,
+            base_loss=base_loss, **churn_kw,
         )
         rows.append(
             {
@@ -283,7 +546,8 @@ def differential_raft(
                     (e["t_us"], e["op"], e["a"], e["b"])
                     for e in sched
                     if e["t_us"] < horizon
-                ],
+                ]
+                and host["churn_applied"] == churn_kw.get("churn_faults", []),
             }
         )
     return {

@@ -15,7 +15,9 @@ See `core.py` for the architecture. Public surface:
     partitions, kill/restart, loss storms, delay spikes, pause/resume
     windows (freeze + deferred delivery), per-node clock-skew windows,
     Bernoulli message duplication (`allow_dup`), and crash-with-amnesia
-    restarts (`strict_restart` + `Machine.durable_spec()`)
+    restarts (`strict_restart` + `Machine.durable_spec()`); and
+    `FaultPlan(churn=ChurnPlan(), churn_until_us=T)`, a fault PROCESS
+    whose ticks draw their faults as they fire (Figure 8's churn)
   * `shrink(engine, seed)` — minimize a failing seed's config (shrink.py)
   * `EngineConfig(trace_ring=R)` + `Engine.ring_trace(result, lane)` —
     on-device last-R-events ring for post-mortems without replay
@@ -32,6 +34,7 @@ See `core.py` for the architecture. Public surface:
 
 from .core import (
     BatchResult,
+    ChurnPlan,
     Engine,
     EngineConfig,
     FaultPlan,

@@ -25,6 +25,7 @@ import dataclasses
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from .core import Engine
+from .corpus import entry_machine
 
 DEFAULT_DIGEST_EVERY = 64
 
@@ -236,7 +237,7 @@ def audit_entry(entry, build_machine: Callable[[str, int], object]) -> AuditOutc
     """Replay one corpus entry on the host path and bisect its recorded
     digest trail. Also cross-checks the behavioral outcome (fail code)
     so a divergence report says whether the finding itself survived."""
-    eng = Engine(build_machine(entry.machine, entry.nodes), entry.config)
+    eng = Engine(entry_machine(entry, build_machine), entry.config)
     every = entry.digest_every or DEFAULT_DIGEST_EVERY
     trail = collect_trail(eng, entry.seed, entry.max_steps, every=every)
     behavior = (
@@ -266,7 +267,7 @@ def record_entry(
     at HEAD. Returns (updated_entry, trail) — the trail carries the
     behavioral outcome (failed / fail_code) so callers can check the
     entry's status contract before saving."""
-    eng = Engine(build_machine(entry.machine, entry.nodes), entry.config)
+    eng = Engine(entry_machine(entry, build_machine), entry.config)
     trail = collect_trail(eng, entry.seed, entry.max_steps, every=every)
     digests, final = trail.to_lists()
     new = dataclasses.replace(

@@ -61,6 +61,8 @@ from ..ops.pallas_pop import (
     pop_gather_batch,
     step_megakernel,
 )
+from jax.extend.random import threefry_2x32
+
 from ..ops.step_rng import (
     RNG_STREAM_COUNTER,
     RNG_STREAM_LEGACY,
@@ -106,6 +108,13 @@ F_HASYM = 18       # asymmetric partition: clog pair a<->b both ways; the
 F_HASYM_HEAL = 19  # heal op unclogs ONE direction arg1->arg2 — the two
 #                    directions heal at independently drawn times, so
 #                    every partition tail is a one-way-link window
+
+# The churn process (FaultPlan.churn) — not a scheduled kind: ONE queue slot
+# that re-arms itself. A tick draws its own faults when it fires (so their
+# number is not a shape of anything), the heal reconnects every node once
+# the process is over. payload[1] of a tick is its index.
+F_CHURN_TICK = 20
+F_CHURN_HEAL = 21
 
 # FaultPlan kind indices (op_apply = 2*kind)
 K_PAIR = 0
@@ -254,6 +263,79 @@ def _clog_row_bools(row, n):
 
 
 @dataclasses.dataclass(frozen=True)
+class ChurnPlan:
+    """A fault PROCESS (`FaultPlan.churn`): faults drawn as they fire.
+
+    The loop of MIT 6.824's `TestFigure8Unreliable2C` (MadRaft's
+    `figure_8_unreliable_2c`), one iteration a tick: sleep, then with
+    probability `disconnect_permille`/1000 disconnect the victim — the
+    node `Machine.churn_victim` names (Raft: the connected leader), or a
+    uniformly drawn connected node for a machine without the hook — by
+    clogging both directions of all its links; then, if fewer than
+    `majority` nodes are connected, draw one node uniformly and
+    reconnect it if it is disconnected. The sleep is U[0, long_sleep_us)
+    with probability `long_sleep_permille`/1000, else U[0,
+    short_sleep_us). At `FaultPlan.churn_until_us` every node is
+    reconnected and the process stops.
+
+    A link carries traffic iff both its ends are connected (labrpc's
+    rule): reconnecting a node re-enables its links to the connected
+    nodes only, and with it whatever scheduled clog lay on them.
+
+    The draws are a counter stream of their own, keyed by the lane's
+    seed (`churn_words`): draw 0 gives the sleep before tick 0, draw
+    i + 1 the coins of tick i and the sleep after it. So the ticks'
+    times, coins and reconnect picks are a function of the seed alone
+    (`differential.churn_reference` re-derives them in plain Python),
+    no other stream moves, and only the victim depends on the run."""
+
+    disconnect_permille: int = 500
+    long_sleep_permille: int = 100
+    long_sleep_us: int = 500_000
+    short_sleep_us: int = 13_000
+    majority: int = 0  # 0 = NUM_NODES // 2 + 1
+
+
+# `--churn <name>`: the named parameter sets
+CHURN_PRESETS = {"fig8": ChurnPlan()}
+
+# second key word of the churn stream ("MADC"); the first is the seed
+CHURN_KEY_TAG = 0x4D414443
+CHURN_DRAW_WORDS = 6  # coin, reconnect pick, long coin, sleep, victim, spare
+CHURN_DRAW_STRIDE = 8  # counters of draw d: 8*d + [0, 6)
+# LaneState.churn's counters, in the order they ride fr_metrics' tail
+CHURN_COUNTER_NAMES = _kinds.FR_CHURN_NAMES
+
+
+def churn_key(seed) -> jax.Array:
+    """uint32[2] key of a lane's churn stream: (seed, CHURN_KEY_TAG)."""
+    if not hasattr(seed, "dtype"):
+        seed = jnp.uint32(int(seed) & 0xFFFFFFFF)
+    return jnp.stack([seed.astype(jnp.uint32), jnp.uint32(CHURN_KEY_TAG)])
+
+
+def churn_words(key, draw) -> jax.Array:
+    """uint32[CHURN_DRAW_WORDS] of draw `draw`: raw Threefry-2x32 over
+    the counters 8*draw + [0, 6) (the flag-independent kernel the v3
+    step stream uses)."""
+    counts = (
+        jnp.asarray(draw).astype(jnp.uint32) * jnp.uint32(CHURN_DRAW_STRIDE)
+        + jnp.arange(CHURN_DRAW_WORDS, dtype=jnp.uint32)
+    )
+    return threefry_2x32(key, counts)
+
+
+def churn_sleep_us(plan: ChurnPlan, words) -> jax.Array:
+    """The sleep a draw's words give (int32 us)."""
+    is_long = (words[2] % jnp.uint32(1000)) < jnp.uint32(plan.long_sleep_permille)
+    return jnp.where(
+        is_long,
+        words[3] % jnp.uint32(plan.long_sleep_us),
+        words[3] % jnp.uint32(plan.short_sleep_us),
+    ).astype(jnp.int32)
+
+
+@dataclasses.dataclass(frozen=True)
 class FaultPlan:
     """Per-lane randomized fault schedule (drawn from the lane seed).
 
@@ -339,6 +421,16 @@ class FaultPlan:
     t_max_us: int = 1_000_000
     dur_min_us: int = 100_000
     dur_max_us: int = 1_000_000
+    # the fault process beside the schedule (None = off: nothing of it
+    # is traced, and every recorded stream is as it was), and the
+    # virtual time at which it reconnects every node and stops
+    churn: Optional[ChurnPlan] = None
+    churn_until_us: int = 0
+
+    def __post_init__(self):
+        # a corpus entry's JSON gives the plan back as a dict
+        if isinstance(self.churn, dict):
+            object.__setattr__(self, "churn", ChurnPlan(**self.churn))
 
     def enabled_kinds(self) -> tuple:
         kinds = []
@@ -561,6 +653,15 @@ class LaneState:
     # pending slot indices, "buf_n": int32 live-entry count} — flushed
     # into "map" by run_segment's cadence/exit folds
     cov: Any
+    # {} unless FaultPlan.churn: {"key": uint32[2] of the churn stream,
+    # "down": int32 bitmask of the disconnected nodes, "until_us": int32
+    # (a value, so shrink's candidates share one program), "last":
+    # int32[2] node masks the latest churn event cut off / brought back
+    # (what `differential.applied_churn_faults` reads), and the
+    # applied-fault counters "ticks" / "disconnects" / "reconnects"}.
+    # An empty dict has no leaf: a program without churn is the program
+    # it was.
+    churn: Any = struct.field(default_factory=dict)
 
 
 @struct.dataclass
@@ -672,13 +773,38 @@ class Engine:
         else:
             self._pallas_interpret = False
         n, q = machine.NUM_NODES, config.queue_capacity
-        min_slots = n + config.faults.slots_per_fault * config.faults.n_faults
+        fp = config.faults
+        # the churn process holds ONE slot, however many faults it applies
+        min_slots = (
+            n + fp.slots_per_fault * fp.n_faults + (fp.churn is not None)
+        )
         if q < min_slots + machine.MAX_MSGS + machine.MAX_TIMERS:
             raise ValueError(
                 f"queue_capacity={q} too small for {n} nodes + "
                 f"{config.faults.n_faults} faults + outbox headroom"
             )
-        fp = config.faults
+        if fp.churn is not None:
+            if not 2 <= n <= CLOG_WORD_BITS:
+                raise ValueError(
+                    f"the churn process keeps the disconnected set as one "
+                    f"int32 bitmask: 2 <= NUM_NODES <= {CLOG_WORD_BITS}"
+                )
+            if fp.churn_until_us <= 0:
+                raise ValueError(
+                    "FaultPlan.churn needs churn_until_us > 0 (the virtual "
+                    "time at which every node is reconnected)"
+                )
+            if min(fp.churn.long_sleep_us, fp.churn.short_sleep_us) < 1:
+                raise ValueError("ChurnPlan sleeps are U[0, x) us with x >= 1")
+        self._churn_majority = (
+            (fp.churn.majority or n // 2 + 1) if fp.churn is not None else 0
+        )
+        # StreamCarry.fr_metrics: the recorder's totals, the churn
+        # process's counters after them; [0] with the recorder off
+        self._fr_metrics_len = (
+            FR_METRICS_LEN
+            + (len(CHURN_COUNTER_NAMES) if fp.churn is not None else 0)
+        ) if config.flight_recorder else 0
         if fp.n_faults > 0 and not fp.enabled_kinds():
             raise ValueError("FaultPlan has n_faults > 0 but every kind disabled")
         if fp.allow_group and (n < 2 or n > 60):
@@ -988,6 +1114,34 @@ class Engine:
                     )
             next_seq += fp.slots_per_fault
 
+        # The churn process: ONE slot after the schedule's, holding tick 0
+        # (or the heal, where the first sleep already passes the end).
+        churn = {}
+        if fp.churn is not None:
+            ckey = churn_key(seed)
+            t0 = churn_sleep_us(fp.churn, churn_words(ckey, 0))
+            over = t0 >= fp.churn_until_us
+            msk = slots == n + fp.slots_per_fault * fp.n_faults
+            eq_time = jnp.where(
+                msk, jnp.where(over, jnp.int32(fp.churn_until_us), t0), eq_time
+            )
+            eq_seq = jnp.where(msk, next_seq, eq_seq)
+            eq_kind = jnp.where(msk, EV_FAULT, eq_kind)
+            pay = jnp.stack(
+                [jnp.where(over, F_CHURN_HEAL, F_CHURN_TICK).astype(jnp.int32)]
+                + [jnp.int32(0)] * (p - 1)
+            )
+            eq_payload = jnp.where(msk[:, None], pay[None, :], eq_payload)
+            eq_valid = eq_valid | msk
+            next_seq += 1
+            churn = {
+                "key": ckey,
+                "down": jnp.int32(0),
+                "until_us": jnp.int32(fp.churn_until_us),
+                "last": jnp.zeros((2,), jnp.int32),
+                **{k: jnp.int32(0) for k in CHURN_COUNTER_NAMES},
+            }
+
         return LaneState(
             now_us=jnp.int32(0),
             next_seq=jnp.int32(next_seq),
@@ -1024,6 +1178,7 @@ class Engine:
             ring=self._empty_ring(),
             fr=self._empty_fr(eq_valid),
             cov=self._empty_cov(),
+            churn=churn,
         )
 
     def _empty_cov(self):
@@ -1386,6 +1541,82 @@ class Engine:
             outbox_valid_msgs = outbox.msg_valid & effective
             outbox_valid_timers = outbox.timer_valid & effective
 
+        with _xprof.scope("step.churn"):
+            # -- the churn process (FaultPlan.churn; off adds NO ops) ----------
+            # A tick draws its faults now: the victim is read off the state
+            # the tick finds, the coins and the next sleep off the lane's
+            # churn stream at this tick's index. `churn_next` re-arms the
+            # popped slot below, so the process never holds a second one.
+            churn = s.churn
+            churn_next = None
+            if cfg.faults.churn is not None:
+                cp = cfg.faults.churn
+                nn = s.killed.shape[0]
+                node_ids = jnp.arange(nn)
+                is_churn = effective & (ev_kind == EV_FAULT)
+                is_tick = is_churn & (ev_payload[0] == F_CHURN_TICK)
+                is_heal = is_churn & (ev_payload[0] == F_CHURN_HEAL)
+                cw = churn_words(churn["key"], ev_payload[1] + 1)
+                down = churn["down"]
+                up = ((down >> node_ids) & 1) == 0
+                victim = m.churn_victim(s.nodes, up)
+                if victim is None:
+                    # no hook: the (word mod #connected)-th connected node
+                    rank = jnp.cumsum(up.astype(jnp.int32)) - 1
+                    n_conn = jnp.maximum(up.sum(dtype=jnp.int32), 1)
+                    kth = (cw[4] % n_conn.astype(jnp.uint32)).astype(jnp.int32)
+                    victim = jnp.where(
+                        up.any(), jnp.argmax(up & (rank == kth)), -1
+                    )
+                victim = jnp.asarray(victim).astype(jnp.int32)
+                do_disc = (
+                    is_tick
+                    & ((cw[0] % jnp.uint32(1000)) < jnp.uint32(cp.disconnect_permille))
+                    & (victim >= 0)
+                )
+                disc_bits = jnp.where(
+                    do_disc, jnp.int32(1) << jnp.clip(victim, 0, nn - 1), 0
+                )
+                down = down | disc_bits
+                n_up = nn - lax.population_count(down)
+                pick_bit = jnp.int32(1) << (cw[1] % jnp.uint32(nn)).astype(jnp.int32)
+                do_reconn = (
+                    is_tick & (n_up < self._churn_majority)
+                    & ((down & pick_bit) != 0)
+                )
+                # the nodes coming back: the tick's pick, or all at the heal
+                up_bits = jnp.where(
+                    is_heal, down, jnp.where(do_reconn, pick_bit, 0)
+                )
+                down = down & ~up_bits
+                clogged = _churn_clog(
+                    clogged, disc_bits, up_bits, down, cfg.clog_packed
+                )
+                churn = dict(
+                    churn,
+                    down=down,
+                    last=jnp.where(
+                        is_tick | is_heal,
+                        jnp.stack([disc_bits, up_bits]),
+                        churn["last"],
+                    ),
+                    ticks=churn["ticks"] + is_tick.astype(jnp.int32),
+                    disconnects=churn["disconnects"] + do_disc.astype(jnp.int32),
+                    reconnects=churn["reconnects"]
+                    + lax.population_count(up_bits),
+                )
+                # the next tick, or the heal once the sleep passes the end
+                t_next = ev_time + churn_sleep_us(cp, cw)
+                over = t_next >= churn["until_us"]
+                churn_next = (
+                    is_tick,
+                    jnp.where(over, churn["until_us"], t_next),
+                    jnp.where(over, F_CHURN_HEAL, F_CHURN_TICK).astype(jnp.int32),
+                    ev_payload[1] + 1,
+                )
+                # provenance: which nodes' faults this event applied
+                churn_prov_bits = (disc_bits | up_bits).astype(jnp.uint32)
+
         with _xprof.scope("step.provenance"):
             # -- causal provenance fold (gate-off adds NO ops) ------------------
             # A processed handler event folds its lineage into the handling
@@ -1420,13 +1651,22 @@ class Engine:
                     idxs_p == ev_node,
                 )
                 add_word = ev_prov
+                if cfg.faults.churn is not None:
+                    # A generated fault sets the bit of the NODE it cuts
+                    # off or brings back (hundreds of faults, N <= 30
+                    # bits: sound as an OR, whatever their number), on
+                    # every node — each is an end of a link it moved. A
+                    # tick that applies nothing touches nothing.
+                    churn_ev = is_fault_ev & (p_op >= F_CHURN_TICK)
+                    touched = jnp.where(churn_ev, churn_prov_bits != 0, touched)
+                    add_word = jnp.where(churn_ev, churn_prov_bits, add_word)
                 if cfg.faults.strict_restart:
                     # a crash-with-amnesia wipe is its own attribution
                     # channel (bit 30): it has no schedule slot of its own
                     add_word = jnp.where(
                         is_fault_ev & (p_op == F_RESTART),
                         ev_prov | jnp.uint32(1 << PROV_BIT_AMNESIA),
-                        ev_prov,
+                        add_word,
                     )
                 node_prov = jnp.where(
                     touched & effective, s.node_prov | add_word, s.node_prov
@@ -1470,6 +1710,26 @@ class Engine:
                         defer_slot, eq["prov"] | s.node_prov[ev_node], eq["prov"]
                     )
             next_seq = s.next_seq
+            if churn_next is not None:
+                # re-arm the slot the tick was popped from: a pop and a
+                # push in one, so the process can never overflow the queue
+                rearm, t_next, next_op, next_tick = churn_next
+                with _xprof.scope("step.churn"):
+                    eq = _push(
+                        eq, idx, rearm, t_next, next_seq, EV_FAULT,
+                        jnp.int32(0), jnp.int32(-1),
+                        # selects on an iota, not `make_payload`'s stack of
+                        # scalars: that form read 2% more chip-us a seed
+                        # (my chip run, PR 27)
+                        jnp.where(
+                            jnp.arange(m.PAYLOAD_WIDTH) == 0, next_op,
+                            jnp.where(
+                                jnp.arange(m.PAYLOAD_WIDTH) == 1, next_tick, 0
+                            ),
+                        ).astype(jnp.int32),
+                        prov=jnp.uint32(0) if cfg.provenance else None,
+                    )
+                next_seq = next_seq + rearm.astype(jnp.int32)
             failed = s.failed
             fail_code = s.fail_code
             msg_count = s.msg_count
@@ -1841,6 +2101,7 @@ class Engine:
                 ring=ring,
                 fr=fr,
                 cov=cov,
+                churn=churn,
             )
 
     # -- batch runners -------------------------------------------------------
@@ -2148,7 +2409,7 @@ class Engine:
                 # schema is unaffected: the stats dict synthesizes
                 # nothing unless the gate is on)
                 fr_metrics=jnp.zeros(
-                    (FR_METRICS_LEN if self.config.flight_recorder else 0,),
+                    (self._fr_metrics_len,),
                     jnp.int32,
                 ),
                 cov_map=(
@@ -2251,7 +2512,18 @@ class Engine:
                                 )
                             ]
                         )
-                    fr_metrics = jnp.concatenate([inj_tot, extra_tot, hwm])
+                    parts = [inj_tot, extra_tot, hwm]
+                    if self.config.faults.churn is not None:
+                        # the process's applied faults ride the tail
+                        book = state.churn
+                        base = FR_METRICS_LEN
+                        with _xprof.collective_scope("fr-fold"):
+                            parts.append(jnp.stack([
+                                # madsim: collective(fr-fold, reduce=sum)
+                                fr_metrics[base + i] + jnp.where(done, book[k], 0).sum()
+                                for i, k in enumerate(CHURN_COUNTER_NAMES)
+                            ]))
+                    fr_metrics = jnp.concatenate(parts)
 
             # coverage rides the harvest too: OR every lane's bit map
             # into the global vector. ALL lanes, not just done ones —
@@ -3218,6 +3490,36 @@ class Engine:
                 f"batches; diverging leaves: {mismatches}"
             )
         return r1
+
+
+def _churn_clog(clogged, disc_bits, up_bits, down, packed: bool):
+    """The clog state after a churn event: the nodes of `disc_bits`
+    lose every link; a link with an end in `up_bits` carries traffic
+    again iff neither end is in `down` (the disconnected set after the
+    event). Bitmasks over node ids (N <= 30); with both masks 0 the
+    state is returned as it was."""
+    n = clogged.shape[0]
+    ids = jnp.arange(n)
+    if packed:
+        # node j of a row is bit j of word 0 (N <= CLOG_WORD_BITS)
+        own = jnp.int32(1) << ids
+        full = jnp.int32((1 << n) - 1)
+        w0 = clogged[:, 0]
+        w0 = w0 | jnp.where((disc_bits & own) != 0, full & ~own, disc_bits)
+        w0 = jnp.where(
+            (down & own) != 0, w0,
+            jnp.where((up_bits & own) != 0, w0 & down, w0 & ~up_bits),
+        )
+        return jnp.stack([w0, clogged[:, 1]], axis=1)
+    in_disc = ((disc_bits >> ids) & 1) == 1
+    in_up = ((up_bits >> ids) & 1) == 1
+    is_down = ((down >> ids) & 1) == 1
+    cut = (in_disc[:, None] | in_disc[None, :]) & ~jnp.eye(n, dtype=bool)
+    back = (
+        (in_up[:, None] | in_up[None, :])
+        & ~is_down[:, None] & ~is_down[None, :]
+    )
+    return (clogged | cut) & ~back
 
 
 def _push(eq, idx, do_push, time, seq, kind, node, src, payload, prov=None):

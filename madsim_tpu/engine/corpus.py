@@ -52,6 +52,10 @@ def config_to_dict(cfg: EngineConfig) -> dict:
     # causal provenance too: lineage words never feed back into results,
     # and `why` re-enables the gate itself at replay time
     d.pop("provenance", None)
+    # a plan without a churn process is written as it always was
+    if d["faults"].get("churn") is None:
+        d["faults"].pop("churn", None)
+        d["faults"].pop("churn_until_us", None)
     return d
 
 
@@ -72,6 +76,8 @@ class CorpusEntry:
     max_steps: int
     nodes: int = 0
     note: str = ""
+    # `--log-capacity` of the run that found it (0 = the registry's own)
+    log_capacity: int = 0
     # Flight-recorder provenance (engine/audit.py): the digest trail
     # recorded when the entry was (re-)recorded — checkpoints every
     # `digest_every` steps as [step, d0, d1], the final [step, d0, d1],
@@ -106,6 +112,8 @@ class CorpusEntry:
             "note": self.note,
             "config": config_to_dict(self.config),
         }
+        if self.log_capacity:
+            d["log_capacity"] = self.log_capacity
         if self.digest_every:
             d["digest_every"] = self.digest_every
             d["digests"] = [[int(x) for x in ck] for ck in self.digests]
@@ -124,6 +132,7 @@ class CorpusEntry:
             status=d.get("status", STATUS_OPEN),
             max_steps=int(d["max_steps"]),
             note=d.get("note", ""),
+            log_capacity=int(d.get("log_capacity", 0)),
             config=config_from_dict(d["config"]),
             digest_every=int(d.get("digest_every", 0)),
             digests=[[int(x) for x in ck] for ck in d.get("digests", [])],
@@ -169,10 +178,18 @@ class RegressOutcome:
     verdict: str            # human-readable disposition
 
 
-def check(entry: CorpusEntry, build_machine: Callable[[str, int], object]) -> RegressOutcome:
+def entry_machine(entry: CorpusEntry, build_machine: Callable[..., object]):
+    """The machine an entry ran on: `build_machine(name, nodes)`, with
+    the entry's log capacity as a third argument where it records one."""
+    if entry.log_capacity:
+        return build_machine(entry.machine, entry.nodes, entry.log_capacity)
+    return build_machine(entry.machine, entry.nodes)
+
+
+def check(entry: CorpusEntry, build_machine: Callable[..., object]) -> RegressOutcome:
     """Re-run one entry on the host replay path and judge it against its
     status contract. `build_machine(name, nodes)` resolves the machine."""
-    eng = Engine(build_machine(entry.machine, entry.nodes), entry.config)
+    eng = Engine(entry_machine(entry, build_machine), entry.config)
     rp = replay(eng, entry.seed, max_steps=entry.max_steps, trace=False)
     failed = bool(rp.failed)
     code = int(rp.fail_code)

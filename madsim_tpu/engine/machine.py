@@ -339,6 +339,14 @@ class Machine:
         """Small pytree gathered back to host per lane."""
         return jnp.int32(0)
 
+    def churn_victim(self, nodes: Any, connected):
+        """Optional: the node a churn tick disconnects
+        (`FaultPlan.churn`), as an int32 scalar read off the state the
+        tick finds — -1 for none (no disconnect on that tick).
+        `connected` is bool[N]: the nodes the process has not cut off.
+        Default None: the engine draws a connected node uniformly."""
+        return None
+
     def coverage_projection(self, nodes: Any, now_us) -> jax.Array:
         """Abstract-state word for the scenario-coverage map
         (`EngineConfig.coverage`, ops/coverage.py): project the whole

@@ -35,6 +35,8 @@ import dataclasses
 from typing import Dict, List, Optional, Set, Tuple
 
 from .core import (
+    F_CHURN_HEAL,
+    F_CHURN_TICK,
     F_CLOG_DIR,
     F_CLOG_GROUP,
     F_CLOG_PAIR,
@@ -64,6 +66,10 @@ _PAIR_OPS = {
 _GLOBAL_OPS = {
     F_CLOG_GROUP, F_UNCLOG_GROUP, F_LOSS_STORM, F_LOSS_END,
     F_DELAY_SPIKE, F_DELAY_END,
+    # a churn tick that applies a fault moves a link of every node (the
+    # device touches none where the tick applied nothing: this mirror is
+    # the looser of the two, which only widens a past cone)
+    F_CHURN_TICK, F_CHURN_HEAL,
 }
 
 # attribution pseudo-kinds for the non-scheduled chaos bits — named like
@@ -71,6 +77,10 @@ _GLOBAL_OPS = {
 # comparable with shrink's minimal `--fault-kinds` / `--strict-restart`
 KIND_DUP = "dup"
 KIND_AMNESIA = "strict-restart"
+# the churn process (`FaultPlan.churn`): its faults are generated, not
+# scheduled, and each sets the provenance bit of the NODE it cut off or
+# brought back — hundreds of faults in N <= 30 bits, sound as an OR
+KIND_CHURN = "churn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,9 +196,17 @@ class Attribution:
     kinds: Tuple[str, ...]         # implicated kind names (sorted), incl.
     #                                the dup / strict-restart pseudo-kinds
     aliased: bool                  # >30 scheduled faults: bit 29 is shared
+    #                                (or the schedule shares bits with churn)
+    churn_nodes: Tuple[int, ...] = ()  # nodes whose churn faults it implicates
 
     def describe(self) -> List[str]:
         lines = [f.describe() for f in self.faults]
+        if self.churn_nodes:
+            lines.append(
+                "churn: a disconnect or reconnect of node(s) "
+                + ",".join(str(i) for i in self.churn_nodes)
+                + " in lineage [bit = node]"
+            )
         if (self.word >> PROV_BIT_AMNESIA) & 1:
             lines.append(
                 f"crash-with-amnesia wipe in lineage [bit {PROV_BIT_AMNESIA}]"
@@ -215,11 +233,20 @@ def implicated(engine: Engine, seed: int, word: int) -> Attribution:
         kinds.add(KIND_AMNESIA)
     if (word >> PROV_BIT_DUP) & 1:
         kinds.add(KIND_DUP)
+    churn_nodes: Tuple[int, ...] = ()
+    if engine.config.faults.churn is not None:
+        churn_nodes = tuple(
+            i for i in range(engine.machine.NUM_NODES) if (word >> i) & 1
+        )
+        if churn_nodes:
+            kinds.add(KIND_CHURN)
     return Attribution(
         word=word,
         faults=faults,
         kinds=tuple(sorted(kinds)),
-        aliased=len(sched) > PROV_FAULT_BITS,
+        # a schedule beside the process shares the low bits with it
+        aliased=len(sched) > PROV_FAULT_BITS or bool(sched and churn_nodes),
+        churn_nodes=churn_nodes,
     )
 
 
@@ -304,7 +331,7 @@ def build_lineage(
     enqueueing parent."""
     n = engine.machine.NUM_NODES
     fp = engine.config.faults
-    init_seq = n + fp.slots_per_fault * fp.n_faults
+    init_seq = n + fp.slots_per_fault * fp.n_faults + (fp.churn is not None)
     horizon = engine.config.horizon_us
     seq_pusher: Dict[int, int] = {}
     prev = init_seq

@@ -187,6 +187,8 @@ _KEY_IF_COVERAGE = ("cov_slots_log2", "cov_buffer")
 _KEY_FAULTS = (
     "allow_pause", "allow_skew", "strict_restart", "allow_torn",
     "allow_heal_asym",
+    # the churn process's generator and its parameters (None: not traced)
+    "churn",
 )
 # Fields the step reads only through an attribute Engine.__init__
 # derives from them; the key holds that attribute, so two settings that
@@ -213,6 +215,7 @@ _INIT_ONLY = frozenset({
     "allow_group",
     "storm_loss_u16",     # a payload value in the schedule
     "t_min_us", "t_max_us", "dur_min_us", "dur_max_us",  # schedule draws
+    "churn_until_us",     # a value in LaneState.churn (shrink bisects it)
     "pallas_megakernel",  # step_batch's kernel choice: never in lane_step
     "compile_cache_dir",  # host-side
 })

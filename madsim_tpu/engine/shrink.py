@@ -14,6 +14,11 @@ to a minimal one that still reproduces the same failure code:
     the minimal chaos vocabulary. (Turning a scheduled kind off changes
     the remaining faults' drawn parameters — that's fine: every
     candidate is verified by a full replay, never assumed.)
+  * the churn process (`FaultPlan.churn`) stopped as early as still
+    reproduces: a process has no list of faults to take a prefix of,
+    but its ticks before `churn_until_us` do not depend on where it
+    ends, so an earlier end is an honest prefix of the applied faults —
+    bisected, every probe a replay
   * horizon cut to just past the failure time
   * step budget cut to just past the failing step
 
@@ -52,6 +57,10 @@ ABLATION_ORDER = (
 )
 ABLATABLE_KINDS = tuple((name, FLAG_BY_KIND[name]) for name in ABLATION_ORDER)
 
+# the churn stage stops bisecting `churn_until_us` at this width: a few
+# ticks of the fig8 set (mean sleep ~31 ms), 5-7 replays from seconds
+CHURN_SHRINK_RESOLUTION_US = 100_000
+
 
 @dataclasses.dataclass
 class ShrinkResult:
@@ -74,6 +83,11 @@ class ShrinkResult:
             parts.append(f"faults {o.faults.n_faults} -> {s.faults.n_faults}")
         if s.packet_loss_rate != o.packet_loss_rate:
             parts.append(f"loss {o.packet_loss_rate} -> 0")
+        if s.faults.churn_until_us != o.faults.churn_until_us:
+            parts.append(
+                f"churn until {o.faults.churn_until_us}us -> "
+                f"{s.faults.churn_until_us}us"
+            )
         if self.kinds_removed:
             parts.append("kinds -" + ",-".join(self.kinds_removed))
         if s.horizon_us != o.horizon_us:
@@ -236,6 +250,41 @@ def shrink(
         if rp is not None:
             cfg, best = cand_cfg, rp
             kinds_removed.append(kind_name)
+
+    # 3b. the churn process, ended as early as still reproduces. Ending
+    #     it just past the failure is sound by construction (the heal
+    #     lands after the failing event); below that, bisect: an end at
+    #     `mid` keeps every tick before `mid` as it was and heals there.
+    #     Not monotone in general (a heal can also CAUSE the failing
+    #     interleaving), so the bisection is a search order, and only
+    #     replays that reproduce the code move `hi`.
+    if cfg.faults.churn is not None:
+
+        def try_until(until_us: int):
+            cand_cfg = dataclasses.replace(
+                cfg,
+                faults=dataclasses.replace(cfg.faults, churn_until_us=until_us),
+            )
+            return cand_cfg, _candidate(
+                engine, cand_cfg, seed, max_steps, code, "churn"
+            )
+
+        hi = cfg.faults.churn_until_us
+        first = min(hi, int(best.state.now_us) + 1)
+        lo = 0
+        if first < hi:
+            attempts += 1
+            cand_cfg, rp = try_until(first)
+            if rp is not None:
+                cfg, best, hi = cand_cfg, rp, first
+        while hi - lo > CHURN_SHRINK_RESOLUTION_US:
+            mid = (lo + hi) // 2
+            attempts += 1
+            cand_cfg, rp = try_until(mid)
+            if rp is not None:
+                cfg, best, hi = cand_cfg, rp, mid
+            else:
+                lo = mid
 
     # 4. horizon just past the failure (sound by construction — events at
     #    t < horizon are unaffected by the horizon value — but verified)

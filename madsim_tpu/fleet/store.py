@@ -137,6 +137,12 @@ SPEC_FIELDS = {
     "fault_kinds": (str, "pair,kill"),
     "rng_stream": (int, 2),
     "strict_restart": (bool, False),
+    # the deployment's flags ('' / 0 = unset): the churn process and its
+    # end, the raft machines' log size, the send latency's range
+    "churn": (str, ""),
+    "churn_until": (float, 0.0),
+    "log_capacity": (int, 0),
+    "latency": (str, ""),
     "coverage": (bool, False),
     "provenance": (bool, False),
     "flight_recorder": (bool, False),
@@ -182,7 +188,7 @@ def normalize_spec(spec: dict) -> dict:
             if isinstance(v, bool) or not isinstance(v, int):
                 raise ValueError(f"spec field {name!r} must be an int, got {v!r}")
         elif typ is str:
-            if not isinstance(v, str) or not v:
+            if not isinstance(v, str) or not (v or default == ""):
                 raise ValueError(f"spec field {name!r} must be a non-empty string")
         out[name] = v
     if out["seeds"] < 1 or out["batch"] < 1:
@@ -299,6 +305,14 @@ def repro_cmd(spec: dict, *, batch_index: Optional[int] = None) -> str:
         f"--fault-kinds {spec['fault_kinds']}",
         f"--rng-stream {spec['rng_stream']}",
     ]
+    from ..__main__ import deployment_flags_str
+
+    deployment = deployment_flags_str(
+        spec.get("churn"), spec.get("churn_until"), spec.get("log_capacity"),
+        spec.get("latency"),
+    ).strip()
+    if deployment:
+        parts.append(deployment)
     if spec.get("devices"):
         parts.append(f"--devices {spec['devices']}")
     for flag, key in (("--strict-restart", "strict_restart"),
@@ -322,6 +336,10 @@ def engine_key(spec: dict) -> str:
         "coverage", "provenance", "flight_recorder", "batch",
     )
     key = {f: spec[f] for f in fields}
+    # .get: docs persisted before these flags have none of them
+    for f in ("churn", "churn_until", "log_capacity", "latency"):
+        if spec.get(f):
+            key[f] = spec[f]
     # mesh size shapes the compiled program (explicit shardings are in
     # the jit); .get keeps pre-mesh docs readable (unsharded group)
     key["devices"] = spec.get("devices", 0)

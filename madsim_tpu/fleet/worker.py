@@ -754,7 +754,7 @@ class FleetWorker:
         word seeding the candidate order, with `why`-style attribution
         decoded from the same word."""
         shrink_mod = importlib.import_module("madsim_tpu.engine.shrink")
-        from ..__main__ import fault_kinds_str
+        from ..__main__ import config_deployment_flags_str, fault_kinds_str
 
         spec = job.spec
         prov = {int(k): int(v) for k, v in (ck.get("prov") or {}).items()}
@@ -805,6 +805,8 @@ class FleetWorker:
                 f"--max-steps {sr.steps} "
                 f"--fault-kinds {fault_kinds_str(f)} "
                 + ("--strict-restart " if f.strict_restart else "")
+                + config_deployment_flags_str(
+                    sr.shrunk, spec.get("log_capacity") or 0)
                 + f"--rng-stream {sr.shrunk.rng_stream}"
             )
             if seed in prov:
@@ -843,6 +845,7 @@ class FleetWorker:
                 entry = corpus.CorpusEntry(
                     machine=job.spec["machine"],
                     nodes=job.spec["nodes"],
+                    log_capacity=job.spec.get("log_capacity") or 0,
                     seed=doc["seed"],
                     fail_code=doc["code"],
                     status=corpus.STATUS_OPEN,

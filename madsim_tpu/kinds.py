@@ -37,6 +37,12 @@ FAULT_KIND_NAMES = (
 # Bernoulli duplicate-delivery gate and crash-with-amnesia restarts.
 FR_EXTRA_NAMES = ("dup", "amnesia")
 
+# The churn process's counters (`FaultPlan.churn`, engine/core.py
+# `LaneState.churn`): the ticks fired and the faults they applied. They
+# ride the flight recorder's metrics vector after the high-water marks
+# while the process is on.
+FR_CHURN_NAMES = ("ticks", "disconnects", "reconnects")
+
 # kind name -> FaultPlan field, in K_* index order.
 KIND_TO_FLAG = (
     ("pair", "allow_partition"),

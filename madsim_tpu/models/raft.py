@@ -118,6 +118,19 @@ class RaftMachine(Machine):
     # networks either way — every recorded no-dup seed replays unchanged.
     DUP_VOTE_COUNT = False
 
+    # Commit rule for entries of EARLIER terms. False (correct, Raft
+    # §5.4.2): a leader commits by counting replicas only entries of its
+    # own term; older ones commit with them (Log Matching). True is the
+    # bug the paper's Figure 8 is drawn for: an old-term entry that has
+    # reached a majority is committed by count, and a later leader whose
+    # log never held it overwrites it — caught by the LogMatching check
+    # (code 102). Reaching it takes leaders that are cut off with
+    # entries half replicated and come back terms later: the churn
+    # process (`FaultPlan.churn`, `--churn fig8`) finds it, and so do
+    # two scheduled kills or partitions on a lossy net (6 of 65,536
+    # seeds against 1 with the churn, my chip run, PR 27).
+    COMMIT_OLD_TERM_BY_COUNT = False
+
     def __init__(self, num_nodes: int = 5, log_capacity: int = 8):
         if num_nodes > 31:
             raise ValueError(
@@ -491,6 +504,8 @@ class RaftMachine(Machine):
             cnt = jnp.sum(replicated, axis=1)
             cur_term_entry = nodes.log_term[node] == nodes.term[node]  # [CAP+1]
             quorum = self.majority - 1 if self.QUORUM_OFF_BY_ONE else self.majority
+            if self.COMMIT_OLD_TERM_BY_COUNT:
+                cur_term_entry = jnp.bool_(True)
             committable = (cnt >= quorum) & cur_term_entry & (idxs >= 1) & (idxs <= nodes.log_len[node])
             best = jnp.max(jnp.where(committable, idxs, 0))
             nodes = update_node(
@@ -527,6 +542,13 @@ class RaftMachine(Machine):
         ok = ~(elec_viol | log_viol)
         code = jnp.where(elec_viol, ELECTION_SAFETY, jnp.where(log_viol, LOG_MATCHING, 0))
         return ok, code.astype(jnp.int32)
+
+    def churn_victim(self, nodes: RaftState, connected):
+        """Figure 8's churn disconnects THE LEADER: the connected node
+        with role LEADER, the lowest index where two terms' leaders are
+        both connected; -1 while there is none."""
+        lead = connected & (nodes.role == LEADER)
+        return jnp.where(lead.any(), jnp.argmax(lead), -1).astype(jnp.int32)
 
     def is_done(self, nodes: RaftState, now_us):
         # all nodes committed a full log => nothing left to explore
@@ -590,3 +612,11 @@ class DupVoteRaft(RaftMachine):
     (caught by dup chaos)."""
 
     DUP_VOTE_COUNT = True
+
+
+class Fig8Raft(RaftMachine):
+    """Bug variant (`demo-fig8-raft`): commits an earlier term's entry
+    by counting replicas (Raft §5.4.2, Figure 8; found under `--churn
+    fig8` and under scheduled kills alike)."""
+
+    COMMIT_OLD_TERM_BY_COUNT = True

@@ -42,6 +42,8 @@ CKPT_REQUIRED_KEYS = frozenset({
     "seeds_consumed", "failing", "infra", "abandoned", "done",
 })
 
+_UNSET_IS_NONE = frozenset({"churn", "churn_until", "log_capacity", "latency"})
+
 # args fields that must match for a resume to be sound: anything that
 # changes which seeds run, in what order, or what they mean.
 _FINGERPRINT_FIELDS = (
@@ -64,11 +66,19 @@ _FINGERPRINT_FIELDS = (
     # bias-selected batches): resuming a guided checkpoint without
     # --guided (or vice versa) would blend two different hunts
     "guided",
+    # the deployment's flags (PR 27). Unset reads None whatever the
+    # caller's way of leaving a flag out ('' / 0 in a fleet spec), so a
+    # checkpoint from before them still belongs to its run
+    "churn",
+    "churn_until",
+    "log_capacity",
+    "latency",
 )
 
 
 def fingerprint_from_args(args) -> dict:
-    return {f: getattr(args, f, None) for f in _FINGERPRINT_FIELDS}
+    return {f: getattr(args, f, None) or None if f in _UNSET_IS_NONE
+            else getattr(args, f, None) for f in _FINGERPRINT_FIELDS}
 
 
 def save_checkpoint(path: str, state: dict) -> None:

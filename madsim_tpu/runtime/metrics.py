@@ -19,6 +19,7 @@ if TYPE_CHECKING:
 # bind — via madsim_tpu/kinds.py (pure literals, no jax import), so
 # this host-side decoder can never drift from the device counters.
 from ..kinds import FAULT_KIND_NAMES as FR_FAULT_KINDS
+from ..kinds import FR_CHURN_NAMES as FR_CHURN
 from ..kinds import FR_EXTRA_NAMES as FR_EXTRAS
 
 # Causal-provenance word layout (mirrors engine/core.py PROV_*): bits
@@ -51,9 +52,13 @@ def fr_metrics_dict(vec: Sequence[int]) -> Dict[str, object]:
     killed-node high-water marks."""
     v = [int(x) for x in vec]
     nk, ne = len(FR_FAULT_KINDS), len(FR_EXTRAS)
-    if len(v) != nk + ne + 3:
-        raise ValueError(f"expected {nk + ne + 3} metric words, got {len(v)}")
-    return {
+    base = nk + ne + 3
+    if len(v) not in (base, base + len(FR_CHURN)):
+        raise ValueError(
+            f"expected {base} metric words (+{len(FR_CHURN)} with a churn "
+            f"process), got {len(v)}"
+        )
+    out = {
         "faults_injected": dict(zip(FR_FAULT_KINDS, v[:nk])),
         "dup_injected": v[nk],
         "amnesia_restarts": v[nk + 1],
@@ -61,6 +66,10 @@ def fr_metrics_dict(vec: Sequence[int]) -> Dict[str, object]:
         "clog_links_hwm": v[nk + ne + 1],
         "killed_hwm": v[nk + ne + 2],
     }
+    if len(v) > base:
+        # FaultPlan.churn: the ticks fired and the faults they applied
+        out["churn"] = dict(zip(FR_CHURN, v[base:]))
+    return out
 
 
 class RuntimeMetrics:
