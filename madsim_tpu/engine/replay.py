@@ -263,26 +263,30 @@ def _bare_twin(engine: Engine) -> Engine:
 
 
 def _fast_outcome_fn(engine: Engine):
-    """One jitted dispatch for a whole no-trace replay: while-loop of
-    freeze-wrapped lane_steps (a done/failed lane passes through
-    untouched, so the final state is bit-exactly the state at the
-    stopping step). max_steps and horizon ride as traced scalars — one
+    """One jitted dispatch for a whole no-trace replay: a `while_loop`
+    of lane_steps that stops where its lane stops, on `done | failed`
+    or at `n_steps`, whichever comes first — a lane that ends after 16
+    events runs 16 iterations whatever `max_steps` is. It returns the
+    final state (bit-exactly the state at the stopping step) and the
+    trip count. max_steps and horizon ride as traced scalars — one
     compile serves every shrink candidate and every seed."""
+    import jax.numpy as jnp
     from jax import lax
 
     def build():
         twin = _bare_twin(engine)
 
         def run(state: LaneState, horizon_us, n_steps):
-            def body(_i, s):
-                return lax.cond(
-                    s.done | s.failed,
-                    lambda x: x,
-                    lambda x: twin.lane_step(x, horizon_us=horizon_us),
-                    s,
-                )
+            def live(carry):
+                i, s = carry
+                return (i < n_steps) & ~(s.done | s.failed)
 
-            return lax.fori_loop(0, n_steps, body, state)
+            def body(carry):
+                i, s = carry
+                return i + 1, twin.lane_step(s, horizon_us=horizon_us)
+
+            i, state = lax.while_loop(live, body, (jnp.zeros_like(n_steps), state))
+            return state, i
 
         return jax.jit(run)
 
@@ -302,7 +306,10 @@ def replay_outcome(engine: Engine, seed: int, max_steps: int = 10_000) -> Replay
     """Traceless replay of one seed in a single compiled dispatch —
     bit-identical final state (same lane_step ops), ~1000x fewer host
     round-trips than the eager trace path. The shrink verification
-    workhorse."""
+    workhorse. Its cost follows the lane, not `max_steps`: the loop's
+    trip count (`trips` on the `replay` span, summed in the counter
+    `replay.loop_trips`) is the lane's event count, or `max_steps` for a
+    lane cut there."""
     import jax.numpy as jnp
 
     with maybe_span("replay", seed=int(seed), traced=False), \
@@ -314,8 +321,10 @@ def replay_outcome(engine: Engine, seed: int, max_steps: int = 10_000) -> Replay
             with _first_call("replay.run"):
                 out = fn(*args)  # returns once compiled and enqueued
         with maybe_span("replay_run"):
-            state = jax.device_get(out if fresh else fn(*args))
-        maybe_note(steps=int(state.step))
+            state, trips = jax.device_get(out if fresh else fn(*args))
+        trips = int(trips)
+        maybe_note(steps=int(state.step), trips=trips)
+        maybe_count("replay.loop_trips", trips)
         return ReplayResult(state=state, trace=[])
 
 
