@@ -1,10 +1,10 @@
-# Dual-mode test/bench targets (reference: madsim's Makefile drives
+# Dual-mode test targets (reference: madsim's Makefile drives
 # `cargo test` and `RUSTFLAGS="--cfg madsim" cargo test`; here the modes
 # are sim [default], real sockets, and the TPU engine CLI).
 
 PY ?= python
 
-.PHONY: test stest rtest check lint lint-fast bench rpc-bench explore examples audit
+.PHONY: test stest rtest check lint lint-fast rpc-bench explore examples audit
 
 # full suite (host engine + TPU engine on a hermetic 8-dev CPU mesh)
 test:
@@ -45,10 +45,6 @@ lint:
 lint-fast:
 	$(PY) -m madsim_tpu lint madsim_tpu/ --cache --no-import-check --changed
 
-# flagship benchmark (one JSON line; real chip when available)
-bench:
-	$(PY) bench.py
-
 # reference-criterion-style microbenches
 rpc-bench:
 	$(PY) benches/rpc_bench.py
@@ -60,7 +56,3 @@ examples:
 	$(PY) examples/raft_host.py 10
 	$(PY) examples/chaos_pipeline.py 42
 	$(PY) examples/delay_hunt.py
-
-# the round-5 chip sweeps, one shot (one process at a time holds the chip)
-chip-sweeps:
-	sh benches/chip_sweeps_r5.sh

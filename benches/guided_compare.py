@@ -39,7 +39,6 @@ import time as wall
 from types import SimpleNamespace
 
 # runnable from a bare checkout (`python benches/guided_compare.py`)
-# like benches/tpu_sweep.py
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 #: (model, nodes, faults, horizon_s, max_steps) — tiny-but-honest

@@ -44,8 +44,7 @@ import traceback
 from importlib import metadata
 from unittest import mock
 
-#: the sizes a run on the chip uses (ISSUE 21; `BASELINE.json` config 3,
-#: `bench.py`, `benches/chip_sweeps_r5.sh` rows 2 and 4)
+#: the sizes a run on the chip uses (ISSUE 21; `BASELINE.json` config 3)
 FULL = {
     "lanes": 8192,
     "flagship_seeds": 16384,
@@ -254,7 +253,7 @@ def on_cpu():
 
 
 def flagship_argv(out_dir: str, tag: str, seeds: int, batch: int) -> list:
-    # the CLI's nearest to bench.py's flagship config
+    # the flagship config: `benchmark/configs/raft5.json`'s flags
     return [
         "explore", "--machine", "raft", "--stream",
         "--seeds", str(seeds), "--batch", str(batch),

@@ -6,7 +6,6 @@ determinism-check mode, as a command line:
   python -m madsim_tpu explore --machine raft --seeds 4096 [--faults 2]
   python -m madsim_tpu replay  --machine raft --seed 1234 [--tail 30]
   python -m madsim_tpu check   --machine kv   --seeds 64
-  python -m madsim_tpu bench   [--lanes 4096]
 
 `explore` prints failing seeds (the reference prints
 `MADSIM_TEST_SEED=...` repro hints; here the seed IS the repro:
@@ -356,15 +355,8 @@ def _perf_session(args):
 
 
 def _stream_kwargs(args) -> dict:
-    """Pipelined-executor knobs shared by explore/hunt/bench (default:
-    pipelined + donated; --no-pipeline restores the r5 per-segment
-    driver, kept for one release)."""
-    kw = {
-        "pipelined": not getattr(args, "no_pipeline", False),
-        "segments_per_dispatch": getattr(args, "segments_per_dispatch", 8),
-        "dispatch_depth": getattr(args, "dispatch_depth", 4),
-        "donate": not getattr(args, "no_donate", False),
-    }
+    """What `--devices N` adds to a run_stream call: the mesh."""
+    kw = {}
     n = getattr(args, "devices", 0)
     if n:
         import jax
@@ -872,7 +864,7 @@ def cmd_explore(args) -> int:
     eng = _build_engine(args)
     if args.stream:
         # seed streaming: finished lanes refill with fresh seeds — the
-        # high-throughput path for large batches (bench.py's path),
+        # high-throughput path for large batches,
         # chunked into --batch-seed batches so long runs heartbeat,
         # emit stats and can stop on a coverage plateau
         out = _stream_batches(eng, args, purpose="explore")
@@ -1875,8 +1867,8 @@ def cmd_perf(args) -> int:
 def _cmd_prof_compile(args) -> int:
     """`prof compile`: the compile autopsy — trace_s / lower_s /
     backend_s per streaming fn at this shape, plus cost_analysis
-    flops/bytes and memory_analysis peak bytes, keyed by the same
-    `cache_subkey` bench.py warms. One JSON line + a table."""
+    flops/bytes and memory_analysis peak bytes, keyed by this shape's
+    `cache_subkey`. One JSON line + a table."""
     from .compile_cache import cache_subkey
     from .utils import device_info
 
@@ -1886,8 +1878,6 @@ def _cmd_prof_compile(args) -> int:
         batch=args.batch,
         segment_steps=384,
         max_steps=args.max_steps,
-        segments_per_dispatch=sk["segments_per_dispatch"],
-        donate=sk["donate"],
         mesh=sk.get("mesh"),
     )
     subkey = cache_subkey(
@@ -2019,155 +2009,6 @@ def cmd_prof(args) -> int:
     return 0
 
 
-_AB_GATES = ("flight_recorder", "coverage", "provenance", "clog-packed",
-             "rng-stream", "coverage-unbuffered")
-
-
-def cmd_bench_ab(args) -> int:
-    """Interleaved A/B cost of ONE engine gate: ABAB… alternating reps
-    in one process over identical seed ranges, median of PAIRED deltas
-    with a seeded-bootstrap 95% CI and an exact sign test
-    (madsim_tpu/perf/ab.py) — the protocol that replaced the one-rep
-    step_cost after it misread the provenance gate by 13x on this
-    drifting box (PR 7's receipt: 8% single-rep vs 0.61% interleaved).
-    Prints one JSON line + a human summary."""
-    from .engine import Engine
-    from .perf.ab import interleaved_ab
-    from .perf.recorder import current_recorder
-    from .utils import device_info
-
-    eng = _build_engine(args)
-    base = eng.config
-    if args.gate == "rng-stream":
-        cfg_a = dataclasses.replace(base, rng_stream=3)
-        cfg_b = dataclasses.replace(base, rng_stream=2)
-        label_a, label_b = "rng_stream=3", "rng_stream=2"
-    elif args.gate == "coverage-unbuffered":
-        # the r12 escape hatch's own cost: the flush-on-freeze buffered
-        # fold (cov_buffer default) vs the old per-event map scatter
-        # (cov_buffer=0) with coverage ON in both — final maps are
-        # bit-identical, so the delta is pure fold mechanics
-        cfg_a = dataclasses.replace(base, coverage=True)
-        cfg_b = dataclasses.replace(base, coverage=True, cov_buffer=0)
-        label_a, label_b = "cov_buffer=on", "cov_buffer=0"
-    else:
-        field = args.gate.replace("-", "_")
-        cfg_a = dataclasses.replace(base, **{field: True})
-        cfg_b = dataclasses.replace(base, **{field: False})
-        label_a, label_b = f"{field}=on", f"{field}=off"
-    lanes = args.lanes or 1024
-    n_rep = args.seeds or 2 * lanes
-    sk = _stream_kwargs(args)
-    runs = {}
-    for tag, cfg in (("a", cfg_a), ("b", cfg_b)):
-        run = Engine(eng.machine, cfg).make_stream_runner(
-            batch=lanes, segment_steps=384, max_steps=args.max_steps, **sk
-        )
-        # compile + one full untimed rep: the harness measures steady
-        # state, never compilation or a cold first rep
-        run(1)
-        run(n_rep, seed_start=500_000)
-        runs[tag] = run
-
-    res = interleaved_ab(
-        lambda s: runs["a"](n_rep, seed_start=s)["completed"],
-        lambda s: runs["b"](n_rep, seed_start=s)["completed"],
-        pairs=args.reps,
-        seed_start=args.seed,
-        seeds_per_rep=4 * n_rep,
-        label_a=label_a,
-        label_b=label_b,
-        recorder=current_recorder(),
-    )
-    print(json.dumps({
-        "metric": f"{args.gate}_ab_delta_pct",
-        "gate": args.gate,
-        "machine": args.machine,
-        **device_info(),
-        "lanes": lanes,
-        "seeds_per_rep": n_rep,
-        **res.to_dict(),
-    }))
-    print(res.summary())
-    return 0
-
-
-def _cmd_bench_report(args) -> int:
-    """`bench report`: render the BENCH_HISTORY.jsonl trend (seeding it
-    from the legacy BENCH_r*.json series when absent). Pure stdlib — no
-    jax, works on a box with no accelerator stack."""
-    from .perf import history
-
-    path = args.history or history.DEFAULT_BASENAME
-    rows = history.load_or_seed(path)
-    print(history.render_report(rows))
-    return 0
-
-
-def cmd_bench(args) -> int:
-    if getattr(args, "action", None) == "report":
-        return _cmd_bench_report(args)
-    if args.lanes < 0 or args.reps < 1 or args.seeds < 1:
-        sys.exit("bench needs --lanes >= 1 (or 0 = default), --reps >= 1, --seeds >= 1")
-    if not getattr(args, "machine", None):
-        import bench  # repo-root bench.py when run from checkout
-
-        argv = ["bench.py"]
-        if args.lanes or args.reps != 3:
-            argv.append(str(args.lanes or 8192))
-        if args.reps != 3:
-            argv.append(str(args.reps))
-        sys.argv = argv
-        bench.main()
-        return 0
-
-    # per-machine throughput: stream `--seeds` with the same statistical
-    # discipline as the flagship bench (compile + warm, median of reps)
-    import statistics
-    import time as wall
-
-    from .utils import device_info
-
-    eng = _build_engine(args)
-    lanes = args.lanes or 8192
-    n = max(args.seeds, lanes)
-    run = eng.make_stream_runner(
-        batch=lanes, segment_steps=384, max_steps=args.max_steps,
-        **_stream_kwargs(args),
-    )
-    run(64)
-    run(n, seed_start=500_000)
-    rates = []
-    fails = 0
-    out = None
-    for r in range(args.reps):
-        t0 = wall.perf_counter()
-        out = run(n, seed_start=args.seed + r * 4 * n)
-        rates.append(out["completed"] / (wall.perf_counter() - t0))
-        fails += len(out["failing"]) + len(out["infra"])
-    st = out["stats"]
-    print(json.dumps({
-        "metric": f"{args.machine}_seeds_per_sec",
-        "value": round(statistics.median(rates), 1),
-        "unit": "seeds/sec",
-        **device_info(),
-        "diagnostics": {
-            "reps": [round(x, 1) for x in rates],
-            "failing_total": fails,
-            "lanes": lanes,
-            "queue_capacity": args.queue,
-            "fault_kinds": getattr(args, "fault_kinds", "pair,kill"),
-            "host_syncs": st["host_syncs"],
-            "device_segments": st["device_segments"],
-            "dispatch_depth": st["dispatch_depth"],
-            "segments_per_dispatch": st["segments_per_dispatch"],
-            "donation": st["donation"],
-            "pipelined": st["pipelined"],
-        },
-    }))
-    return 0
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="madsim_tpu")
     sub = parser.add_subparsers(dest="cmd", required=True)
@@ -2291,25 +2132,7 @@ def main(argv=None) -> int:
         )
 
     def stream_flags(p):
-        """Pipelined streaming-executor knobs (explore/hunt/bench)."""
-        p.add_argument(
-            "--no-pipeline", action="store_true",
-            help="use the r5 per-segment driver (one blocking host sync "
-            "per segment) instead of the pipelined executor",
-        )
-        p.add_argument(
-            "--segments-per-dispatch", type=int, default=8,
-            help="segments fused into one device dispatch (supersegment)",
-        )
-        p.add_argument(
-            "--dispatch-depth", type=int, default=4,
-            help="async dispatches in flight between blocking counter polls",
-        )
-        p.add_argument(
-            "--no-donate", action="store_true",
-            help="disable StreamCarry buffer donation (keeps the r5 "
-            "copy-per-call behavior; results are bit-identical either way)",
-        )
+        """Streaming-run flags (explore/hunt/perf)."""
         p.add_argument(
             "--devices", type=int, default=0, metavar="N",
             help="span the hunt over the first N devices as one jitted "
@@ -2518,60 +2341,6 @@ def main(argv=None) -> int:
     common(p)
     p.add_argument("--seeds", type=int, default=64)
     p.set_defaults(fn=cmd_check)
-
-    p = sub.add_parser(
-        "bench",
-        help="flagship benchmark (one JSON line); with --machine, a "
-        "streaming throughput bench of any registered machine; "
-        "`bench report` renders the BENCH_HISTORY.jsonl trend (jax-free)",
-    )
-    common(p)  # one source of truth for the engine flags
-    p.add_argument(
-        "action", nargs="?", choices=("report",), default=None,
-        help="report: render the drift-aware bench history trend "
-        "(per-capture delta vs its own comparable neighbor — same "
-        "platform/lanes/gates/host; seeds the history from the legacy "
-        "BENCH_r*.json series on first use)",
-    )
-    p.add_argument("--lanes", type=int, default=0)
-    p.add_argument("--seeds", type=int, default=16384, help="seeds per rep")
-    p.add_argument("--reps", type=int, default=3)
-    p.add_argument(
-        "--history", default=None, metavar="PATH",
-        help="bench history JSONL to render/append "
-        "(default ./BENCH_HISTORY.jsonl)",
-    )
-    stream_flags(p)
-    # bench-specific defaults: no machine = the flagship bench.py, and
-    # timed seed ranges start clear of the validation sweeps
-    p.set_defaults(fn=cmd_bench, machine=None, seed=1_000_000)
-
-    p = sub.add_parser(
-        "bench-ab",
-        help="interleaved A/B cost of one engine gate: ABAB… paired "
-        "reps over identical seed ranges in one process; median paired "
-        "delta with bootstrap 95%% CI + sign test (one JSON line). The "
-        "drift-robust replacement for single-rep gate costing",
-    )
-    common(p)
-    p.add_argument(
-        "--gate", required=True, choices=_AB_GATES,
-        help="the gate to cost: A runs it on, B off (rng-stream: "
-        "A=v3 vs B=v2); every other engine flag comes from the usual "
-        "options, so you can cost a gate on top of any configuration",
-    )
-    p.add_argument("--lanes", type=int, default=1024, help="lanes per streaming batch")
-    p.add_argument(
-        "--seeds", type=int, default=0,
-        help="seeds per rep (0 = 2*lanes)",
-    )
-    p.add_argument(
-        "--reps", type=int, default=4, metavar="PAIRS",
-        help="A/B rep PAIRS (4 pairs ≈ the PR-7 hand protocol; 2 is "
-        "the CI smoke minimum)",
-    )
-    stream_flags(p)
-    p.set_defaults(fn=cmd_bench_ab, seed=3_000_000)
 
     p = sub.add_parser(
         "perf",
@@ -3054,12 +2823,9 @@ def main(argv=None) -> int:
         )
     if args.cmd == "perf":
         # the out positional IS the host timeline: cmd_perf runs under
-        # the same --perf-timeline session as explore/hunt/bench
+        # the same --perf-timeline session as explore/hunt
         args.perf_timeline = args.out
     jax_free = args.cmd in ("serve", "coverage", "lint") or (
-        # `bench report` renders history with no jax import at all
-        args.cmd == "bench" and getattr(args, "action", None) == "report"
-    ) or (
         # the whole fleet control plane (serve + client verbs + fsck +
         # chaos orchestration) is jax-free by contract; only a worker
         # with the real driver runs engines — the chaos harness's
@@ -3081,7 +2847,7 @@ def main(argv=None) -> int:
         # (compile_cache.enable_compile_cache: $JAX_COMPILATION_CACHE_DIR,
         # else --compile-cache / $MADSIM_TPU_COMPILE_CACHE, else the
         # checkout default) BEFORE the
-        # subcommand's first jit, so hunt/explore/bench-ab warmups
+        # subcommand's first jit, so hunt/explore warmups
         # read and write the cache from their very first compile —
         # enabling is first-directory-wins per process, and an engine
         # constructed before the cache was bound would pay a full

@@ -1,13 +1,12 @@
 """L-rules: jax-free layer enforcement over the import graph.
 
 The hunt farm's control plane (`fleet serve` / `submit` / `status`),
-the guided-search bias math, the bench-history renderer and this
-analysis package all ship a hard promise: **importing them never
-imports jax**. Until now that promise lived in docstring sentences
-("Pure host-side stdlib — no jax import anywhere in this module",
-`fleet/store.py`) and one subprocess test; a single careless
-`from ..engine import shrink` at the top of a fleet module would break
-`fleet serve`'s startup cost, the chaos harness's 0.3 s synthetic
+the guided-search bias math and this analysis package all ship a
+hard promise: **importing them never imports jax**. Until now that
+promise lived in docstring sentences ("Pure host-side stdlib — no
+jax import anywhere in this module", `fleet/store.py`) and one
+subprocess test; a single careless `from ..engine import shrink` at
+the top of a fleet module would break `fleet serve`'s startup cost, the chaos harness's 0.3 s synthetic
 workers, and every jax-less deployment — and nothing static would say
 so. These rules make the layer map declarative and the check
 whole-program:
@@ -23,11 +22,10 @@ L002  a jax-free module eagerly imports a PROJECT module whose eager
 L003  gated-import discipline: a *function-local* (lazy) import of a
       jax-reaching module from a jax-free module is only legal through
       a recorded gate — either a `try:/except ImportError` optional-
-      dependency probe (`perf/history.py`'s version stamp) or an
-      inline justified allowance; and any call from the zone to an
-      `import_jax`-gated helper (`compile_cache.cache_subkey`) must
-      pass the literal `import_jax=False` (the idiom
-      `fleet/store.job_subkey` records)
+      dependency probe or an inline justified allowance; and any call
+      from the zone to an `import_jax`-gated helper
+      (`compile_cache.cache_subkey`) must pass the literal
+      `import_jax=False` (the idiom `fleet/store.job_subkey` records)
 
 The zone below is the layer map. Adding a module to the zone is a
 claim reviewers can hold you to; removing one is a visible contract
@@ -64,7 +62,6 @@ JAX_FREE_ZONE = (
     "madsim_tpu.fleet.scheduler",
     "madsim_tpu.fleet.store",
     "madsim_tpu.kinds",
-    "madsim_tpu.perf.history",
     "madsim_tpu.search.bias",
 )
 

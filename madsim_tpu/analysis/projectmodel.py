@@ -10,7 +10,7 @@ the shared substrate the L/T passes spend:
   *eager* (module/class level — executed at import time) vs *lazy*
   (function-local — executed at call time) and *guarded* (directly
   inside a ``try`` whose handler catches ImportError — the
-  optional-dependency idiom, e.g. `perf/history.py`'s version probe).
+  optional-dependency idiom).
   Importing `a.b.c` also executes `a/__init__.py` and `a/b/__init__.py`,
   so every edge to a project module fans out to its package ancestors —
   the exact channel through which an innocent-looking

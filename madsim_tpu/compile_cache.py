@@ -28,9 +28,9 @@ allocator groups same-compile jobs by it and the AOT artifacts below
 are filed under it. It is not part of the XLA cache path.
 
 Failure discipline: an unwritable directory is probed by an actual
-write: `strict=True` (bench, priming jobs) raises; the default logs a
-warning and leaves the cache off — never a silent no-op that lets a
-fleet believe it is warm while every worker recompiles.
+write: `strict=True` (the benchmark, chip_smoke.py) raises; the default
+logs a warning and leaves the cache off — never a silent no-op that
+lets a fleet believe it is warm while every worker recompiles.
 """
 
 from __future__ import annotations
@@ -54,7 +54,7 @@ _log = logging.getLogger("madsim_tpu.compile_cache")
 # -- AOT supersegment serialization (r12) ------------------------------------
 #
 # The persistent XLA cache above removes the *compile* half of a warm
-# worker's start cost; BENCH_r11 measured the remaining 18.2 s flagship
+# worker's start cost; round 11 measured the remaining 18.2 s flagship
 # warm start as TRACE-dominated — jax re-traces the streaming program
 # every process even when the executable deserializes. `jax.export`
 # closes that half: the engine serializes the exported (traced +
@@ -191,7 +191,7 @@ def cache_subkey(
     single-device job in different groups (their compiled programs
     share nothing).
 
-    `gates` is the bench-style dict ({"rng_stream": 3, "coverage":
+    `gates` is a dict ({"rng_stream": 3, "coverage":
     True, ...}); bool values render as 0/1, the rest as-is. Unknown /
     None fields are simply omitted — the key is best-effort
     discrimination, jax's internal (HLO, jaxlib, flags, device) key is
@@ -332,8 +332,8 @@ def measure_warm_compile(build_and_run, cold_trace: bool = False) -> Optional[fl
     """Time the WARM compile path: drop every in-process jit cache,
     then run `build_and_run` (which must construct fresh jitted
     callables and force their compilation — invoke once, or compile
-    without executing via `Engine.compile_stream` / `.lower().compile()`
-    so device execution stays out of the timed window) against the
+    without executing via `.lower().compile()` so device execution
+    stays out of the timed window) against the
     persistent entries the cold path just wrote — the exact path a new
     fleet worker or a post-restart replay pays. Returns seconds, or
     None when no persistent cache is active (there is no warm path to

@@ -5,11 +5,9 @@ See `core.py` for the architecture. Public surface:
   * `Machine` — protocol step-function authoring base (machine.py)
   * `Engine(machine, EngineConfig)` — batch runner: `make_runner()`,
     `run_batch(seeds)`, `failing_seeds(result)`
-  * `Engine.run_stream(n_seeds, ...)` / `make_stream_runner(...)` — the
-    pipelined streaming executor: donated `StreamCarry`, device-side
-    supersegments (`segments_per_dispatch`), K-deep async dispatch
-    (`dispatch_depth`); `pipelined=False` keeps the r5 per-segment
-    driver for one release (bit-identical results either way)
+  * `Engine.run_stream(n_seeds, ...)` — the pipelined streaming
+    executor: donated `StreamCarry`, device-side supersegments
+    (`segments_per_dispatch`), K-deep async dispatch (`dispatch_depth`)
   * `replay(engine, seed)` — bit-identical single-seed CPU replay
   * `FaultPlan` — randomized chaos schedules: pair/dir/group
     partitions, kill/restart, loss storms, delay spikes, pause/resume

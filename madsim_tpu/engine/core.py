@@ -177,7 +177,7 @@ _DIGEST_M0 = 0x9E3779B1
 _DIGEST_M1 = 0x85EBCA6B
 
 # FaultPlan kind names, indexed by K_* — the fault-injection counter
-# labels used by run_stream stats / bench / audit output. The table
+# labels used by run_stream stats / audit output. The table
 # lives in madsim_tpu/kinds.py (single source of truth for every host
 # mirror; `python -m madsim_tpu lint` cross-checks the consumers).
 FAULT_KIND_NAMES = _kinds.FAULT_KIND_NAMES
@@ -560,10 +560,8 @@ class EngineConfig:
     # fold, r12): > 0 buffers each popped event's slot index in a tiny
     # int32[cov_buffer] per-lane ring and folds the packed bit map only
     # on a fixed segment cadence, at segment exit, and therefore at
-    # every freeze point — removing the per-event map RMW scatter that
-    # BENCH_r11 measured at -7.37% of step throughput. 0 = the
-    # unbuffered per-event scatter (the escape hatch / differential
-    # oracle; A/B-able via `bench-ab --gate coverage-unbuffered`).
+    # every freeze point — removing the per-event map RMW scatter.
+    # 0 = the unbuffered per-event scatter (the differential oracle).
     # Final maps are bit-identical either way — OR is commutative and
     # idempotent, and the executor's segment-exit flush runs
     # unconditionally, so frozen lanes can never strand buffered slots.
@@ -2289,30 +2287,28 @@ class Engine:
 
         `segment` / `supersegment` / `reset_rings` donate their
         StreamCarry argument when `donate` (the multi-MB lane state is
-        aliased in place instead of copied in HBM every call; toggle
-        kept for one release so bit-identity vs the undonated path stays
-        assertable). A donated carry is CONSUMED: never touch a carry
-        after passing it back in — read counters/rings first.
+        aliased in place instead of copied in HBM every call;
+        `donate=False` is the tests' reference for bit-identity). A
+        donated carry is CONSUMED: never touch a carry after passing it
+        back in — read counters/rings first.
 
-        `supersegment` is the pipelined executor's device half: an inner
-        `lax.while_loop` advances up to `segments_per_dispatch` whole
-        segments (refill + advance + harvest each) per host dispatch,
-        with the termination check (`completed < need`) and the
-        ring-pressure check ON DEVICE — the exact conditions the r5 host
-        loop evaluated between segments, so the executed segment
-        sequence is bit-identical to the per-segment driver. When a ring
-        crosses its drain mark (count > cap - batch) the loop parks
-        until the host drains, which bounds appends at `cap` regardless
-        of how many dispatches are in flight."""
+        `supersegment` is the pipelined executor's device half: a
+        `lax.scan` of `segments_per_dispatch` iterations, each a
+        `lax.cond` on the go-predicate (`completed < need` and no ring
+        past its drain mark) around one whole segment (refill + advance
+        + harvest) — the exact conditions the r5 host loop evaluated
+        between segments, checked ON DEVICE. The predicate is monotone
+        within a dispatch: `completed` only grows and the rings only
+        fill (drains happen on the host between dispatches), so once it
+        flips false it stays false, the parked iterations execute
+        nothing, and the executed segment sequence is bit-identical to
+        the per-segment driver's. When a ring crosses its drain mark
+        (count > cap - batch) the dispatch parks until the host drains,
+        which bounds appends at `cap` regardless of how many dispatches
+        are in flight."""
         cache = getattr(self, "_stream_cache", None)
         if cache is None:
             cache = self._stream_cache = {}
-        # scan-over-segments (r12): the supersegment's fixed-count
-        # dispatch loop as lax.scan of a predicated segment body
-        # instead of lax.while_loop. MADSIM_TPU_STREAM_SCAN=0 keeps the
-        # while form A/B-able for one release; both execute the
-        # bit-identical segment sequence (see supersegment below).
-        use_scan = os.environ.get("MADSIM_TPU_STREAM_SCAN", "1") != "0"
         if aot and mesh is not None:
             raise ValueError(
                 "AOT stream fns cannot serve a meshed run: jax.export "
@@ -2325,7 +2321,7 @@ class Engine:
         # measurement gate selects a quartet: the run that is profiled
         # compiles the program that was timed.
         key = (segment_steps, max_steps, ring_capacity, batch, donate,
-               segments_per_dispatch, use_scan, aot, mesh)
+               segments_per_dispatch, aot, mesh)
         if key in cache:
             return cache[key]
 
@@ -2570,39 +2566,16 @@ class Engine:
             return (cc.completed < need) & ~pressure
 
         def supersegment(c: StreamCarry, need) -> StreamCarry:
-            if use_scan:
-                # scan-over-segments: a fixed segments_per_dispatch trip
-                # count with the go-predicate as a per-iteration
-                # lax.cond (scalar, so the parked branch executes
-                # nothing). Bit-identical to the while form: completed
-                # only grows and the rings only fill WITHIN a dispatch
-                # (drains happen on the host between dispatches), so
-                # the go-predicate is monotone — once it flips false it
-                # stays false, and the executed segment prefix is
-                # exactly the while_loop's.
-                def body(cc, _):
-                    cc = lax.cond(
-                        _dispatch_go(cc, need),
-                        _segment_impl,
-                        lambda x: x,
-                        cc,
-                    )
-                    return cc, None
-
-                final, _ = lax.scan(
-                    body, c, None, length=segments_per_dispatch
+            def body(cc, _):
+                cc = lax.cond(
+                    _dispatch_go(cc, need),
+                    _segment_impl,
+                    lambda x: x,
+                    cc,
                 )
-                return final
+                return cc, None
 
-            def cond(carry):
-                cc, it = carry
-                return (it < segments_per_dispatch) & _dispatch_go(cc, need)
-
-            def body(carry):
-                cc, it = carry
-                return _segment_impl(cc), it + 1
-
-            final, _ = lax.while_loop(cond, body, (c, jnp.int32(0)))
+            final, _ = lax.scan(body, c, None, length=segments_per_dispatch)
             return final
 
         def reset_rings(c: StreamCarry) -> StreamCarry:
@@ -2810,83 +2783,13 @@ class Engine:
             out.append(jax.jit(_make_wrapped(exp), **kw))
         return tuple(out)
 
-    def measure_stream_trace(
-        self,
-        batch: int,
-        segment_steps: int = 256,
-        max_steps: int = 10_000,
-        segments_per_dispatch: int = 8,
-        donate: Optional[bool] = None,
-    ) -> float:
-        """Time the TRACE+LOWER phase of the streaming supersegment at
-        this shape — the component of a cold compile that `jax.jit`
-        re-pays every process even when the persistent XLA cache
-        serves the executable. bench.py reports it as `trace_s` next
-        to compile_s_cold/warm so TRACE- vs XLA-dominance is a
-        recorded number. `jitted.lower()` always re-traces, so calling
-        this AFTER the timed cold run leaves that measurement
-        untouched."""
-        import time
-
-        if donate is None:
-            donate = os.environ.get("MADSIM_TPU_STREAM_DONATE", "1") not in ("", "0")
-        init_carry, _segment, supersegment, _reset = self._stream_fns(
-            segment_steps, max_steps, 2 * batch, batch,
-            donate=donate, segments_per_dispatch=segments_per_dispatch,
-        )
-        seeds_aval = jax.ShapeDtypeStruct((batch,), jnp.uint32)
-        carry_aval = jax.eval_shape(init_carry, seeds_aval)
-        t0 = time.perf_counter()  # madsim: allow(D001) — host-side timing
-        supersegment.lower(carry_aval, jax.ShapeDtypeStruct((), jnp.int32))
-        return time.perf_counter() - t0  # madsim: allow(D001)
-
-    def compile_stream(
-        self,
-        batch: int,
-        segment_steps: int = 256,
-        max_steps: int = 10_000,
-        segments_per_dispatch: int = 8,
-        donate: Optional[bool] = None,
-    ) -> None:
-        """Force-compile the streaming quartet at this shape WITHOUT
-        executing a stream: build (or fetch) the jitted fns exactly as
-        the unsharded `run_stream` would — same `_stream_fns` cache
-        key, same AOT gating — then `.lower().compile()` each at its
-        declared avals. This is a worker's start cost in isolation:
-        trace (or AOT deserialize) + XLA compile (or persistent-cache
-        hit), with zero device execution mixed in. bench.py times this
-        as compile_s_cold / compile_s_warm; the old run(1)-based timing
-        conflated the start cost with the FIRST DISPATCH's execution,
-        which at the 8192-lane flagship shape on the 1-core CPU
-        reference box is ~17 s of fixed-shape compute — drowning the
-        ~1 s the warm start actually pays."""
-        from ..compile_cache import aot_enabled
-
-        if donate is None:
-            donate = os.environ.get("MADSIM_TPU_STREAM_DONATE", "1") not in ("", "0")
-        init_carry, segment, supersegment, reset_rings = self._stream_fns(
-            segment_steps, max_steps, 2 * batch, batch,
-            donate=donate, segments_per_dispatch=segments_per_dispatch,
-            aot=aot_enabled(),
-        )
-        seeds_aval = jax.ShapeDtypeStruct((batch,), jnp.uint32)
-        carry_aval = jax.eval_shape(init_carry, seeds_aval)
-        need_aval = jax.ShapeDtypeStruct((), jnp.int32)
-        for fn, avals in (
-            (init_carry, (seeds_aval,)),
-            (segment, (carry_aval,)),
-            (supersegment, (carry_aval, need_aval)),
-            (reset_rings, (carry_aval,)),
-        ):
-            fn.lower(*avals).compile()
-
     def stream_compile_autopsy(
         self,
         batch: int,
         segment_steps: int = 256,
         max_steps: int = 10_000,
         segments_per_dispatch: int = 8,
-        donate: Optional[bool] = None,
+        donate: bool = True,
         mesh=None,
     ) -> list:
         """Per-fn compile autopsy of the streaming quartet at this
@@ -2894,13 +2797,11 @@ class Engine:
         bytes and memory_analysis peak bytes for each of init_carry,
         segment, supersegment, reset_rings — the `compile_s` opaque
         total split into the three stages the [perf] open item needs
-        apart (perf/xprof.compile_autopsy; `prof compile`, bench.py).
+        apart (perf/xprof.compile_autopsy; `prof compile`).
         Re-traces by construction, so run it on a throwaway engine or
         accept the duplicate trace cost."""
         from ..perf import xprof
 
-        if donate is None:
-            donate = os.environ.get("MADSIM_TPU_STREAM_DONATE", "1") not in ("", "0")
         init_carry, segment, supersegment, reset_rings = self._stream_fns(
             segment_steps, max_steps, 2 * batch, batch,
             donate=donate, segments_per_dispatch=segments_per_dispatch,
@@ -2950,7 +2851,7 @@ class Engine:
         pipelined: bool = True,
         segments_per_dispatch: int = 8,
         dispatch_depth: int = 4,
-        donate: Optional[bool] = None,
+        donate: bool = True,
     ):
         """Continuous seed streaming: run at least n_seeds simulations
         keeping every lane busy. Each segment — refill previously-finished
@@ -2961,17 +2862,17 @@ class Engine:
         `counters` array and drains the failing/abandoned rings when
         they near capacity.
 
-        The default PIPELINED executor dispatches `segments_per_dispatch`
-        segments per jitted call (an inner device `lax.while_loop` with
-        the termination and ring-pressure checks on-device) and keeps
+        The PIPELINED executor dispatches `segments_per_dispatch`
+        segments per jitted call (an inner device `lax.scan` with the
+        termination and ring-pressure checks on-device) and keeps
         `dispatch_depth` such calls in flight before one blocking
         counters read — the steady state runs with ZERO blocking host
         syncs between segments, vs one per segment for the r5 driver
-        (`pipelined=False`, kept for one release; both executors run the
+        (`pipelined=False`, the tests' reference; both executors run the
         bit-identical segment sequence, so results are equal by
         construction). All streaming ops donate the multi-MB StreamCarry
-        (`donate=False` or MADSIM_TPU_STREAM_DONATE=0 opts out), so XLA
-        aliases the lane state in HBM instead of copying it every call.
+        (`donate=False` is the tests' reference), so XLA aliases the
+        lane state in HBM instead of copying it every call.
 
         Seed coverage is gapless: exactly the range
         [seed_start, seed_start + seeds_consumed) enters lanes, in order.
@@ -3014,8 +2915,6 @@ class Engine:
         """
         import numpy as np
 
-        if donate is None:
-            donate = os.environ.get("MADSIM_TPU_STREAM_DONATE", "1") not in ("", "0")
         if segments_per_dispatch < 1 or dispatch_depth < 1:
             raise ValueError("segments_per_dispatch and dispatch_depth must be >= 1")
 
@@ -3024,10 +2923,11 @@ class Engine:
         # rings can never overflow no matter how many dispatches are in
         # flight.
         ring_capacity = 2 * batch
-        # AOT deserialization of the streaming fns ($MADSIM_TPU_AOT_
-        # CACHE, compile_cache.aot_enabled): gated to the unsharded
-        # path — an exported module is traced without shardings, and
-        # replaying it under a mesh would drop the layout contract.
+        # AOT deserialization of the streaming fns
+        # ($MADSIM_TPU_AOT_CACHE, compile_cache.aot_enabled): gated to
+        # the unsharded path — an exported module is traced without
+        # shardings, and replaying it under a mesh would drop the
+        # layout contract.
         from ..compile_cache import aot_enabled
 
         if mesh is not None and mesh.size > 1 and (
@@ -3336,33 +3236,6 @@ class Engine:
             return fn(shard_seeds(seeds, mesh))
 
         return sharded
-
-    def make_stream_runner(
-        self,
-        batch: int = 1024,
-        segment_steps: int = 256,
-        max_steps: int = 10_000,
-        mesh=None,
-        **stream_kwargs,
-    ):
-        """A configured `(n_seeds, seed_start=0) -> run_stream dict`:
-        one place to bind the pipelined-executor knobs (pipelined /
-        segments_per_dispatch / dispatch_depth / donate) so the CLI, the
-        bench harness, and the sharded + multihost paths all inherit the
-        same executor. Pre-warms nothing: the first call compiles."""
-
-        def run(n_seeds: int, seed_start: int = 0):
-            return self.run_stream(
-                n_seeds,
-                batch=batch,
-                segment_steps=segment_steps,
-                seed_start=seed_start,
-                max_steps=max_steps,
-                mesh=mesh,
-                **stream_kwargs,
-            )
-
-        return run
 
     def run_seed_batch(self, seeds, max_steps: int = 10_000) -> dict:
         """Run an EXPLICIT seed vector — one lane per seed, every lane

@@ -240,7 +240,6 @@ class FleetAPI:
         self.store = store
         self._prom_cache = _FileCache()
         self._events_cache = _FileCache()
-        self._bench_cache = _FileCache()
         # -- admission control (all knobs default OFF: unset/0 keeps
         # the pre-admission behavior byte-for-byte) -----------------------
         env = os.environ.get
@@ -730,7 +729,6 @@ class FleetAPI:
                         f'{admission[tenant][outcome]}'
                     )
         self._slo_histograms(lines, jobs)
-        self._bench_trajectory(lines)
         seen_types = {"madsim_tpu_fleet_jobs",
                       "madsim_tpu_fleet_requeues_total",
                       "madsim_tpu_fleet_lease_reclaims_total",
@@ -768,66 +766,6 @@ class FleetAPI:
         ("madsim_tpu_fleet_lane_seconds_per_find", "lane_seconds_per_find"),
         ("madsim_tpu_fleet_batches_per_find", "batches_per_find"),
     )
-
-    def _bench_trajectory(self, lines: List[str]) -> None:
-        """The BENCH_HISTORY.jsonl trajectory as gauges: for each
-        comparable-fingerprint group (platform + lanes + gate tuple +
-        host — `perf/history.comparable`), the NEWEST row's throughput
-        and warm compile, labeled by its tag. The scrape answers "what
-        is this box's current bench baseline, and which capture set
-        it" without shelling out to `bench report`; rows from other
-        boxes/configs export as their own series instead of being
-        averaged into noise. File resolution matches bench.py
-        ($MADSIM_TPU_BENCH_HISTORY, else the repo's checked-in file);
-        parsed via the stat-keyed cache — unchanged history, zero
-        re-reads. Absent file → no series (a farm box without the repo
-        checkout scrapes clean)."""
-        from ..perf import history
-
-        path = os.environ.get("MADSIM_TPU_BENCH_HISTORY") or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))),
-            history.DEFAULT_BASENAME,
-        )
-        rows = self._bench_cache.get(path, history.load)
-        if not rows:
-            return
-        # newest row per comparability group, file order == time order
-        heads: List[dict] = []
-        for row in rows:
-            for i, head in enumerate(heads):
-                if history.comparable(row.get("fingerprint"),
-                                      head.get("fingerprint")):
-                    heads[i] = row
-                    break
-            else:
-                heads.append(row)
-        series = (
-            ("madsim_tpu_bench_seeds_per_sec", "value",
-             "newest capture per comparable fingerprint"),
-            ("madsim_tpu_bench_compile_s_warm", "compile_s_warm",
-             "persistent-cache warm start, same grouping"),
-        )
-        for name, key, help_text in series:
-            rendered = False
-            for row in heads:
-                val = row.get(key)
-                if val is None:
-                    continue  # e.g. no cache configured: no warm path
-                fp = row.get("fingerprint") or {}
-                labels = ",".join(
-                    f'{k}="{v}"' for k, v in (
-                        ("tag", row.get("tag", "?")),
-                        ("platform", fp.get("platform", "?")),
-                        ("lanes", fp.get("lanes", "?")),
-                        ("host", fp.get("host") or "?"),
-                    )
-                )
-                if not rendered:
-                    lines.append(f"# HELP {name} {help_text}")
-                    lines.append(f"# TYPE {name} gauge")
-                    rendered = True
-                lines.append(f"{name}{{{labels}}} {val:g}")
 
     def _slo_histograms(self, lines: List[str], jobs) -> None:
         """SLO metrics derived from the event log at scrape time —

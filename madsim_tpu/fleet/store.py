@@ -220,10 +220,6 @@ def spec_to_args(spec: dict, **overrides) -> SimpleNamespace:
     ns = SimpleNamespace(
         **spec,
         stream=True,
-        no_pipeline=False,
-        segments_per_dispatch=8,
-        dispatch_depth=4,
-        no_donate=False,
         compile_cache=None,
         checkpoint=None,
         stats=None,

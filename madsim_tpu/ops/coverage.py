@@ -169,8 +169,8 @@ def cov_fold(cov_map: jax.Array, slot, hit) -> jax.Array:
 
 # Default per-lane slot-buffer depth for the flush-on-freeze buffered
 # fold (EngineConfig.cov_buffer; 0 = the unbuffered per-event scatter
-# above). BENCH_r11 measured the per-event map RMW at -7.37% of step
-# throughput: the scatter's operand is the whole [lanes, words] map, so
+# above). Round 11 measured the per-event map RMW at -7.37% of step
+# throughput on a CPU box: the scatter's operand is the whole [lanes, words] map, so
 # XLA touches 2 KiB/lane every step to set one bit. Buffering the slot
 # indices in a tiny int32[C] per-lane ring and folding only at the
 # flush cadence / segment exit removes the map from the per-event

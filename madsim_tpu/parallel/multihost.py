@@ -110,9 +110,8 @@ def run_stream_global(
 ) -> dict:
     """Seed streaming sharded over the global (all-hosts) mesh: every
     process runs the identical SPMD pipelined executor — device-side
-    supersegments, donated carry, K-deep dispatch (run_stream kwargs
-    `pipelined` / `segments_per_dispatch` / `dispatch_depth` / `donate`
-    pass through) — and the host loops stay in lockstep because every
+    supersegments, donated carry, K-deep dispatch (run_stream's other
+    keyword arguments pass through) — and the host loops stay in lockstep because every
     decision they make reads replicated counters. Only the counters
     poll and the ring drains cross DCN, each a few hundred bytes, so
     the steady state is collective-free exactly like the single-host

@@ -30,9 +30,9 @@ backend compilation are three different problems (ROADMAP [perf]:
   compile into trace_s / lower_s / backend_s via the AOT stages API
   and attaches `.cost_analysis()` flops/bytes and
   `.memory_analysis()` peak bytes, keyed per `cache_subkey` by the
-  callers (bench.py, `prof compile`, `/metrics`). (The same three
-  stages of the compiles a run really makes, by program, are
-  `perf/compile_log.py`'s: no second compile needed.)
+  caller (`prof compile`). (The same three stages of the compiles a
+  run really makes, by program, are `perf/compile_log.py`'s: no second
+  compile needed.)
 
 * **The merged plane** — `merge_plane(host_doc, device_events,
   virtual_doc)` aligns the host timeline, the device profile and a

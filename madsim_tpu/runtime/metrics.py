@@ -4,8 +4,8 @@ Also the host-side decoder for the TPU engine's flight-recorder metrics
 vector (`StreamCarry.fr_metrics` / `LaneState.fr`): the device
 accumulates per-fault-kind injection counters and occupancy high-water
 marks in the step kernel; `fr_metrics_dict` turns the harvested int
-vector into the labelled dict that run_stream stats, bench.py and the
-hunt report print.
+vector into the labelled dict that run_stream stats and the hunt
+report print.
 """
 
 from __future__ import annotations

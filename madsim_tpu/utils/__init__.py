@@ -38,7 +38,7 @@ def tree_stack_fields(tree, n):
 def device_info() -> dict:
     """The device work is placed on, as jax reports it: platform,
     device_kind and how many such devices are visible. Every stream
-    summary, bench line and stats record carries it, so a number can
+    summary, benchmark line and stats record carries it, so a number can
     never be read without the device it came from. Honors an active
     `jax.default_device(...)` (the CPU replay/oracle paths)."""
     dev = jax.config.jax_default_device or jax.devices()[0]

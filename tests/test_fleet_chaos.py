@@ -465,56 +465,6 @@ def test_metrics_gains_self_healing_series(tmp_path):
     assert 'madsim_tpu_fleet_jobs{state="quarantined"} 1' in text
 
 
-def test_metrics_exports_bench_history_trajectory(tmp_path, monkeypatch):
-    """/metrics exports the BENCH_HISTORY trajectory as gauges (PR 19
-    satellite): the NEWEST row per comparable-fingerprint group —
-    superseded captures drop out, different shapes stay distinct
-    series, and compile_s_warm only appears where a warm path was
-    measured. Resolution honors $MADSIM_TPU_BENCH_HISTORY; a missing
-    file exports no bench series at all."""
-    from madsim_tpu.perf import history
-
-    hp = str(tmp_path / "h.jsonl")
-    fp = {
-        "host": "boxA", "platform": "cpu", "python": "3", "jax": "0.4",
-        "jaxlib": "0.4", "lanes": 8192, "reps": 5, "segment_steps": 384,
-        "gates": {"rng_stream": 3, "clog_packed": True, "pallas_pop": False,
-                  "flight_recorder": True, "coverage": True,
-                  "provenance": False},
-    }
-    history.append(hp, history.make_record("r01", 100.0, fp, ts=1.0))
-    history.append(hp, history.make_record(
-        "r02", 110.0, fp, compile_s_warm=3.2, ts=2.0))
-    history.append(hp, history.make_record(
-        "r03", 55.0, dict(fp, lanes=512), ts=3.0))
-    monkeypatch.setenv("MADSIM_TPU_BENCH_HISTORY", hp)
-    api = FleetAPI(JobStore(str(tmp_path / "farm")))
-    _, _, body = api.handle("GET", "/metrics")
-    text = body.decode()
-    # r01 was superseded by the comparable r02; r03 is its own shape
-    assert 'madsim_tpu_bench_seeds_per_sec{tag="r02"' in text
-    assert 'lanes="8192",host="boxA"} 110' in text
-    assert 'madsim_tpu_bench_seeds_per_sec{tag="r03"' in text
-    assert 'tag="r01"' not in text
-    # warm compile: only the row that measured one exports the gauge
-    warm = [ln for ln in text.splitlines()
-            if ln.startswith("madsim_tpu_bench_compile_s_warm{")]
-    assert warm == [
-        'madsim_tpu_bench_compile_s_warm{tag="r02",platform="cpu",'
-        'lanes="8192",host="boxA"} 3.2'
-    ]
-    # scrape of an unchanged history re-parses nothing
-    parses = api._bench_cache.parses
-    api.handle("GET", "/metrics")
-    assert api._bench_cache.parses == parses
-    # missing file: no bench series, scrape still clean
-    monkeypatch.setenv("MADSIM_TPU_BENCH_HISTORY", str(tmp_path / "nope"))
-    api2 = FleetAPI(JobStore(str(tmp_path / "farm2")))
-    status, _, body = api2.handle("GET", "/metrics")
-    assert status == 200
-    assert "madsim_tpu_bench" not in body.decode()
-
-
 # -- client transient retry (satellite) --------------------------------------
 
 

@@ -203,7 +203,7 @@ def test_perf_package_self_lints_clean():
     # the suppressions are file-level and deliberate — each module
     # justifies its wall-clock contract next to the allowance (the
     # justification comment is part of the hygiene bar, not optional)
-    for fname in ("recorder.py", "ab.py", "history.py", "xprof.py"):
+    for fname in ("recorder.py", "xprof.py"):
         with open(os.path.join(perf_dir, fname)) as f:
             src = f.read()
         assert "madsim: allow-file(D001)" in src, fname

@@ -1,8 +1,7 @@
 """The perf observatory (madsim_tpu/perf): host-timeline recorder span
-semantics + Perfetto schema pin, interleaved-A/B paired statistics
-against hand-computed fixtures, bench-history fingerprint/neighbor/
-report round-trips, and the run_stream --perf-timeline end-to-end
-accounting (spans must explain the wall).
+semantics + Perfetto schema pin, the run_stream --perf-timeline
+end-to-end accounting (spans must explain the wall), and the compile
+cache's placement and warm start.
 
 Everything except the e2e half is jax-free host math — deterministic
 fake clocks, no device work.
@@ -11,18 +10,9 @@ fake clocks, no device work.
 import json
 import math
 import os
-import subprocess
-import sys
 
 import pytest
 
-from madsim_tpu.perf import history
-from madsim_tpu.perf.ab import (
-    bootstrap_ci,
-    interleaved_ab,
-    paired_stats,
-    sign_test_p,
-)
 from madsim_tpu.perf.recorder import (
     PerfRecorder,
     current_recorder,
@@ -254,237 +244,6 @@ def test_chrome_trace_schema_pin(tmp_path):
     assert doc["madsim_perf_summary"]["spans"]["dispatch"]["count"] == 1
 
 
-# -- paired A/B statistics ---------------------------------------------------
-
-
-def test_sign_test_hand_computed():
-    # n=5 nonzero, k=4 positive: p = 2 * (C(5,0)+C(5,1)) / 2^5 = 0.375
-    assert sign_test_p([1, 2, 3, -1, 5]) == pytest.approx(0.375)
-    # all-positive (known-biased) sequence: p = 2 / 2^8
-    assert sign_test_p([0.5] * 8) == pytest.approx(2 / 256)
-    # zeros are discarded before the test
-    assert sign_test_p([0, 0, 1, -1]) == pytest.approx(1.0, abs=1e-9)
-    assert sign_test_p([]) == 1.0
-    # perfectly balanced: p capped at 1
-    assert sign_test_p([1, -1]) == 1.0
-
-
-def test_paired_stats_fixture():
-    st = paired_stats([1, 2, 3, -1, 5])
-    assert st["median"] == 2.0
-    assert st["n"] == 5
-    assert st["sign_p"] == pytest.approx(0.375)
-    lo, hi = st["ci95"]
-    assert lo <= st["median"] <= hi
-    assert lo >= -1 and hi <= 5  # bootstrap of medians stays in range
-    # deterministic: the CI is part of recorded bench artifacts
-    assert paired_stats([1, 2, 3, -1, 5])["ci95"] == st["ci95"]
-
-
-def test_bootstrap_ci_degenerate_and_seeded():
-    assert bootstrap_ci([4.2]) == (4.2, 4.2)
-    a = bootstrap_ci([1.0, 2.0], seed=0)
-    b = bootstrap_ci([1.0, 2.0], seed=0)
-    assert a == b
-    assert a[0] >= 1.0 and a[1] <= 2.0
-    with pytest.raises(ValueError):
-        bootstrap_ci([])
-
-
-def test_interleaved_ab_alternation_and_pairing():
-    """The harness must run ABAB… (never AABB — that would reintroduce
-    the drift the pairing exists to cancel), hand both halves of a pair
-    the SAME seed range, and compute per-pair deltas."""
-    calls = []
-    clk = FakeClock()
-
-    def rep(label, rate):
-        def f(seed_start):
-            calls.append((label, seed_start))
-            clk.tick(100.0 / rate)  # 100 units at `rate`/s
-            return 100
-
-        return f
-
-    res = interleaved_ab(
-        rep("A", 100.0), rep("B", 80.0), pairs=3, seed_start=1000,
-        seeds_per_rep=50, label_a="on", label_b="off", clock=clk,
-    )
-    assert res.order == ["on", "off"] * 3
-    assert [c[0] for c in calls] == ["A", "B"] * 3
-    # pair i: both reps got the same range, advanced by seeds_per_rep
-    assert [c[1] for c in calls] == [1000, 1000, 1050, 1050, 1100, 1100]
-    assert res.rates_a == pytest.approx([100.0] * 3)
-    assert res.rates_b == pytest.approx([80.0] * 3)
-    # delta = (a-b)/a = 20%
-    assert res.median_delta_pct == pytest.approx(20.0)
-    assert res.ci95_pct[0] == pytest.approx(20.0)
-    d = res.to_dict()
-    assert d["pairs"] == 3 and d["median_a"] == 100.0
-    assert "median paired delta +20.00%" in res.summary()
-
-
-def test_interleaved_ab_detects_known_bias_under_drift():
-    """The whole point: a monotone drift that swamps absolute medians
-    must not swamp paired deltas. B is 2% slower; the box drifts 20%
-    across the run."""
-    clk = FakeClock()
-    state = {"i": 0}
-
-    def rep(slowdown):
-        def f(seed_start):
-            # drift: each successive rep runs on a slower box
-            drift = 1.0 - 0.02 * state["i"]
-            state["i"] += 1
-            clk.tick(1.0 / (drift * slowdown))
-            return 100
-
-        return f
-
-    res = interleaved_ab(rep(1.0), rep(0.98), pairs=5, clock=clk)
-    # drift across the WHOLE run is 20%, but each paired delta sees
-    # only ~2% bias + ~2% one-rep drift; the median stays near truth
-    assert 1.0 < res.median_delta_pct < 5.0
-    assert res.sign_p == pytest.approx(2 / 32)  # 5/5 positive
-
-
-# -- bench history -----------------------------------------------------------
-
-
-def _fp(**kw):
-    base = dict(
-        host="boxA", platform="cpu", python="3.12", jax="0.4", jaxlib="0.4",
-        lanes=8192, reps=5, segment_steps=384,
-        gates={"rng_stream": 3, "clog_packed": True, "pallas_pop": False,
-               "flight_recorder": True, "coverage": True, "provenance": False},
-    )
-    base.update(kw)
-    return base
-
-
-def test_history_append_load_roundtrip(tmp_path):
-    path = str(tmp_path / "h.jsonl")
-    r1 = history.make_record("r01", 100.0, _fp(), reps=[99.0, 101.0], ts=123.0)
-    r2 = history.make_record("r02", 105.0, _fp(), ts=124.0)
-    history.append(path, r1)
-    history.append(path, r2)
-    rows = history.load(path)
-    assert [r["tag"] for r in rows] == ["r01", "r02"]
-    assert rows[0]["reps"] == [99.0, 101.0]
-    assert rows[0]["fingerprint"]["gates"]["coverage"] is True
-    assert history.next_tag(rows) == "r03"
-
-
-def test_history_neighbor_selection():
-    rows = [
-        history.make_record("r01", 100.0, _fp(), ts=1.0),
-        history.make_record("r02", 200.0, _fp(lanes=512), ts=2.0),  # other shape
-        history.make_record("r03", 110.0, _fp(), ts=3.0),
-        history.make_record(
-            "r04", 150.0,
-            _fp(gates={"rng_stream": 3, "clog_packed": True,
-                       "pallas_pop": False, "flight_recorder": False,
-                       "coverage": False, "provenance": False}),
-            ts=4.0,
-        ),  # different gate tuple
-        history.make_record("r05", 120.0, _fp(host="boxB"), ts=5.0),  # other box
-    ]
-    nb = history.select_neighbor(rows, _fp())
-    assert nb["tag"] == "r03"  # newest same-shape same-box row
-    # hostless legacy rows stay comparable by config
-    nb2 = history.select_neighbor(rows, _fp(host=None))
-    assert nb2["tag"] == "r05"
-    b = history.neighbor_budget(rows, 104.0, _fp())
-    assert b["neighbor"] == "r03"
-    assert b["vs_neighbor"] == pytest.approx(104.0 / 110.0, abs=1e-3)
-    assert b["within_5pct"] is False
-    # unseen config: no honest baseline
-    assert history.neighbor_budget(rows, 104.0, _fp(platform="tpu")) is None
-
-
-def test_history_legacy_import_real_series(tmp_path):
-    """Legacy BENCH_r*.json captures import with their recorded values
-    in both shapes in the wild: a wrapped driver capture
-    ({"parsed": {...}}) and the flat bench.py JSON."""
-    flat_gates = {"rng_stream": 3, "clog_packed": True, "pallas_pop": False,
-                  "flight_recorder": True, "coverage": True}
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "cmd": "python bench.py", "rc": 0, "tail": "...",
-        "parsed": {"metric": "madraft5_seeds_per_sec_per_chip",
-                   "value": 207.1, "unit": "seeds/sec", "platform": "cpu"},
-    }))
-    for tag, value in (("r08", 447.6), ("r09", 452.5)):
-        (tmp_path / f"BENCH_{tag}.json").write_text(json.dumps({
-            "metric": "madraft5_seeds_per_sec_per_chip", "value": value,
-            "platform": "cpu", "compile_s": 24.07, "gates": flat_gates,
-            "diagnostics": {"reps": [461.7, 458.5, value, 434.0, 434.4],
-                            "lanes": 8192, "segment_steps": 384,
-                            "spread_pct": 6.0},
-        }))
-    (tmp_path / "BENCH_r10.json").write_text("{not json")  # skipped, not fatal
-    rows = history.import_legacy(str(tmp_path))
-    assert [r["tag"] for r in rows] == ["r01", "r08", "r09"]
-    by_tag = {r["tag"]: r for r in rows}
-    assert by_tag["r01"]["value"] == 207.1
-    assert by_tag["r01"]["fingerprint"]["lanes"] is None  # never recorded
-    assert by_tag["r09"]["value"] == 452.5
-    assert by_tag["r09"]["fingerprint"]["lanes"] == 8192
-    assert by_tag["r09"]["fingerprint"]["reps"] == 5
-    assert by_tag["r09"]["fingerprint"]["gates"]["coverage"] is True
-    assert by_tag["r09"]["ts"] is None  # legacy: capture time unknown
-    # r09's neighbor under its own config is r08 (same gates/lanes/platform)
-    nb = history.select_neighbor(rows[:2], by_tag["r09"]["fingerprint"])
-    assert nb["tag"] == "r08"
-
-
-def test_history_report_renders_checked_in_series():
-    """`bench report` must render the seeded BENCH_HISTORY.jsonl — the
-    acceptance artifact (r01..r10 trend) — without error."""
-    path = os.path.join(REPO, history.DEFAULT_BASENAME)
-    assert os.path.exists(path), "BENCH_HISTORY.jsonl must ship seeded"
-    rows = history.load(path)
-    assert len(rows) >= 10
-    text = history.render_report(rows)
-    for tag in ("r01", "r06", "r09", "r10"):
-        assert tag in text, text
-    assert "COMPARABLE" in text
-
-
-def test_bench_report_cli_is_jax_free(tmp_path):
-    """`python -m madsim_tpu bench report` renders without importing
-    jax at all (it must work on a box with no accelerator stack):
-    run in a child whose `jax` import is poisoned."""
-    path = tmp_path / "h.jsonl"
-    history.append(
-        str(path), history.make_record("r01", 42.0, _fp(), ts=1.0)
-    )
-    code = (
-        "import sys; sys.modules['jax'] = None; "  # any `import jax` raises
-        "from madsim_tpu.__main__ import main; "
-        f"sys.exit(main(['bench', 'report', '--history', {str(path)!r}]))"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code], cwd=REPO,
-        capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 0, proc.stderr
-    assert "r01" in proc.stdout
-
-
-def test_history_fingerprint_gate_normalization():
-    fp = history.env_fingerprint(
-        backend_platform="cpu", lanes=64, reps=1, segment_steps=384,
-        gates={"rng_stream": 3, "clog_packed": True, "pallas_pop": False,
-               "flight_recorder": True, "coverage": True,
-               "compile_cache": "/tmp/x"},  # dropped: not comparability
-    )
-    assert fp["gates"] == {
-        "rng_stream": 3, "clog_packed": True, "pallas_pop": False,
-        "flight_recorder": True, "coverage": True, "provenance": False,
-    }
-    assert fp["python"]  # live fingerprints carry versions
-
-
 # -- end to end: --perf-timeline over a real streaming run -------------------
 
 
@@ -672,47 +431,7 @@ def test_perf_timeline_written_on_failure(tmp_path):
     assert any(e.get("name") == "doomed" for e in doc["traceEvents"])
 
 
-# -- r11: warm-start compiles + the widened A/B default ----------------------
-
-
-def test_bench_ab_pairs_default_pinned():
-    """The bench-side interleaved pair count is a measurement-protocol
-    constant: 5 pairs (10 alternating reps) is the floor at which the
-    bootstrap CI of a sub-percent gate stops being the degenerate
-    [min, max] of two deltas (r10's coverage line straddled zero at 2
-    pairs). Changing it changes what every step_cost CI means — it must
-    look like a protocol change, not an env drift."""
-    from madsim_tpu.perf.ab import DEFAULT_BENCH_AB_PAIRS
-
-    assert DEFAULT_BENCH_AB_PAIRS == 5
-    # bench.py must bind the constant, not carry its own copy
-    src = open(os.path.join(REPO, "bench.py")).read()
-    assert "DEFAULT_BENCH_AB_PAIRS" in src
-    assert "MADSIM_TPU_BENCH_AB_PAIRS" in src  # env override retained
-
-
-def test_history_record_carries_warm_compile_and_cache_state(tmp_path):
-    """make_record / env_fingerprint round-trip the r11 fields: the
-    warm compile number and the cache state — and the cache state must
-    NOT break neighbor comparability (it never changes steady rate)."""
-    fp_cold = history.env_fingerprint(
-        backend_platform="cpu", lanes=64, reps=1, segment_steps=384,
-        gates={"rng_stream": 3}, compile_cache=False,
-    )
-    fp_warm = history.env_fingerprint(
-        backend_platform="cpu", lanes=64, reps=1, segment_steps=384,
-        gates={"rng_stream": 3}, compile_cache=True,
-    )
-    assert fp_cold["compile_cache"] is False and fp_warm["compile_cache"] is True
-    assert history.comparable(fp_cold, fp_warm)
-    rec = history.make_record(
-        "r99", 123.4, fp_warm, compile_s=22.5, compile_s_warm=3.1,
-    )
-    p = str(tmp_path / "h.jsonl")
-    history.append(p, rec)
-    [row] = history.load(p)
-    assert row["compile_s"] == 22.5 and row["compile_s_warm"] == 3.1
-    assert row["fingerprint"]["compile_cache"] is True
+# -- the compile cache: placement and warm start ------------------------------
 
 
 def test_compile_cache_subkey_shape():
@@ -823,72 +542,6 @@ def test_compile_cache_default_is_the_checkout_dir(tmp_path, monkeypatch):
     assert updates["jax_compilation_cache_dir"] == str(tmp_path / "flag")
 
 
-def test_bench_reports_cold_and_warm_compile_keys():
-    """bench.py's JSON contract for the warm-start split: both keys
-    emitted, legacy "compile_s" preserved as the cold number (source
-    pin — running the flagship bench in tier-1 is out of budget; the CI
-    bench step asserts the live values)."""
-    src = open(os.path.join(REPO, "bench.py")).read()
-    for key in ('"compile_s_cold"', '"compile_s_warm"', '"compile_s"'):
-        assert key in src, key
-    assert "measure_warm_compile" in src
-    assert "enable_compile_cache(" in src and "strict=True" in src
-
-
-def test_bench_reports_trace_s_and_cold_trace_mode():
-    """bench.py's r12 contract additions: trace_s emitted as its own
-    key (the pure abstract-trace share a warm worker pays even when
-    every XLA executable deserializes) — since r13 measured by the
-    compile autopsy's per-stage split rather than the old re-lower —
-    and the MADSIM_TPU_BENCH_COLD_TRACE env wires through to
-    measure_warm_compile's AOT-suspended mode (source pin — the
-    flagship bench is out of tier-1 budget; CI's bench step asserts
-    the live values)."""
-    import inspect
-
-    from madsim_tpu import compile_cache as cc
-
-    src = open(os.path.join(REPO, "bench.py")).read()
-    assert '"trace_s"' in src
-    assert "MADSIM_TPU_BENCH_COLD_TRACE" in src
-    assert "cold_trace=cold_trace" in src
-    assert "cold_trace" in inspect.signature(cc.measure_warm_compile).parameters
-    # the coverage-unbuffered escape hatch stays A/B-able from the bench
-    assert "coverage_unbuffered" in src and "cov_buffer=0" in src
-
-
-def test_bench_reports_compile_autopsy_split(tmp_path):
-    """bench.py's r13 contract: the compile is split by AOT stage
-    (trace_s / lower_s / backend_s summed over the stream quartet) via
-    the engine's stream_compile_autopsy, with XLA cost_analysis
-    flops/bytes normalized per seed-step, and the same four fields ride
-    the BENCH_HISTORY record — with GATE_KEYS untouched so r13 rows
-    stay comparable to r12 (source pin for the bench itself; the live
-    values are asserted by the CI bench step and BENCH_r13.json)."""
-    src = open(os.path.join(REPO, "bench.py")).read()
-    for key in ('"lower_s"', '"backend_s"', '"flops_per_seed_step"',
-                '"bytes_per_seed_step"', '"compile_autopsy"'):
-        assert key in src, key
-    assert "stream_compile_autopsy" in src
-    # comparability contract: the autopsy must not widen the gate tuple
-    assert history.GATE_KEYS == (
-        "rng_stream", "clog_packed", "pallas_pop", "flight_recorder",
-        "coverage", "provenance",
-    )
-    # and the history record round-trips the split
-    rec = history.make_record(
-        "r98", 100.0, _fp(), compile_s=22.1, trace_s=14.0, lower_s=3.2,
-        backend_s=4.9, flops_per_seed_step=7.5, bytes_per_seed_step=34.0,
-    )
-    p = str(tmp_path / "h.jsonl")
-    history.append(p, rec)
-    [row] = history.load(p)
-    assert row["trace_s"] == 14.0 and row["lower_s"] == 3.2
-    assert row["backend_s"] == 4.9
-    assert row["flops_per_seed_step"] == 7.5
-    assert row["bytes_per_seed_step"] == 34.0
-
-
 def test_aot_warm_start_beats_cold_trace(tmp_path, monkeypatch):
     """The AOT supersegment artifacts pay off: a rebuilt engine whose
     stream fns DESERIALIZE (warm, artifacts allowed) must start faster
@@ -915,7 +568,7 @@ def test_aot_warm_start_beats_cold_trace(tmp_path, monkeypatch):
 
     def build_and_run():
         eng = Engine(EchoMachine(), cfg)
-        eng.make_stream_runner(batch=16, segment_steps=64, max_steps=256)(8)
+        eng.run_stream(8, batch=16, segment_steps=64, max_steps=256)
         built.append(eng)
 
     build_and_run()  # cold: traces, exports, persists the artifacts

@@ -125,14 +125,15 @@ def test_overflow_lands_in_infra_bucket_not_findings():
     assert all(code == OVERFLOW for _seed, code in out["infra"])
 
 
-def test_make_stream_runner_threads_executor_config(raft_engine):
-    """make_stream_runner binds the executor knobs once; repeated calls
-    reuse the jit cache and stay deterministic."""
-    run = raft_engine.make_stream_runner(
-        batch=16, segment_steps=64, segments_per_dispatch=4, dispatch_depth=2
-    )
-    out1 = run(32, seed_start=700)
-    out2 = run(32, seed_start=700)
+def test_repeated_run_stream_reuses_fns_and_is_deterministic(raft_engine):
+    """Repeated run_stream calls at one shape reuse the cached jitted
+    quartet and stay deterministic; the stats name the knobs that ran."""
+    kw = dict(batch=16, segment_steps=64, segments_per_dispatch=4,
+              dispatch_depth=2, seed_start=700)
+    out1 = raft_engine.run_stream(32, **kw)
+    n_fns = len(raft_engine._stream_cache)
+    out2 = raft_engine.run_stream(32, **kw)
+    assert len(raft_engine._stream_cache) == n_fns
     assert out1 == out2
     assert out1["completed"] >= 32
     assert out1["stats"]["pipelined"] and out1["stats"]["segments_per_dispatch"] == 4
