@@ -60,13 +60,27 @@ def campaign_seeds(traffic: dict, seed: int):
     the seed space. Every --seed runs the same pool of `pool` campaigns,
     in an order drawn from the seed, so runs with different seeds do the
     same work; past the pool the campaigns go on to fresh seeds, never
-    back to ones this process has already traced and compiled for."""
+    back to ones this process has already traced and compiled for.
+
+    Where the traffic file names its ranges (`slots`: slot numbers that
+    `pool_check.py` has run whole and found to lose no lane), position k
+    is the k-th of that list: the first `pool` of it are the pool, the
+    rest the fresh ranges in list order. Past the list's end the
+    campaigns go on with the slot numbers after its last — ranges nobody
+    has checked, so a `benchmark:` line says so."""
     pool = int(traffic["pool"])
     order = list(range(pool))
     random.Random(int(seed)).shuffle(order)
+    slots = traffic.get("slots")
     k = 0
     while True:
         slot = order[k] if k < pool else k
+        if slots is not None and slot < len(slots):
+            slot = int(slots[slot])
+        elif slots is not None:  # past the list: the numbers after its last
+            slot = int(slots[-1]) + 1 + slot - len(slots)
+            say(f"benchmark: campaign {k} is past the {len(slots)} checked "
+                f"ranges of the traffic file: slot {slot}, unchecked")
         yield int(traffic["base_seed"]) + slot * int(traffic["stride"])
         k += 1
 
