@@ -88,8 +88,9 @@ class CliRun:
 
     @property
     def setup_s(self) -> float:
-        """Everything before the timed stream loop: engine build,
-        trace, compile (or cache hit) and the warm-up dispatch."""
+        """Everything before the timed stream loop: engine build and
+        the stream's programs made ready (trace, compile or cache hit;
+        nothing dispatched)."""
         return self.wall_s - self.agg["elapsed_s"]
 
 
@@ -472,8 +473,9 @@ def stage_mesh(flagship: CliRun, out_dir: str, seeds: int, batch: int,
         flagship, run, os.path.join(out_dir, "flagship"),
         os.path.join(out_dir, "mesh"), f"1 device vs {devices}-device mesh",
     )
-    # where the carry lives: build it as the stream did (the cached,
-    # already-compiled init_carry of the same mesh) and look
+    # where the carry lives: build it as the stream did (the engine's
+    # cached init_carry of the same mesh; the stream ran the executable
+    # made from it, so this call is a persistent-cache read) and look
     lanes = min(seeds, batch)
     mesh = make_mesh(jax.devices()[:devices])
     init_carry = run.eng._stream_fns(

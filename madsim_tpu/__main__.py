@@ -487,7 +487,7 @@ def _stream_batches(eng, args, purpose="explore"):
     import numpy as np
     import time as wall
 
-    from .perf.recorder import maybe_span
+    from .perf.recorder import maybe_note, maybe_span
 
     if getattr(args, "guided", False):
         # coverage-feedback search (madsim_tpu/search): same aggregate
@@ -616,9 +616,17 @@ def _stream_batches(eng, args, purpose="explore"):
             },
         )
 
-    # compile + warm outside the timed loop (same discipline as before)
-    with maybe_span("warmup_dispatch"):
-        eng.run_stream(1, batch=batch, segment_steps=384, max_steps=args.max_steps, **sk)
+    # compile outside the timed loop, and run nothing: the stream's
+    # programs are made ready (traced, lowered, compiled or read from
+    # the cache) without a dispatch, and an engine that already holds
+    # them — a second campaign on a process, a fleet worker's next
+    # unit — does nothing here. Nothing left to run, nothing to make.
+    if start_bi < planned and agg["completed"] < args.seeds:
+        with maybe_span("warmup_dispatch"):
+            made = eng.prepare_stream(
+                batch=batch, segment_steps=384, max_steps=args.max_steps, **sk
+            )
+            maybe_note(ready=not made, programs=made)
 
     t_start = wall.perf_counter()
     bi = start_bi - 1

@@ -516,7 +516,17 @@ class _BodyWalk:
                 ):
                     expr = n.value
                     break
-        if isinstance(expr, ast.Tuple):
+        kind, target = (
+            resolve_callee(expr, self.fn, self.engine.model)
+            if isinstance(expr, ast.Call) else (None, None)
+        )
+        if kind == "project":
+            # a factory's return passed through whole
+            # (`return self._stream_fns(...)`): the same slots donate
+            s = self.engine.summary(target)
+            self.returns_traced_fn |= s.returns_traced_fn
+            self.returns_donating.update(s.returns_donating)
+        elif isinstance(expr, ast.Tuple):
             for i, e in enumerate(expr.elts):
                 info = jit_info(e)
                 if info is not None:

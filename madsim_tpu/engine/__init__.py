@@ -7,7 +7,9 @@ See `core.py` for the architecture. Public surface:
     `run_batch(seeds)`, `failing_seeds(result)`
   * `Engine.run_stream(n_seeds, ...)` — the pipelined streaming
     executor: donated `StreamCarry`, device-side supersegments
-    (`segments_per_dispatch`), K-deep async dispatch (`dispatch_depth`)
+    (`segments_per_dispatch`), K-deep async dispatch (`dispatch_depth`);
+    `Engine.prepare_stream(...)` makes its programs ready (traced,
+    compiled or read from the cache) without running them
   * `replay(engine, seed)` — bit-identical single-seed CPU replay
   * `FaultPlan` — randomized chaos schedules: pair/dir/group
     partitions, kill/restart, loss storms, delay spikes, pause/resume

@@ -163,6 +163,11 @@ def skew_scale_us(delay_us, q10):
 OK = 0
 OVERFLOW = 1  # event queue full — lane aborts (host fallback)
 
+# The streaming quartet, in the order `Engine._stream_fns` returns it;
+# also the names its programs go by in `perf/compile_log.py` and in the
+# `program` arg of a `compile` span.
+STREAM_PROGRAMS = ("init_carry", "segment", "supersegment", "reset_rings")
+
 # -- flight recorder (observability) ----------------------------------------
 # Rolling per-lane trace digest: a uint32[2] xor-rotate-multiply fold
 # over every popped event tuple plus the step's RNG word block. Not
@@ -2707,16 +2712,8 @@ class Engine:
             + "-"
             + hashlib.sha1(ident.encode()).hexdigest()[:16]
         )
-        names = ("init_carry", "segment", "supersegment", "reset_rings")
-        seeds_aval = jax.ShapeDtypeStruct((batch,), jnp.uint32)
-        carry_aval = jax.eval_shape(jitted[0], seeds_aval)
-        need_aval = jax.ShapeDtypeStruct((), jnp.int32)
-        avals = {
-            "init_carry": (seeds_aval,),
-            "segment": (carry_aval,),
-            "supersegment": (carry_aval, need_aval),
-            "reset_rings": (carry_aval,),
-        }
+        avals = self._stream_avals(jitted[0], batch)
+        carry_aval = avals["reset_rings"][0]
         # jax.export cannot serialize custom pytree nodes (the flax
         # struct dataclasses and model states riding the carry), so
         # each fn is exported over FLAT LEAF LISTS and the pytree
@@ -2748,7 +2745,7 @@ class Engine:
             "aot_key": subkey,
         }
         out = []
-        for name, jfn, rfn in zip(names, jitted, raw):
+        for name, jfn, rfn in zip(STREAM_PROGRAMS, jitted, raw):
             kw = {} if name == "init_carry" else donate_kw
             in_leaves, in_tree = jax.tree.flatten(avals[name])
             exp = None
@@ -2783,6 +2780,127 @@ class Engine:
             out.append(jax.jit(_make_wrapped(exp), **kw))
         return tuple(out)
 
+    @staticmethod
+    def _stream_avals(init_carry, batch: int) -> dict:
+        """{program name: its argument avals} for the quartet at `batch`
+        lanes (`init_carry` is the quartet's own: its output IS the
+        carry the other three take)."""
+        seeds_aval = jax.ShapeDtypeStruct((batch,), jnp.uint32)
+        carry_aval = jax.eval_shape(init_carry, seeds_aval)
+        need_aval = jax.ShapeDtypeStruct((), jnp.int32)
+        return {
+            "init_carry": (seeds_aval,),
+            "segment": (carry_aval,),
+            "supersegment": (carry_aval, need_aval),
+            "reset_rings": (carry_aval,),
+        }
+
+    def _stream_quartet(
+        self,
+        *,
+        batch: int,
+        segment_steps: int,
+        max_steps: int,
+        mesh,
+        segments_per_dispatch: int,
+        donate: bool,
+    ):
+        """`_stream_fns` under the key a `run_stream` with these
+        arguments uses, after refusing what a meshed run cannot take."""
+        from ..compile_cache import aot_enabled
+
+        if mesh is not None:
+            from ..parallel import check_lane_split
+
+            check_lane_split(mesh, batch)
+            if mesh.size > 1 and (self.use_pallas_pop or self.use_megakernel):
+                raise ValueError(
+                    "meshed runs need the Pallas kernels off "
+                    "(MADSIM_TPU_PALLAS_POP=0 / MADSIM_TPU_PALLAS_MEGAKERNEL=0, "
+                    "or Engine(use_pallas_pop=False)): pallas_call blocks "
+                    "GSPMD sharding propagation, so the lane-pinned layout "
+                    "cannot cross it"
+                )
+        # Ring capacity 2 * batch: the device parks at the drain mark
+        # (cap - batch), and one segment can complete at most `batch`
+        # lanes, so the rings can never overflow no matter how many
+        # dispatches are in flight. AOT deserialization of the
+        # streaming fns ($MADSIM_TPU_AOT_CACHE,
+        # compile_cache.aot_enabled) is gated to the unsharded path —
+        # an exported module is traced without shardings, and replaying
+        # it under a mesh would drop the layout contract.
+        return self._stream_fns(
+            segment_steps, max_steps, 2 * batch, batch,
+            donate=donate, segments_per_dispatch=segments_per_dispatch,
+            aot=mesh is None and aot_enabled(),
+            mesh=mesh,
+        )
+
+    def _stream_executables(self, quartet, batch: int, pipelined: bool):
+        """(held, made): `held` maps a jitted stream fn to the COMPILED
+        executable this engine keeps for it, and holds — made here where
+        missing — the three programs a `run_stream` over `quartet`
+        calls: `init_carry`, `supersegment` (`segment` where not
+        `pipelined`), `reset_rings`. `made` counts those this call had
+        to make; at 0 nothing was traced, lowered, compiled or read.
+
+        A program is made by the AOT stages of its jitted fn (plain,
+        meshed or exported-call alike) over `_stream_avals` — trace,
+        lower, compile-or-read-from-the-persistent-cache — and nothing
+        is dispatched to the device. jax's AOT path does not feed
+        `jit`'s call cache, so the executable itself is kept and
+        `run_stream` calls what is kept: one way to a program, whether
+        `prepare_stream` or a first `run_stream` asked for it. Each
+        make is a `compile` span (arg `program`) and its stages go to
+        `perf/compile_log.py` under the program's name."""
+        from ..perf import compile_log
+        from ..perf.recorder import maybe_span
+
+        fns = dict(zip(STREAM_PROGRAMS, quartet))
+        del fns["segment" if pipelined else "supersegment"]
+        # keyed by the jitted fn (cached per stream key on the engine,
+        # so the dict holds no extra lifetime)
+        held = self.__dict__.setdefault("_stream_compiled", {})
+        missing = [name for name, fn in fns.items() if fn not in held]
+        if missing:
+            avals = self._stream_avals(fns["init_carry"], batch)
+            for name in missing:
+                with compile_log.program(name), \
+                        maybe_span("compile", program=name):
+                    held[fns[name]] = fns[name].lower(*avals[name]).compile()
+        return held, len(missing)
+
+    def prepare_stream(
+        self,
+        batch: int = 1024,
+        segment_steps: int = 256,
+        max_steps: int = 10_000,
+        mesh=None,
+        pipelined: bool = True,
+        segments_per_dispatch: int = 8,
+        donate: bool = True,
+    ) -> int:
+        """Make ready the programs a `run_stream` with these arguments
+        calls, WITHOUT running them: they are traced, lowered and
+        compiled (or read from the persistent cache), nothing is
+        dispatched to the device, and the `run_stream` calls that
+        follow execute exactly these executables. Returns how many
+        programs had to be made — 0 on an engine that already holds
+        them, where this does nothing at all. Counted as
+        `stream.programs_ready_hit` / `stream.programs_ready_miss`."""
+        from ..perf.recorder import maybe_count
+
+        quartet = self._stream_quartet(
+            batch=batch, segment_steps=segment_steps, max_steps=max_steps,
+            mesh=mesh, segments_per_dispatch=segments_per_dispatch,
+            donate=donate,
+        )
+        _, made = self._stream_executables(quartet, batch, pipelined)
+        maybe_count(
+            "stream.programs_ready_miss" if made else "stream.programs_ready_hit"
+        )
+        return made
+
     def stream_compile_autopsy(
         self,
         batch: int,
@@ -2802,22 +2920,15 @@ class Engine:
         accept the duplicate trace cost."""
         from ..perf import xprof
 
-        init_carry, segment, supersegment, reset_rings = self._stream_fns(
+        fns = self._stream_fns(
             segment_steps, max_steps, 2 * batch, batch,
             donate=donate, segments_per_dispatch=segments_per_dispatch,
             mesh=mesh,
         )
-        seeds_aval = jax.ShapeDtypeStruct((batch,), jnp.uint32)
-        carry_aval = jax.eval_shape(init_carry, seeds_aval)
-        need_aval = jax.ShapeDtypeStruct((), jnp.int32)
+        avals = self._stream_avals(fns[0], batch)
         return [
-            xprof.compile_autopsy(fn, avals, label=label)
-            for label, fn, avals in (
-                ("init_carry", init_carry, (seeds_aval,)),
-                ("segment", segment, (carry_aval,)),
-                ("supersegment", supersegment, (carry_aval, need_aval)),
-                ("reset_rings", reset_rings, (carry_aval,)),
-            )
+            xprof.compile_autopsy(fn, avals[name], label=name)
+            for name, fn in zip(STREAM_PROGRAMS, fns)
         ]
 
     def run_stream(self, n_seeds: int, **kwargs):
@@ -2918,36 +3029,21 @@ class Engine:
         if segments_per_dispatch < 1 or dispatch_depth < 1:
             raise ValueError("segments_per_dispatch and dispatch_depth must be >= 1")
 
-        # Ring capacity: the device parks at the drain mark (cap - batch),
-        # and one segment can complete at most `batch` lanes, so the
-        # rings can never overflow no matter how many dispatches are in
-        # flight.
-        ring_capacity = 2 * batch
-        # AOT deserialization of the streaming fns
-        # ($MADSIM_TPU_AOT_CACHE, compile_cache.aot_enabled): gated to
-        # the unsharded path — an exported module is traced without
-        # shardings, and replaying it under a mesh would drop the
-        # layout contract.
-        from ..compile_cache import aot_enabled
-
-        if mesh is not None and mesh.size > 1 and (
-            self.use_pallas_pop or self.use_megakernel
-        ):
-            raise ValueError(
-                "meshed runs need the Pallas kernels off "
-                "(MADSIM_TPU_PALLAS_POP=0 / MADSIM_TPU_PALLAS_MEGAKERNEL=0, "
-                "or Engine(use_pallas_pop=False)): pallas_call blocks "
-                "GSPMD sharding propagation, so the lane-pinned layout "
-                "cannot cross it"
-            )
-        init_carry, segment, supersegment, reset_rings = self._stream_fns(
-            segment_steps, max_steps, ring_capacity, batch,
-            donate=donate, segments_per_dispatch=segments_per_dispatch,
-            aot=mesh is None and aot_enabled(),
-            mesh=mesh,
+        init_carry, segment, supersegment, reset_rings = self._stream_quartet(
+            batch=batch, segment_steps=segment_steps, max_steps=max_steps,
+            mesh=mesh, segments_per_dispatch=segments_per_dispatch,
+            donate=donate,
         )
+        # a stream program is named by its jitted fn below and run as
+        # the executable the engine holds for it
+        programs, _ = self._stream_executables(
+            (init_carry, segment, supersegment, reset_rings), batch, pipelined
+        )
+        ring_capacity = 2 * batch
 
-        seeds = jnp.arange(seed_start, seed_start + batch, dtype=jnp.uint32)
+        # host-made: a `jnp.arange` would compile an `iota` on a
+        # process's first batch
+        seeds = np.arange(seed_start, seed_start + batch, dtype=np.uint32)
         if mesh is not None:
             from ..parallel import shard_seeds
 
@@ -2980,25 +3076,14 @@ class Engine:
         # dispatch/poll/drain below lands on the host timeline as a
         # span. Pure host-side wall-clock accounting — no RNG words, no
         # device-visible values, so streams are untouched by
-        # construction. `perf_warmed` tracks which jitted streaming fns
-        # this engine has already invoked: the FIRST call of a jitted
-        # fn traces + compiles synchronously before the async dispatch,
-        # so it is labelled "compile" (near-zero wall on a warm
-        # persistent cache), later calls "dispatch"/"init".
-        from ..perf import compile_log
-        from ..perf.recorder import current_recorder
+        # construction. Every program called below is a held executable
+        # (`_stream_executables`: made under its own `compile` span), so
+        # a dispatch never traces or compiles, the first one included.
+        from ..perf.recorder import current_recorder, maybe_span
 
         perf = current_recorder()
-        perf_warmed = self.__dict__.setdefault("_perf_warmed", set())
 
-        def _span_of(fn, hot_name, program):
-            # membership by object identity — the jitted fns are cached
-            # on the engine, so the set holds no extra lifetime
-            if fn in perf_warmed:
-                return {"span": hot_name}
-            return {"span": "compile", "program": program}
-
-        def _dispatch(what, fn, *fn_args, span=None, **span_args):
+        def _dispatch(what, fn, *fn_args, span=None):
             def on_retry(attempt, exc, delay_s):
                 stats["dispatch_retries"] += 1
                 import logging
@@ -3012,25 +3097,13 @@ class Engine:
             # which an annotating recorder (PerfRecorder(annotate=True))
             # also writes into a jax.profiler capture as
             # "madsim.<name>" on the clock of the device ops.
-            if perf is None and "program" not in span_args:
+            run = programs.get(fn, fn)  # `jax.device_get` is itself
+            with maybe_span(span or what):
                 return retry_transient(
-                    lambda: fn(*fn_args), what=what, on_retry=on_retry
-                )
-            with contextlib.ExitStack() as stack:
-                if "program" in span_args:
-                    # a first call: its compile stages go by this name
-                    stack.enter_context(compile_log.program(span_args["program"]))
-                if perf is not None:
-                    stack.enter_context(perf.span(span or what, **span_args))
-                return retry_transient(
-                    lambda: fn(*fn_args), what=what, on_retry=on_retry
+                    lambda: run(*fn_args), what=what, on_retry=on_retry
                 )
 
-        carry = _dispatch(
-            "carry init", init_carry, seeds,
-            **_span_of(init_carry, "init", "init_carry"),
-        )
-        perf_warmed.add(init_carry)
+        carry = _dispatch("carry init", init_carry, seeds, span="init")
 
         def drain(c: StreamCarry) -> StreamCarry:
             # madsim: allow(T002) — this IS a designed sync point: the
@@ -3058,12 +3131,7 @@ class Engine:
                 if self.config.provenance:
                     prov_by_seed[int(s)] = int(f_provs[i])
             abandoned.extend(int(s) for s in a_seeds[: int(a_n)])
-            reset = _dispatch(
-                "ring reset", reset_rings, c,
-                **_span_of(reset_rings, "dispatch", "reset_rings"),
-            )
-            perf_warmed.add(reset_rings)
-            return reset
+            return _dispatch("ring reset", reset_rings, c, span="dispatch")
 
         def poll(c: StreamCarry):
             """The blocking device->host sync: one small counters read."""
@@ -3096,7 +3164,9 @@ class Engine:
         max_segments = (max_steps // segment_steps + 2) * (n_seeds // batch + 2)
 
         if pipelined:
-            need = jnp.int32(min(n_seeds, 2**31 - 1))
+            # placed, not converted: `jnp.int32(...)` is an eager op, a
+            # compile of its own on a process's first batch
+            need = jax.device_put(np.int32(min(n_seeds, 2**31 - 1)))
             max_dispatch = max_segments + dispatch_depth * (n_seeds // batch + 4)
             in_flight = 0
             while completed < n_seeds and stats["dispatches"] < max_dispatch:
@@ -3105,9 +3175,8 @@ class Engine:
                 _xprof.sync_marker("dispatch")
                 carry = _dispatch(
                     "supersegment dispatch", supersegment, carry, need,
-                    **_span_of(supersegment, "dispatch", "supersegment"),
+                    span="dispatch",
                 )
-                perf_warmed.add(supersegment)
                 stats["dispatches"] += 1
                 in_flight += 1
                 if in_flight >= dispatch_depth:
@@ -3124,10 +3193,8 @@ class Engine:
             while completed < n_seeds and stats["dispatches"] < max_segments:
                 _xprof.sync_marker("dispatch")
                 carry = _dispatch(
-                    "segment dispatch", segment, carry,
-                    **_span_of(segment, "dispatch", "segment"),
+                    "segment dispatch", segment, carry, span="dispatch"
                 )
-                perf_warmed.add(segment)
                 stats["dispatches"] += 1
                 counters = poll(carry)
                 completed = int(counters[0])

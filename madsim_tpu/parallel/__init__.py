@@ -44,27 +44,34 @@ def seed_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(LANE_AXIS))
 
 
-def shard_seeds(seeds, mesh: Mesh):
-    """Place a seed batch sharded over the mesh's "batch" axis; the
-    engine's streaming quartet then pins every StreamCarry leaf with
-    `carry_shardings` (explicit in/out_shardings, not propagation).
-
-    Validates the mesh and batch shape up front so every sharding entry
-    point gets a clear error instead of a raw XLA one. On a multi-host
-    (jax.distributed) mesh, each process materializes only its local
-    shard — device_put can't place onto non-addressable devices."""
+def check_lane_split(mesh: Mesh, n: int) -> None:
+    """Refuse a mesh without the "batch" axis, or a batch of `n` lanes
+    it does not divide, so every sharding entry point (seed placement,
+    making the stream's programs) gets a clear error instead of a raw
+    XLA one."""
     if LANE_AXIS not in mesh.shape:
         raise ValueError(
             f'mesh has no "{LANE_AXIS}" axis (axes: {tuple(mesh.shape)}); '
             f"build it with parallel.make_mesh(...)"
         )
     axis = mesh.shape[LANE_AXIS]
-    n = len(seeds)
     if n % axis != 0:
         raise ValueError(
             f"seed batch ({n}) must be a multiple of the mesh's "
             f'"{LANE_AXIS}" axis size ({axis})'
         )
+
+
+def shard_seeds(seeds, mesh: Mesh):
+    """Place a seed batch sharded over the mesh's "batch" axis; the
+    engine's streaming quartet then pins every StreamCarry leaf with
+    `carry_shardings` (explicit in/out_shardings, not propagation).
+
+    Validates the mesh and batch shape up front (`check_lane_split`).
+    On a multi-host (jax.distributed) mesh, each process materializes
+    only its local shard — device_put can't place onto non-addressable
+    devices."""
+    check_lane_split(mesh, len(seeds))
     sharding = seed_sharding(mesh)
     if any(d.process_index != jax.process_index() for d in mesh.devices.flat):
         # madsim: allow(T001) — deliberate one-time host

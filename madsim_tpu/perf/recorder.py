@@ -26,15 +26,22 @@ as duration minus children without guessing from ``depth``:
 =====================  =====================================================
 ``engine_build``       `_build_engine`: imports, model init, first backend
                        touch
-``warmup_dispatch``    `_stream_batches`' unmeasured `run_stream(1, ...)`
+``warmup_dispatch``    `_stream_batches`, before its timed loop: the
+                       stream's programs made ready
+                       (`Engine.prepare_stream`), nothing dispatched.
+                       Args: ``ready`` (the engine held them already:
+                       no child span), ``programs`` (how many were
+                       made: each a ``compile`` child)
 ``run_stream``         one `Engine.run_stream` call (args: n_seeds, batch)
-``compile``            first invocation of a jitted fn (trace + lower +
-                       compile-or-read + first dispatch; near-zero on a
-                       warm persistent cache); arg ``program``:
+``compile``            a program being made; arg ``program``. Executor:
                        ``init_carry`` / ``supersegment`` / ``segment`` /
-                       ``reset_rings`` (executor), ``replay.run`` /
-                       ``replay.step`` (replay)
-``init``               a later `init_carry` dispatch
+                       ``reset_rings`` — trace + lower +
+                       compile-or-read, no dispatch (the executable is
+                       kept and every dispatch calls it). Replay:
+                       ``replay.run`` / ``replay.step`` — the first
+                       invocation of the jitted fn, its dispatch
+                       included. Near-zero on a warm persistent cache
+``init``               an `init_carry` dispatch
 ``dispatch``           an async supersegment/segment dispatch (returns as
                        soon as the work is enqueued — short by design)
 ``counters_poll``      the blocking device->host counters read (where a
@@ -58,6 +65,9 @@ Counters (`maybe_count`): ``compile.trace`` / ``compile.lower`` /
 ``compile.backend`` / ``compile.cache_miss`` / ``compile.cache_hit`` —
 one per jax compile-stage event while a recorder is active
 (`perf/compile_log.py`, which also keeps them by program).
+``stream.programs_ready_hit`` / ``stream.programs_ready_miss`` — one per
+`Engine.prepare_stream` call: the engine held the stream's programs
+(nothing traced, compiled or read) or had to make some.
 
 `PerfRecorder(annotate=True)` also writes every span into a running
 `jax.profiler` capture as a ``madsim.<name>`` TraceAnnotation, on the
@@ -318,8 +328,8 @@ class PerfRecorder:
             d["count"] += 1
         top_union = self._union_us(top)
         # device_wait is scoped to the streaming spans: interior of a
-        # `run_stream` span (at any depth: the warm-up's sits under
-        # `warmup_dispatch`) that none of its child spans covers is the
+        # `run_stream` span (at any depth) that none of its child spans
+        # covers is the
         # device executing (or starving the host thread on a shared-core
         # box); uncovered interior of anything else is just that span's
         # own host work

@@ -68,3 +68,15 @@ class MiniEngine:
         advanced = _retry(segment, carry)  # donates `carry`...
         stale = carry + jnp.uint32(1)  # T003 expected
         return advanced, stale
+
+    def _stream_quartet(self, donate):
+        """A factory's return passed through whole: the same slots
+        donate (the real executor's `_stream_quartet`)."""
+        return self._stream_fns(donate)
+
+    def run_passthrough_use_after_donate(self, n):
+        init_carry, segment = self._stream_quartet(True)
+        carry = init_carry(jnp.arange(n, dtype=jnp.uint32))
+        advanced = _retry(segment, carry)  # donates `carry`...
+        stale = carry + jnp.uint32(1)  # T003 expected
+        return advanced, stale

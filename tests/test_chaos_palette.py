@@ -716,11 +716,12 @@ def test_run_stream_retries_transient_dispatch(monkeypatch, echo_engine):
     fake raises BEFORE touching the donated carry — the retry-able
     shape; a post-consumption failure propagates (not retried), which
     the donation caveat in _dispatch_retry documents."""
-    orig = Engine._stream_fns
+    orig = Engine._stream_executables
     state = {"tripped": False}
 
-    def wrapped(self, *a, **kw):
-        init_c, segment, supersegment, reset = orig(self, *a, **kw)
+    def wrapped(self, quartet, batch, pipelined):
+        held, made = orig(self, quartet, batch, pipelined)
+        supersegment = held[quartet[2]]  # the executable run_stream calls
 
         def flaky_super(c, need):
             if not state["tripped"]:
@@ -728,9 +729,9 @@ def test_run_stream_retries_transient_dispatch(monkeypatch, echo_engine):
                 raise RuntimeError("UNAVAILABLE: injected backend blip")
             return supersegment(c, need)
 
-        return init_c, segment, flaky_super, reset
+        return {**held, quartet[2]: flaky_super}, made
 
-    monkeypatch.setattr(Engine, "_stream_fns", wrapped)
+    monkeypatch.setattr(Engine, "_stream_executables", wrapped)
     out = echo_engine.run_stream(32, batch=32, segment_steps=384, max_steps=300)
     assert out["completed"] >= 32
     assert out["stats"]["dispatch_retries"] == 1

@@ -315,6 +315,14 @@ def test_texec_use_after_donate_is_t003(texec_model):
     assert f.severity == "error" and "donated" in f.message
 
 
+def test_texec_passthrough_factory_keeps_donation(texec_model):
+    """A factory that returns another factory's quartet whole
+    (`return self._stream_fns(...)`) hands on which slots donate."""
+    found = texec_findings(
+        texec_model, "MiniEngine.run_passthrough_use_after_donate")
+    assert [f.rule for f in found] == ["T003"]
+
+
 def test_texec_expected_lines_match_tags(texec_model):
     """Every tagged hazard line in the fixture is found by SOME entry
     walk, and nothing untagged fires."""
@@ -324,6 +332,7 @@ def test_texec_expected_lines_match_tags(texec_model):
         "MiniEngine.run_clean", "MiniEngine.run_item_sink",
         "MiniEngine.run_truthy_sink", "MiniEngine.run_hidden_fetch",
         "MiniEngine.run_use_after_donate",
+        "MiniEngine.run_passthrough_use_after_donate",
     ):
         all_found |= {f.line for f in texec_findings(texec_model, entry)}
     expected = set()
@@ -360,6 +369,28 @@ def test_t001_real_executor_item_injection(tmp_path):
     model = projectmodel.build_model(str(root))
     found = [f for f in trules.check_model(model) if f.rule == "T001"]
     assert found and ".item()" in found[0].message
+    assert "Engine._run_stream_impl" in found[0].message
+
+
+def test_t003_real_executor_use_after_donate_injection(tmp_path):
+    """The same shape for donation: `_run_stream_impl` takes its quartet
+    through `_stream_quartet` and runs held executables, and a carry
+    read after the dispatch that donated it must still be caught."""
+    root = tmp_path / "repo"
+    dst = root / "madsim_tpu" / "engine" / "core.py"
+    dst.parent.mkdir(parents=True)
+    src = open(os.path.join(REPO, "madsim_tpu", "engine", "core.py")).read()
+    needle = '                stats["dispatches"] += 1\n                in_flight += 1'
+    assert needle in src, "executor anchor moved; update this test"
+    dst.write_text(src.replace(
+        needle,
+        '                stats["dispatches"] += 1\n'
+        '                _dispatch("again", supersegment, carry, need)\n'
+        '                in_flight += 1',
+    ))
+    model = projectmodel.build_model(str(root))
+    found = [f for f in trules.check_model(model) if f.rule == "T003"]
+    assert found and "`carry` is used after being donated" in found[0].message
     assert "Engine._run_stream_impl" in found[0].message
 
 
