@@ -36,7 +36,8 @@ def build_machine(name: str, nodes: int = 0, log_capacity: int = 0):
     lives beside its model) so the hunt -> shrink -> replay -> corpus
     workflow is demonstrable without writing a protocol first.
 
-    `log_capacity` (0 = the registry's 8) sizes the raft machines' log
+    `log_capacity` (0 = the registry's 8, for kafka its 64) sizes the
+    raft machines' log and the kafka machines' partition logs
     (`--log-capacity`; a corpus entry records it). One object per
     (name, nodes, log_capacity) per process: the compiled-replay cache
     hangs on the machine object (engine/replay.py `_replay_cache`), so
@@ -56,6 +57,7 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
         EtcdMvccMachine, NoDedupMvcc, PrematureGiveupMvcc,
     )
     from .models.gossip import DupAckGossip, GossipMachine
+    from .models.kafka import KafkaMachine, NoDedupKafkaMachine
     from .models.kafka_group import KafkaGroupMachine, NoFencingGroupMachine
     from .models.kv import KvMachine
     from .models.mq import MqMachine
@@ -82,6 +84,13 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
         "etcd-mvcc": lambda: EtcdMvccMachine(num_nodes=nodes or 4),
         "twopc": lambda: TwoPcMachine(num_nodes=nodes or 4),
         "group": lambda: KafkaGroupMachine(num_nodes=nodes or 4),
+        # the partition logs' capacity: 64 where --log-capacity is unset
+        "kafka": lambda: KafkaMachine(
+            num_nodes=nodes or 5, log_capacity=log_capacity or 64
+        ),
+        "demo-nodedup-kafka": lambda: NoDedupKafkaMachine(
+            num_nodes=nodes or 5, log_capacity=log_capacity or 64
+        ),
         "paxos": lambda: PaxosMachine(num_nodes=nodes or 5),
         "multipaxos": lambda: MultiPaxosMachine(num_nodes=nodes or 5),
         "demo-nopromise-paxos": lambda: NoPromiseCheckPaxos(num_nodes=nodes or 5),
@@ -128,7 +137,8 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
         sys.exit(f"unknown machine {name!r}; choose from {sorted(machines)}")
     machine = machines[name]()
     if log_capacity and getattr(machine, "log_capacity", None) != log_capacity:
-        sys.exit(f"--log-capacity sizes a raft machine's log; {name!r} has none")
+        sys.exit(f"--log-capacity sizes the log of a raft or kafka machine; "
+                 f"{name!r} has none")
     return machine
 
 
@@ -390,6 +400,9 @@ def _print_fr_stats(stats) -> None:
         c = fr["churn"]
         extra += (f", churn {c['ticks']} ticks / {c['disconnects']} "
                   f"disconnects / {c['reconnects']} reconnects")
+    if fr.get("machine"):
+        extra += ", machine [" + ", ".join(
+            f"{k}={v}" for k, v in fr["machine"].items()) + "]"
     print(
         f"flight recorder: faults injected [{inj or 'none'}]{extra}, "
         f"queue hwm {fr['queue_hwm']}, clogged-links hwm {fr['clog_links_hwm']}, "
@@ -2081,7 +2094,9 @@ def main(argv=None) -> int:
         p.add_argument(
             "--log-capacity", type=int, default=None, metavar="N",
             help="log entries a node of a raft machine can hold (default "
-            "8: a lane ends when every node has committed a full log)",
+            "8: a lane ends when every node has committed a full log), or "
+            "records a partition log of a kafka machine can hold (default "
+            "64: a full log refuses appends and counts log_full)",
         )
         p.add_argument(
             "--latency", default=None, metavar="MIN_US,MAX_US",

@@ -28,6 +28,13 @@ is most likely:
   session tick), so the contract there is convergent state: same final
   members, same final assignment, no committed-offset regression.
 
+* `differential_kafka(engine, seed)` — the whole pipeline
+  (`models/kafka.py`): the group half as above, and the produce / fetch
+  half — every PRODUCE and FETCH the broker processed is put to the L5
+  `Broker`'s partition logs, and the logs' records in order, the high
+  watermarks and every fetch response the members were delivered are
+  compared.
+
 Abstraction note (documented divergence): the machine models leases as
 one slot per client where a re-grant refreshes the slot in place;
 genuine etcd is id-per-grant. The adapter mirrors the slot model by
@@ -193,10 +200,16 @@ GROUP = "diff-group"
 TOPIC = "diff-topic"
 
 
-def drive_kafka_coordinator(machine, trace):
+def drive_kafka_coordinator(machine, trace, proto=None, on_request=None):
     """Apply the device lane's membership timeline + commit stream to the
     L5 Broker coordinator. Machine µs are passed as broker ms (same
     numeric session semantics, same strict expiry inequality).
+
+    `proto` is the module whose message and timer kinds the trace is
+    written in (`models/kafka_group.py` unless given; `models/kafka.py`
+    for the whole pipeline), and
+    `on_request(broker, ev)` is handed every other message the live
+    coordinator node processed (the pipeline's PRODUCE and FETCH).
 
     Round-5 strengthening (VERDICT r4 directive 8): the broker runs in
     timer-driven expiry mode (`expire_on_traffic=False`) and the adapter
@@ -217,9 +230,11 @@ def drive_kafka_coordinator(machine, trace):
     Returns (broker, member_of, accept_log); accept_log rows are
     (t, src, gen, part, off, accepted|None, before, after)."""
     from .engine.core import F_KILL, F_RESTART
-    from .models import kafka_group as G
     from .services.kafka import Broker
 
+    if proto is None:
+        from .models import kafka_group as proto
+    G = proto
     b = Broker(expire_on_traffic=False)
     b.create_topic(TOPIC, machine.P)
     member_of: Dict[int, str] = {}
@@ -280,14 +295,18 @@ def drive_kafka_coordinator(machine, trace):
                 accepted = False
             after = b.committed(GROUP, TOPIC, c_part)
             accept_log.append((t, src, c_gen, c_part, c_off, accepted, before, after))
+        elif on_request is not None:
+            on_request(b, ev)
     return b, member_of, accept_log
 
 
-def _machine_fencing_mirror(machine, trace):
+def _machine_fencing_mirror(machine, trace, proto=None):
     """Host mirror of the machine coordinator's fencing inputs for
     FAULT-FREE lanes (no expiry, so gen bumps only on joins): yields
     would-accept decisions per commit, in delivery order."""
-    from .models import kafka_group as G
+    if proto is None:
+        from .models import kafka_group as proto
+    G = proto
 
     joined: List[int] = []  # in node-id order (machine ranks by node id)
     gen = 0
@@ -311,6 +330,54 @@ def _machine_fencing_mirror(machine, trace):
     return gen, decisions
 
 
+def _compare_group(machine, b, member_of, mismatches: List[str], *,
+                   joined, gen, assign, committed) -> Tuple[int, int]:
+    """The machine coordinator's final state (its member table,
+    generation, owner per partition, committed offsets) against the
+    Broker's group: exact equality, appended to `mismatches`. Returns
+    (machine generation, broker generation)."""
+    g = b.groups.get(GROUP)
+    m_members = {i for i in range(1, machine.NUM_NODES) if bool(joined[i])}
+    b_members = set()
+    if g:
+        mid_to_src = {mid: src for src, mid in member_of.items()}
+        b_members = {mid_to_src[mid] for mid in g.members if mid in mid_to_src}
+    if m_members != b_members:
+        mismatches.append(
+            f"members: machine {sorted(m_members)} != broker {sorted(b_members)}"
+        )
+
+    m_gen = int(gen)
+    b_gen = g.generation if g else 0
+    if m_gen != b_gen:
+        mismatches.append(f"generation: machine {m_gen} != broker {b_gen}")
+
+    # assignment: both sides range/round-robin by rank over the joined
+    # set — with (non-empty) membership equal, the owner maps must agree
+    # exactly. Empty membership skips: after a coordinator restart with
+    # no rejoin yet, the machine's durable assign_member still shows
+    # pre-kill owners while the broker has no assignments — not drift.
+    if g is not None and m_members == b_members and m_members:
+        m_assign = {
+            p: int(assign[p]) for p in range(machine.P)
+        }
+        b_assign = {p: -1 for p in range(machine.P)}
+        for src, mid in member_of.items():
+            if mid in g.members:
+                for (_topic, p) in g.assignments.get(mid, ()):
+                    b_assign[p] = src
+        if m_assign != b_assign:
+            mismatches.append(f"assignment: machine {m_assign} != broker {b_assign}")
+
+    # committed offsets: exact equality on every partition, all lanes
+    for p in range(machine.P):
+        m_off = int(committed[p])
+        b_off = b.committed(GROUP, TOPIC, p) or 0
+        if m_off != b_off:
+            mismatches.append(f"committed[{p}]: machine {m_off} != broker {b_off}")
+    return m_gen, b_gen
+
+
 def differential_kafka_group(engine, seed: int, max_steps: int = 4000) -> Dict:
     """One seed, machine vs Broker coordinator — the STRONG contract on
     every lane, faulted or not (round-5; VERDICT r4 directive 8): exact
@@ -329,44 +396,11 @@ def differential_kafka_group(engine, seed: int, max_steps: int = 4000) -> Dict:
     g = b.groups.get(GROUP)
 
     mismatches: List[str] = []
-    m_members = {i for i in range(1, machine.NUM_NODES) if bool(nodes.joined[i])}
-    b_members = set()
-    if g:
-        mid_to_src = {mid: src for src, mid in member_of.items()}
-        b_members = {mid_to_src[mid] for mid in g.members if mid in mid_to_src}
-    if m_members != b_members:
-        mismatches.append(
-            f"members: machine {sorted(m_members)} != broker {sorted(b_members)}"
-        )
-
-    m_gen = int(nodes.gen[G.COORD])
-    b_gen = g.generation if g else 0
-    if m_gen != b_gen:
-        mismatches.append(f"generation: machine {m_gen} != broker {b_gen}")
-
-    # assignment: both sides range/round-robin by rank over the joined
-    # set — with (non-empty) membership equal, the owner maps must agree
-    # exactly. Empty membership skips: after a coordinator restart with
-    # no rejoin yet, the machine's durable assign_member still shows
-    # pre-kill owners while the broker has no assignments — not drift.
-    if g is not None and m_members == b_members and m_members:
-        m_assign = {
-            p: int(nodes.assign_member[G.COORD, p]) for p in range(machine.P)
-        }
-        b_assign = {p: -1 for p in range(machine.P)}
-        for src, mid in member_of.items():
-            if mid in g.members:
-                for (_topic, p) in g.assignments.get(mid, ()):
-                    b_assign[p] = src
-        if m_assign != b_assign:
-            mismatches.append(f"assignment: machine {m_assign} != broker {b_assign}")
-
-    # committed offsets: exact equality on every partition, all lanes
-    for p in range(machine.P):
-        m_off = int(nodes.committed[G.COORD, p])
-        b_off = b.committed(GROUP, TOPIC, p) or 0
-        if m_off != b_off:
-            mismatches.append(f"committed[{p}]: machine {m_off} != broker {b_off}")
+    m_gen, b_gen = _compare_group(
+        machine, b, member_of, mismatches,
+        joined=nodes.joined, gen=nodes.gen[G.COORD],
+        assign=nodes.assign_member[G.COORD], committed=nodes.committed[G.COORD],
+    )
 
     had_fault = any(ev.kind == "fault" for ev in rp.trace)
     fencing_agreements = fencing_total = 0
@@ -397,6 +431,148 @@ def differential_kafka_group(engine, seed: int, max_steps: int = 4000) -> Dict:
         "broker_gen": b_gen,
         "commits": len(accept_log),
         "fencing_checked": fencing_total,
+        "replay_failed": rp.failed,
+    }
+
+
+def differential_kafka(engine, seed: int, max_steps: int = 6000) -> Dict:
+    """One seed of the whole pipeline (`models/kafka.py`), machine vs the
+    L5 `Broker`: the plain reference of `kafka_pc5`.
+
+    The lane is replayed on the CPU; every request node 0 actually
+    processed (delivered, and node 0 alive) is put to a `Broker` with a
+    topic of 3 partitions and one group, at its virtual time: PRODUCE ->
+    `produce`, FETCH -> `fetch(offset, 8)`, heartbeat -> `join_group`,
+    COMMIT -> `commit_offsets`, the session tick -> `sweep_expired`, a
+    restart of node 0 -> the member table cleared. Compared: per
+    partition the log's records `(producer, seq)` in order and the high
+    watermark; every fetch response a member was delivered against what
+    `Broker.fetch` returned to that request; the totals `appended` and
+    `dup_refused`; and for the group the members, generation, assignment
+    and committed offsets. The contract is `differential_kafka_group`'s:
+    on fault-free seeds it is event for event (every fenced or accepted
+    commit agrees as well), under kills the final state converges — and
+    since the adapter sweeps on the machine's own session ticks and
+    mirrors node 0's kill windows, what converges is exact equality.
+
+    Where the machine departs from the service, and what the adapter
+    does about it:
+
+    * Idempotence. The L5 `Broker` appends whatever it is given: the
+      reference's `SimBroker` knows no producer id and no sequence. The
+      adapter applies Kafka's definition from the service's OWN log — a
+      record is new iff its sequence equals the number of records of
+      that producer the partition already holds — and holds no cursor
+      of its own, so a machine that appends a duplicate or skips a
+      sequence departs from the service's log at that record.
+    * Capacity. The service's logs are unbounded, the machine's hold
+      `log_capacity` records a partition and refuse the rest
+      (`log_full`); the adapter refuses at the same length. `kafka_pc5`
+      guarantees it never happens, and `log_full` is compared.
+    * A record is `(producer, seq)`; the service stores it as the
+      payload `b"<producer>:<seq>"` under no key, on the partition the
+      producer chose (the service's own partitioner hashes a key; the
+      machine draws one and takes it mod 3).
+    * An empty fetch is answered by silence in the machine and by an
+      empty list in the service.
+    * Ordered transport and the ownership check of a commit: as
+      `drive_kafka_coordinator` states."""
+    from .models import kafka as K
+
+    machine = engine.machine
+    rp = replay(engine, seed, max_steps=max_steps)
+    nodes = rp.state.nodes
+    # a lane run to the horizon pops one event at or past it and stops
+    # without processing it: the trace holds it, the broker never saw it
+    trace = [ev for ev in rp.trace if ev.time_us < engine.config.horizon_us]
+    served: Dict[Tuple[int, int, int], set] = {}
+    refused = {"dup": 0, "full": 0}
+
+    def on_request(b, ev):
+        mtype, src, t = ev.payload[0], ev.src, ev.time_us
+        if mtype == K.M_PRODUCE:
+            part, seq = int(ev.payload[1]), int(ev.payload[2])
+            records = b.topics[TOPIC][part].records
+            mine = sum(1 for r in records if r[1].startswith(b"%d:" % src))
+            if seq != mine:
+                refused["dup"] += 1
+            elif len(records) >= machine.log_capacity:
+                refused["full"] += 1
+            else:
+                b.produce(TOPIC, part, None, b"%d:%d" % (src, seq), t)
+        elif mtype == K.M_FETCH:
+            part, off = int(ev.payload[1]), int(ev.payload[2])
+            got = b.fetch(TOPIC, part, off, K.FETCH_MAX)
+            served.setdefault((src, part, off), set()).add(off + len(got))
+
+    b, member_of, accept_log = drive_kafka_coordinator(
+        machine, trace, proto=K, on_request=on_request)
+
+    mismatches: List[str] = []
+    appended = 0
+    for p in range(machine.P):
+        n = int(nodes.log_len[p])
+        m_log = [(int(nodes.log_producer[p, k]), int(nodes.log_seq[p, k]))
+                 for k in range(n)]
+        b_log = [tuple(int(x) for x in r[1].split(b":"))
+                 for r in b.topics[TOPIC][p].records]
+        appended += len(b_log)
+        if b.watermarks(TOPIC, p)[1] != n:
+            mismatches.append(
+                f"high watermark[{p}]: machine {n} != broker "
+                f"{b.watermarks(TOPIC, p)[1]}")
+        if m_log != b_log:
+            k = next((k for k, (x, y) in enumerate(zip(m_log, b_log)) if x != y),
+                     min(len(m_log), len(b_log)))
+            mismatches.append(
+                f"log[{p}] departs at offset {k}: machine {m_log[k:k + 3]} "
+                f"!= broker {b_log[k:k + 3]}")
+    # every fetch response a member was delivered is one the service gave
+    # to that member's request for that (partition, offset)
+    fetches = 0
+    for ev in trace:
+        if ev.kind == "msg" and ev.node != K.BROKER and ev.payload[0] == K.M_FETCH_RESP:
+            part, off, hi = (int(x) for x in ev.payload[1:4])
+            fetches += 1
+            if hi not in served.get((ev.node, part, off), ()):
+                mismatches.append(
+                    f"fetch response to node {ev.node} {(part, off, hi)}: the "
+                    f"service answered {sorted(served.get((ev.node, part, off), ()))}")
+    counters = dict(zip(
+        machine.STREAM_COUNTERS, (int(v) for v in machine.stream_counters(nodes))))
+    for name, want in (("appended", appended), ("dup_refused", refused["dup"]),
+                       ("log_full", refused["full"])):
+        if counters[name] != want:
+            mismatches.append(f"{name}: machine {counters[name]} != adapter {want}")
+
+    m_gen, b_gen = _compare_group(
+        machine, b, member_of, mismatches,
+        joined=nodes.joined, gen=nodes.gen, assign=nodes.assign_member,
+        committed=nodes.committed,
+    )
+    had_fault = any(ev.kind == "fault" for ev in trace)
+    fenced = sum(1 for row in accept_log if row[5] is False)
+    if not had_fault:
+        # event for event: the host mirror's verdict on every commit
+        _gen, decisions = _machine_fencing_mirror(machine, trace, proto=K)
+        for row, want in zip(accept_log, decisions):
+            if row[5] is not None and row[5] != want:
+                mismatches.append(
+                    f"fencing: commit {row[:5]} broker={row[5]} machine-rule={want}")
+        if counters["commits_fenced"] != fenced:
+            mismatches.append(
+                f"commits_fenced: machine {counters['commits_fenced']} != "
+                f"broker {fenced}")
+    return {
+        "ok": not mismatches,
+        "mismatches": mismatches,
+        "had_fault": had_fault,
+        "machine_gen": m_gen,
+        "broker_gen": b_gen,
+        "records": appended,
+        "fetch_responses": fetches,
+        "commits": len(accept_log),
+        "counters": counters,
         "replay_failed": rp.failed,
     }
 

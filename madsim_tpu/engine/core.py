@@ -804,10 +804,14 @@ class Engine:
         )
         # StreamCarry.fr_metrics: the recorder's totals, the churn
         # process's counters after them; [0] with the recorder off
+        # and after those the machine's own totals (`Machine.
+        # STREAM_COUNTERS`: none on a machine that declares none)
         self._fr_metrics_len = (
             FR_METRICS_LEN
             + (len(CHURN_COUNTER_NAMES) if fp.churn is not None else 0)
+            + len(machine.STREAM_COUNTERS)
         ) if config.flight_recorder else 0
+        _check_lane_spec(machine)
         if fp.n_faults > 0 and not fp.enabled_kinds():
             raise ValueError("FaultPlan has n_faults > 0 but every kind disabled")
         if fp.allow_group and (n < 2 or n > 60):
@@ -2524,6 +2528,23 @@ class Engine:
                                 fr_metrics[base + i] + jnp.where(done, book[k], 0).sum()
                                 for i, k in enumerate(CHURN_COUNTER_NAMES)
                             ]))
+                    names = self.machine.STREAM_COUNTERS
+                    if names:
+                        # the machine's totals of the lanes finishing
+                        # this segment: summed, or folded with max
+                        mine = jax.vmap(self.machine.stream_counters)(state.nodes)
+                        mine = mine * done[:, None].astype(jnp.int32)
+                        prev = fr_metrics[self._fr_metrics_len - len(names):]
+                        with _xprof.collective_scope("fr-fold"):
+                            # madsim: collective(fr-fold, reduce=sum)
+                            summed = prev + mine.sum(axis=0)
+                        with _xprof.collective_scope("fr-hwm"):
+                            # madsim: collective(fr-hwm, reduce=max)
+                            maxed = jnp.maximum(prev, mine.max(axis=0))
+                        folds_max = jnp.asarray(
+                            [k in self.machine.STREAM_COUNTERS_MAX for k in names]
+                        )
+                        parts.append(jnp.where(folds_max, maxed, summed))
                     fr_metrics = jnp.concatenate(parts)
 
             # coverage rides the harvest too: OR every lane's bit map
@@ -3215,7 +3236,8 @@ class Engine:
                 perf.span("harvest") if perf else contextlib.nullcontext()
             ):
                 fr_vec = jax.device_get(carry.fr_metrics)
-            fr_stats = {"flight_recorder": fr_metrics_dict(fr_vec)}
+            fr_stats = {"flight_recorder": fr_metrics_dict(
+                fr_vec, self.machine.STREAM_COUNTERS)}
         cov_stats = {}
         cov_map_np = None
         if self.config.coverage:
@@ -3430,6 +3452,32 @@ class Engine:
                 f"batches; diverging leaves: {mismatches}"
             )
         return r1
+
+
+def _check_lane_spec(machine: Machine) -> None:
+    """A machine that declares role-held leaves (`Machine.lane_spec`)
+    is held to its declaration: every other leaf of `init()` has the
+    node axis. A leaf stored once a lane and not declared would be
+    indexed by node in the generic restarts."""
+    spec = machine.lane_spec()
+    if spec is None:
+        return
+    n = machine.NUM_NODES
+    shapes = jax.eval_shape(machine.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    held = jax.tree.leaves(spec)
+    named = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    if len(held) != len(named) or not all(isinstance(h, bool) for h in held):
+        raise ValueError(
+            f"{type(machine).__name__}.lane_spec() must be congruent to "
+            f"init() with a python bool at every leaf"
+        )
+    for h, (path, leaf) in zip(held, named):
+        if not h and (leaf.ndim < 1 or leaf.shape[0] != n):
+            raise ValueError(
+                f"{type(machine).__name__}: leaf {jax.tree_util.keystr(path)} "
+                f"has shape {leaf.shape}, no node axis of {n}, and "
+                f"lane_spec() does not declare it role-held"
+            )
 
 
 def _churn_clog(clogged, disc_bits, up_bits, down, packed: bool):

@@ -8,7 +8,10 @@ item 3 — this authoring model is first-class).
 
 Per-lane calling convention (the engine vmaps over lanes):
 
-  * node state: a pytree whose every leaf has leading dim N (num nodes)
+  * node state: a pytree whose every leaf has leading dim N (num nodes),
+    except the leaves a machine declares role-held (`Machine.lane_spec`):
+    state ONE role of the lane holds (a broker's partition logs), stored
+    once a lane, without the node axis
   * handlers receive the whole pytree + a scalar node index and return
     (new pytree, Outbox); use `update_node` / `.at[i]` scatters
   * Outbox: fixed-width message/timer slots with validity masks — the
@@ -153,6 +156,14 @@ class Machine:
     PAYLOAD_WIDTH: int = 4
     MAX_MSGS: int = 4
     MAX_TIMERS: int = 2
+    # A machine's own totals of a stream (records appended, commits
+    # fenced): the names of the per-lane vector `stream_counters`
+    # returns. With the flight recorder on, the harvest adds each over
+    # the lanes a stream resolves (those named in STREAM_COUNTERS_MAX
+    # fold with max) and they come out beside the recorder's totals, as
+    # `stats["flight_recorder"]["machine"]`. Empty: no leaf and no op.
+    STREAM_COUNTERS: Tuple[str, ...] = ()
+    STREAM_COUNTERS_MAX: Tuple[str, ...] = ()
 
     def empty_outbox(self) -> Outbox:
         return empty_outbox(self.MAX_MSGS, self.MAX_TIMERS, self.PAYLOAD_WIDTH)
@@ -163,12 +174,56 @@ class Machine:
         """Initial node-state pytree (every leaf leading dim NUM_NODES)."""
         raise NotImplementedError
 
+    def lane_spec(self) -> Any:
+        """Optional: the leaves ONE role of the lane holds. A pytree
+        CONGRUENT to `init()`'s node state whose every leaf is a python
+        bool — True marks a role-held leaf: it has NO node axis (a
+        broker's partition logs are stored `[P, CAP]`, not `[N, P,
+        CAP]` with N - 1 rows nobody reads), so its bytes, and the
+        step's write-back select over it, are paid once a lane and not
+        once a node. Handlers read and write it whole. The generic
+        restarts (`_wipe_node_if`, `amnesia_restart_if`,
+        `torn_restart_if`) index axis 0 by node and therefore leave a
+        role-held leaf alone: what a restart of the holding node does to
+        it is the machine's own `restart_lane_if`, which they call
+        (under `--strict-restart` and torn writes too). Everything else
+        the engine does with the node tree — the step's write-back, the
+        lane freeze, replay, the mesh's lane sharding — is shape-blind;
+        provenance words and the digest trail are per node and per
+        event, and hold no node state.
+
+        Default None: every leaf has the node axis. The engine checks
+        the declaration against `init()`'s shapes when it is built."""
+        return None
+
+    def restart_lane_if(self, nodes: Any, i, cond, rng_key) -> Any:
+        """A restart of node i (traced, under `cond`) as the role-held
+        leaves see it: reset what the role keeps in memory only (a
+        coordinator's member table when i is the coordinator). The
+        generic restarts call it after their per-node wipe; a machine's
+        own `restart_if` calls it too, so every kill of the holding
+        node goes through this one hook. Default: nothing is lost."""
+        return nodes
+
+    def _map_node_leaves(self, fn, nodes: Any, *rest: Any) -> Any:
+        """`jax.tree.map(fn, nodes, *rest)` over the leaves that have
+        the node axis; role-held leaves (`lane_spec`) pass through."""
+        spec = self.lane_spec()
+        if spec is None:
+            return jax.tree.map(fn, nodes, *rest)
+        return jax.tree.map(
+            lambda held, cur, *r: cur if held else fn(cur, *r), spec, nodes, *rest
+        )
+
     def _wipe_node_if(self, nodes: Any, i, cond, rng_key) -> Any:
         """Non-virtual building block: copy row i from a fresh init()
         under `cond` (never dispatches to overrides — safe to call from
-        any subclass hook without recursion)."""
+        any subclass hook without recursion). Role-held leaves have no
+        row i and are left alone."""
         fresh = self.init(rng_key)
-        return jax.tree.map(lambda cur, f: set_at(cur, i, f, cond), nodes, fresh)
+        return self._map_node_leaves(
+            lambda cur, f: set_at(cur, i, f, cond), nodes, fresh
+        )
 
     def init_node(self, nodes: Any, i, rng_key) -> Any:
         """Reset node i to its initial state (legacy restart hook).
@@ -217,10 +272,13 @@ class Machine:
                 f"state contract to know which leaves to wipe"
             )
         fresh = self.init(rng_key)
-        return jax.tree.map(
-            lambda durable, cur, f: cur if durable else set_at(cur, i, f, cond),
-            spec, nodes, fresh,
+        nodes = self._map_node_leaves(
+            lambda cur, durable, f: cur if durable else set_at(cur, i, f, cond),
+            nodes, spec, fresh,
         )
+        # a role-held leaf's entry in the contract is documentation: the
+        # machine's own hook is what wipes it
+        return self.restart_lane_if(nodes, i, cond, rng_key)
 
     def torn_spec(self) -> Any:
         """Optional storage-atomicity contract for torn/lost-write
@@ -261,7 +319,7 @@ class Machine:
         fresh = self.init(rng_key)
         leaf_idx = [0]
 
-        def damage(durable, cls, cur, f):
+        def damage(cur, durable, cls, f):
             li = leaf_idx[0]
             leaf_idx[0] += 1
             if not durable:
@@ -285,7 +343,10 @@ class Machine:
                 f"TORN_LOSE/TORN_PREFIX)"
             )
 
-        return jax.tree.map(damage, spec, tspec, nodes, fresh)
+        # role-held leaves take no generic damage (the classes tear node
+        # rows); their volatile part goes through the machine's hook
+        nodes = self._map_node_leaves(damage, nodes, spec, tspec, fresh)
+        return self.restart_lane_if(nodes, i, cond, rng_key)
 
     def restart_node_if(self, nodes: Any, i, cond, rng_key, strict: bool = False) -> Any:
         """Engine-facing restart dispatch — do NOT override. With
@@ -338,6 +399,11 @@ class Machine:
     def summary(self, nodes: Any) -> Any:
         """Small pytree gathered back to host per lane."""
         return jnp.int32(0)
+
+    def stream_counters(self, nodes: Any) -> jax.Array:
+        """int32[len(STREAM_COUNTERS)], in that order, read off one
+        lane's final state."""
+        return jnp.zeros((0,), jnp.int32)
 
     def churn_victim(self, nodes: Any, connected):
         """Optional: the node a churn tick disconnects

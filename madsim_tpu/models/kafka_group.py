@@ -44,6 +44,11 @@ Invariants (checked on-device after every event):
     consumed by any member (at-least-once violated). Tracked with a
     ghost consumed-bitmap — spec-only auxiliary state, written at
     consume time, never read by the protocol.
+
+A half at toy size (pre-filled partitions, no producer): the
+deployment-sized machine — this group over live partition logs fed by
+`mq.py`'s idempotent producers — is `models/kafka.py` (`--machine
+kafka`); size a benchmark cell on that.
 """
 
 from __future__ import annotations

@@ -14,6 +14,10 @@ carry the broker's cumulative cursor, so the invariant holds under
 packet loss, partitions AND kill/restart; the `NoDedupBroker` test
 variant (retries append duplicates) violates it, which is the
 ordering-bug class the reference's kafka tests exist to catch.
+
+A half at toy size: the deployment-sized machine — this produce path over
+three live partition logs, with `kafka_group.py`'s rebalancing group — is
+`models/kafka.py` (`--machine kafka`); size a benchmark cell on that.
 """
 
 from __future__ import annotations

@@ -45,19 +45,26 @@ def prov_word_bits(word: int) -> Dict[str, object]:
     }
 
 
-def fr_metrics_dict(vec: Sequence[int]) -> Dict[str, object]:
+def fr_metrics_dict(
+    vec: Sequence[int], machine_counters: Sequence[str] = ()
+) -> Dict[str, object]:
     """Decode a flight-recorder metrics vector: per-kind fault injection
     totals, the non-scheduled chaos counters (message duplicates pushed,
     crash-with-amnesia restarts applied), then queue / clogged-link /
-    killed-node high-water marks."""
+    killed-node high-water marks; after them the churn process's
+    counters where the plan has one, and last the machine's own totals
+    (`Machine.STREAM_COUNTERS`, named by the caller: the vector carries
+    no names)."""
     v = [int(x) for x in vec]
     nk, ne = len(FR_FAULT_KINDS), len(FR_EXTRAS)
     base = nk + ne + 3
-    if len(v) not in (base, base + len(FR_CHURN)):
+    n_mine = len(machine_counters)
+    if len(v) - n_mine not in (base, base + len(FR_CHURN)):
         raise ValueError(
             f"expected {base} metric words (+{len(FR_CHURN)} with a churn "
-            f"process), got {len(v)}"
+            f"process, +{n_mine} of the machine's), got {len(v)}"
         )
+    mine, v = v[len(v) - n_mine:], v[:len(v) - n_mine]
     out = {
         "faults_injected": dict(zip(FR_FAULT_KINDS, v[:nk])),
         "dup_injected": v[nk],
@@ -69,6 +76,8 @@ def fr_metrics_dict(vec: Sequence[int]) -> Dict[str, object]:
     if len(v) > base:
         # FaultPlan.churn: the ticks fired and the faults they applied
         out["churn"] = dict(zip(FR_CHURN, v[base:]))
+    if machine_counters:
+        out["machine"] = dict(zip(machine_counters, mine))
     return out
 
 
