@@ -29,7 +29,7 @@ import contextlib
 import dataclasses
 import logging
 from functools import partial
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +42,7 @@ _stream_log = logging.getLogger("madsim_tpu.stream")
 
 from .. import kinds as _kinds
 from ..compile_cache import enable_compile_cache
-from ..ops import find_free_slot, pop_earliest
+from ..ops import free_slot_ranks, pop_earliest
 from ..ops.coverage import (
     COV_BAND_AMNESIA,
     COV_BAND_DUP,
@@ -1716,15 +1716,21 @@ class Engine:
                     eq["prov"] = jnp.where(
                         defer_slot, eq["prov"] | s.node_prov[ev_node], eq["prov"]
                     )
-            next_seq = s.next_seq
+            # The event's pushes are collected in push order — message 0,
+            # (its duplicate), message 1, ..., timers, the restart boot —
+            # and land together at the end of `step.timers`: nothing frees
+            # a slot inside a step, so the k-th push that finds one takes
+            # the k-th lowest slot free after the pop, and `_push_ranked`
+            # ranks those slots once and writes every queue leaf once.
+            pushes = []
+            pinned = None
             if churn_next is not None:
                 # re-arm the slot the tick was popped from: a pop and a
                 # push in one, so the process can never overflow the queue
                 rearm, t_next, next_op, next_tick = churn_next
                 with _xprof.scope("step.churn"):
-                    eq = _push(
-                        eq, idx, rearm, t_next, next_seq, EV_FAULT,
-                        jnp.int32(0), jnp.int32(-1),
+                    pinned = (idx, _Push(
+                        rearm, t_next, EV_FAULT, jnp.int32(0), jnp.int32(-1),
                         # selects on an iota, not `make_payload`'s stack of
                         # scalars: that form read 2% more chip-us a seed
                         # (my chip run, PR 27)
@@ -1734,12 +1740,8 @@ class Engine:
                                 jnp.arange(m.PAYLOAD_WIDTH) == 1, next_tick, 0
                             ),
                         ).astype(jnp.int32),
-                        prov=jnp.uint32(0) if cfg.provenance else None,
-                    )
-                next_seq = next_seq + rearm.astype(jnp.int32)
-            failed = s.failed
-            fail_code = s.fail_code
-            msg_count = s.msg_count
+                        jnp.uint32(0),
+                    ))
 
             lat_span = max(1, cfg.latency_max_us - cfg.latency_min_us)
             lat_bits = step_words[layout.lat_off : layout.lat_off + m.MAX_MSGS]
@@ -1774,7 +1776,6 @@ class Engine:
                 dup_lat_bits = step_words[
                     layout.dup_off + m.MAX_MSGS : layout.dup_off + 2 * m.MAX_MSGS
                 ]
-                n_dups = jnp.int32(0)
             # the handling node's outbound clog row, read ONCE (pre-fault
             # state, matching the unpacked path's s.clogged[ev_node, dst])
             # and expanded to bool[N] so each message pays the same tiny
@@ -1791,7 +1792,6 @@ class Engine:
                     blocked = s.clogged[ev_node, dst]
                 if layout.loss_active:
                     blocked = blocked | (drop_bits[mi] < loss_threshold)
-                do_push = want & ~blocked
                 latency = jnp.int32(cfg.latency_min_us) + (
                     lat_bits[mi] % jnp.uint32(lat_span)
                 ).astype(jnp.int32)
@@ -1804,44 +1804,30 @@ class Engine:
                         spike_mag_bits[mi] % jnp.uint32(DELAY_EXTRA_SPAN_US)
                     ).astype(jnp.int32)
                     latency = latency + jnp.where(spiked, extra, 0)
-                slot, has_free = find_free_slot(eq["valid"])
-                overflow = do_push & ~has_free
-                failed = failed | overflow
-                fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
-                do_push = do_push & has_free
-                eq = _push(
-                    eq, slot, do_push, new_now + latency, next_seq, EV_MSG, dst,
-                    ev_node, outbox.msg_payload[mi], prov=sender_prov,
-                )
-                next_seq = next_seq + jnp.where(do_push, 1, 0)
-                msg_count = msg_count + jnp.where(do_push, 1, 0)
+                pushes.append(_Push(
+                    want & ~blocked, new_now + latency, EV_MSG, dst, ev_node,
+                    outbox.msg_payload[mi], sender_prov,
+                ))
                 if layout.dup_active:
                     # Bernoulli duplicate of a successfully pushed message,
                     # re-enqueued with an independently drawn latency (the
                     # idempotency chaos loss-only vocabularies can't
                     # express). Same overflow accounting as any push.
-                    want_dup = do_push & (dup_bits[mi] < jnp.uint32(DUP_PROB_U32))
-                    dslot, dfree = find_free_slot(eq["valid"])
-                    doverflow = want_dup & ~dfree
-                    failed = failed | doverflow
-                    fail_code = jnp.where(doverflow, jnp.int32(OVERFLOW), fail_code)
-                    want_dup = want_dup & dfree
                     dup_latency = jnp.int32(cfg.latency_min_us) + (
                         dup_lat_bits[mi] % jnp.uint32(lat_span)
                     ).astype(jnp.int32)
-                    eq = _push(
-                        eq, dslot, want_dup, new_now + dup_latency, next_seq,
-                        EV_MSG, dst, ev_node, outbox.msg_payload[mi],
+                    pushes.append(_Push(
+                        dup_bits[mi] < jnp.uint32(DUP_PROB_U32),
+                        new_now + dup_latency, EV_MSG, dst, ev_node,
+                        outbox.msg_payload[mi],
                         # the duplicate copy carries the dup attribution bit:
                         # a violation whose lineage includes it names `dup`
-                        prov=(
+                        (
                             sender_prov | jnp.uint32(1 << PROV_BIT_DUP)
                             if sender_prov is not None else None
                         ),
-                    )
-                    next_seq = next_seq + jnp.where(want_dup, 1, 0)
-                    msg_count = msg_count + jnp.where(want_dup, 1, 0)
-                    n_dups = n_dups + want_dup.astype(jnp.int32)
+                        dup_of=len(pushes) - 1,
+                    ))
 
         with _xprof.scope("step.timers"):
             # -- push timers (for the handling node) ----------------------------
@@ -1853,12 +1839,6 @@ class Engine:
                 # fault events arm no timers, so pre == post here)
                 node_skew_q10 = s.skew_q10[ev_node]
             for ti in range(m.MAX_TIMERS):
-                want = outbox_valid_timers[ti]
-                slot, has_free = find_free_slot(eq["valid"])
-                overflow = want & ~has_free
-                failed = failed | overflow
-                fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), fail_code)
-                want = want & has_free
                 tpay = jnp.where(slot0, outbox.timer_id[ti], 0).astype(jnp.int32)
                 t_delay = outbox.timer_delay_us[ti]
                 if cfg.faults.allow_skew:
@@ -1867,25 +1847,33 @@ class Engine:
                         skew_scale_us(t_delay, node_skew_q10),
                         t_delay,
                     )
-                eq = _push(
-                    eq, slot, want, new_now + t_delay, next_seq,
-                    EV_TIMER, ev_node, jnp.int32(-1), tpay, prov=sender_prov,
-                )
-                next_seq = next_seq + jnp.where(want, 1, 0)
+                pushes.append(_Push(
+                    outbox_valid_timers[ti], new_now + t_delay, EV_TIMER,
+                    ev_node, jnp.int32(-1), tpay, sender_prov,
+                ))
 
             # -- restart boot timer ---------------------------------------------
-            want_boot = effective & (boot_node >= 0)
-            slot, has_free = find_free_slot(eq["valid"])
-            boot_overflow = want_boot & ~has_free
-            failed = failed | boot_overflow
-            fail_code = jnp.where(boot_overflow, jnp.int32(OVERFLOW), fail_code)
-            want_boot = want_boot & has_free
             boot_pay = jnp.zeros((m.PAYLOAD_WIDTH,), jnp.int32)  # BOOT == 0
-            eq = _push(
-                eq, slot, want_boot, new_now, next_seq, EV_TIMER, boot_node,
-                jnp.int32(-1), boot_pay, prov=sender_prov,
+            pushes.append(_Push(
+                effective & (boot_node >= 0), new_now, EV_TIMER, boot_node,
+                jnp.int32(-1), boot_pay, sender_prov,
+            ))
+
+            # -- the one pass: rank the free slots, write every leaf ------------
+            eq, landed, overflow, next_seq = _push_ranked(
+                eq, pushes, s.next_seq, pinned
             )
-            next_seq = next_seq + jnp.where(want_boot, 1, 0)
+            failed = s.failed | overflow
+            fail_code = jnp.where(overflow, jnp.int32(OVERFLOW), s.fail_code)
+            msg_count = s.msg_count + sum(
+                ok.astype(jnp.int32)
+                for ok, p in zip(landed, pushes) if p.kind == EV_MSG
+            )
+            if layout.dup_active:
+                n_dups = sum(
+                    ok.astype(jnp.int32)
+                    for ok, p in zip(landed, pushes) if p.dup_of is not None
+                )
 
         with _xprof.scope("step.recorder"):
             # -- flight recorder (observability; gate-off adds NO ops) ----------
@@ -3510,27 +3498,70 @@ def _churn_clog(clogged, disc_bits, up_bits, down, packed: bool):
     return (clogged | cut) & ~back
 
 
-def _push(eq, idx, do_push, time, seq, kind, node, src, payload, prov=None):
-    """Masked-select write of one event into slot `idx` (no scatters).
-    `prov`, when the provenance gate materializes the eq["prov"] plane,
-    is the pushed event's lineage word (the sender's word, plus the dup
-    bit for duplicate copies)."""
-    m = (jnp.arange(eq["valid"].shape[0]) == idx) & do_push
+class _Push(NamedTuple):
+    """One push an event wants to make: the lane's candidate for a queue
+    slot. `dup_of`, static, is the index (in the event's push list) of
+    the message this one duplicates: it is wanted only if that one
+    landed. `prov` is the pushed event's lineage word (the sender's
+    word, plus the dup bit for duplicate copies), read only where the
+    provenance gate materializes the eq["prov"] plane."""
 
-    def upd(arr, value):
-        return jnp.where(m, jnp.int32(value), arr)
+    want: Any  # traced bool
+    time: Any
+    kind: Any
+    node: Any
+    src: Any
+    payload: Any  # int32[P]
+    prov: Any = None
+    dup_of: Optional[int] = None
 
-    out = {
-        "time": upd(eq["time"], time),
-        "seq": upd(eq["seq"], seq),
-        "kind": upd(eq["kind"], kind),
-        "node": upd(eq["node"], node),
-        "src": upd(eq["src"], src),
-        "payload": jnp.where(m[:, None], payload[None, :], eq["payload"]),
-        "valid": eq["valid"] | m,
-    }
-    if "prov" in eq:
-        out["prov"] = (
-            jnp.where(m, prov, eq["prov"]) if prov is not None else eq["prov"]
-        )
-    return out
+
+def _push_ranked(eq, pushes, next_seq, pinned=None):
+    """Land all of one event's pushes in ONE pass over the queue: the
+    free slots are ranked once (`ops.free_slot_ranks`) and every leaf is
+    written once, by masked selects (no scatters).
+
+    The k-th wanted push lands in the k-th lowest free slot with seq
+    `next_seq + k`; once the free slots run out every later wanted push
+    overflows — what a first-free scan and a whole-queue write per push,
+    in sequence, arrive at, at one K-th of the passes. `pinned`, a
+    `(slot, push)`, is a push into a slot of its own that is known to be
+    free (the churn re-arm of the popped slot): it takes the first seq
+    and its slot is ranked as taken.
+
+    Returns (eq, landed, overflow, next_seq): `landed[k]` says whether
+    `pushes[k]` found a slot, `overflow` whether any wanted one did not.
+    """
+    valid = eq["valid"]
+    writes = []  # (one-hot slot mask, seq, push); the masks are disjoint
+    if pinned is not None:
+        slot, push = pinned
+        mask = (jnp.arange(valid.shape[0]) == slot) & push.want
+        writes.append((mask, next_seq, push))
+        valid = valid | mask
+        next_seq = next_seq + push.want.astype(jnp.int32)
+    rank, n_free = free_slot_ranks(valid)
+    taken = jnp.int32(0)
+    landed = []
+    overflow = jnp.bool_(False)
+    for push in pushes:
+        want = push.want if push.dup_of is None else push.want & landed[push.dup_of]
+        fits = taken < n_free
+        ok = want & fits
+        overflow = overflow | (want & ~fits)
+        # taken slots rank -1, so a push that lands nowhere asks for -2
+        writes.append((rank == jnp.where(ok, taken, -2), next_seq + taken, push))
+        landed.append(ok)
+        taken = taken + ok.astype(jnp.int32)
+
+    # the slots that were taken: the free ones ranked below `taken`
+    out = dict(eq, valid=valid | ((rank >= 0) & (rank < taken)))
+    for mask, seq, push in writes:
+        narrow = {"time": push.time, "seq": seq, "kind": push.kind,
+                  "node": push.node, "src": push.src}
+        for name, value in narrow.items():
+            out[name] = jnp.where(mask, jnp.int32(value), out[name])
+        out["payload"] = jnp.where(mask[:, None], push.payload[None, :], out["payload"])
+        if "prov" in eq:
+            out["prov"] = jnp.where(mask, push.prov, out["prov"])
+    return out, landed, overflow, next_seq + taken

@@ -43,8 +43,30 @@ def pop_earliest(eq_time, eq_seq, eq_valid) -> Tuple[jax.Array, jax.Array]:
     return idx, jnp.any(eq_valid)
 
 
-def find_free_slot(eq_valid) -> Tuple[jax.Array, jax.Array]:
-    """First free slot index and whether one exists (lane overflow check)."""
+def free_slot_ranks(eq_valid) -> Tuple[jax.Array, jax.Array]:
+    """Rank every free slot of one lane's queue, once an event.
+
+    Returns (rank, n_free): rank int32[Q] is the number of free slots
+    below q where slot q is free and -1 where it is taken, so
+    `rank == k` is the one-hot mask of the k-th lowest free slot (all
+    False for k >= n_free: the lane overflow check). The k-th push of
+    an event that finds a slot takes exactly that one — what K
+    first-free scans, each after the write before it, arrive at.
+
+    The exclusive prefix count is a product with a strict triangle: on
+    the chip it rides the otherwise idle MXU (`[L, Q] x [Q, Q]` under
+    vmap) and read no slower than `cumsum`'s reduce-window, a `[Q, Q]`
+    compare-sum or log-step shifts in any cell (my chip runs, PR 34).
+    Exact on every backend: 0/1 in bfloat16, sums <= Q in float32.
+    """
     free = ~eq_valid
-    idx = jnp.argmax(free)  # first True
-    return idx, jnp.any(free)
+    q = free.shape[0]
+    below = (jnp.arange(q)[:, None] < jnp.arange(q)[None, :]).astype(jnp.bfloat16)
+    excl = jnp.dot(
+        free.astype(jnp.bfloat16), below, preferred_element_type=jnp.float32
+    ).astype(jnp.int32)
+    # n_free is a reduction of its own, not `excl[-1] + free[-1]`: with the
+    # count read off the product XLA laid the step out differently and
+    # `raft5_sweep` read 2999 seeds/s for 3282 (my chip runs, PR 34;
+    # PERF.md section 6: a matter of layout assignment, not of this sum)
+    return jnp.where(free, excl, -1), jnp.sum(free, dtype=jnp.int32)
