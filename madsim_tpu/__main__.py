@@ -36,8 +36,9 @@ def build_machine(name: str, nodes: int = 0, log_capacity: int = 0):
     lives beside its model) so the hunt -> shrink -> replay -> corpus
     workflow is demonstrable without writing a protocol first.
 
-    `log_capacity` (0 = the registry's 8, for kafka its 64) sizes the
-    raft machines' log and the kafka machines' partition logs
+    `log_capacity` (0 = the registry's 8, for kafka and kvraft its 64)
+    sizes the raft and kvraft machines' log and the kafka machines'
+    partition logs
     (`--log-capacity`; a corpus entry records it). One object per
     (name, nodes, log_capacity) per process: the compiled-replay cache
     hangs on the machine object (engine/replay.py `_replay_cache`), so
@@ -60,6 +61,7 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
     from .models.kafka import KafkaMachine, NoDedupKafkaMachine
     from .models.kafka_group import KafkaGroupMachine, NoFencingGroupMachine
     from .models.kv import KvMachine
+    from .models.kvraft import KvRaftMachine, LocalGetKvRaft
     from .models.mq import MqMachine
     from .models.multipaxos import MultiPaxosMachine, NoPromiseCheckMultiPaxos
     from .models.paxos import NoPromiseCheckPaxos, PaxosMachine
@@ -90,6 +92,13 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
         ),
         "demo-nodedup-kafka": lambda: NoDedupKafkaMachine(
             num_nodes=nodes or 5, log_capacity=log_capacity or 64
+        ),
+        # 5 servers + 5 clerks; the servers' logs: 64 where unset
+        "kvraft": lambda: KvRaftMachine(
+            num_nodes=nodes or 10, log_capacity=log_capacity or 64
+        ),
+        "demo-localget-kvraft": lambda: LocalGetKvRaft(
+            num_nodes=nodes or 10, log_capacity=log_capacity or 64
         ),
         "paxos": lambda: PaxosMachine(num_nodes=nodes or 5),
         "multipaxos": lambda: MultiPaxosMachine(num_nodes=nodes or 5),
@@ -137,7 +146,7 @@ def _registry_machine(name: str, nodes: int, log_capacity: int = 0):
         sys.exit(f"unknown machine {name!r}; choose from {sorted(machines)}")
     machine = machines[name]()
     if log_capacity and getattr(machine, "log_capacity", None) != log_capacity:
-        sys.exit(f"--log-capacity sizes the log of a raft or kafka machine; "
+        sys.exit(f"--log-capacity sizes the log of a raft, kvraft or kafka machine; "
                  f"{name!r} has none")
     return machine
 
@@ -400,6 +409,8 @@ def _print_fr_stats(stats) -> None:
         c = fr["churn"]
         extra += (f", churn {c['ticks']} ticks / {c['disconnects']} "
                   f"disconnects / {c['reconnects']} reconnects")
+        if "partitions" in c:  # kind kv3a
+            extra += f" / {c['partitions']} partitions / {c['crashes']} crashes"
     if fr.get("machine"):
         extra += ", machine [" + ", ".join(
             f"{k}={v}" for k, v in fr["machine"].items()) + "]"
@@ -2083,20 +2094,25 @@ def main(argv=None) -> int:
             "draw their faults as they fire, one queue slot however many. "
             "fig8 = 6.824 TestFigure8Unreliable2C's loop: every U[0,13) ms "
             "(10%% of ticks U[0,500) ms) disconnect the leader w.p. 1/2, "
-            "reconnect a random node while under a majority is connected; "
-            "needs --churn-until",
+            "reconnect a random node while under a majority is connected. "
+            "kv3a = 6.824 lab 3A's partitioner and crash "
+            "(TestPersistPartitionUnreliable3A): every 1 s + U[0,200) ms "
+            "re-draw a random two-way split of the nodes the machine names "
+            "(kvraft: its servers); at --churn-until heal, kill them all at "
+            "once and restart them 150 ms later. Needs --churn-until",
         )
         p.add_argument(
             "--churn-until", type=float, default=None, metavar="S",
             help="virtual second at which --churn reconnects every node "
-            "and stops",
+            "and stops (kv3a: and kills and restarts the named nodes)",
         )
         p.add_argument(
             "--log-capacity", type=int, default=None, metavar="N",
             help="log entries a node of a raft machine can hold (default "
             "8: a lane ends when every node has committed a full log), or "
-            "records a partition log of a kafka machine can hold (default "
-            "64: a full log refuses appends and counts log_full)",
+            "records a partition log of a kafka machine, or entries a "
+            "server's log of a kvraft machine, can hold (default 64: a full "
+            "log refuses appends and counts log_full)",
         )
         p.add_argument(
             "--latency", default=None, metavar="MIN_US,MAX_US",

@@ -68,6 +68,14 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CHURN_DISCONNECT = "disconnect"
 CHURN_RECONNECT = "reconnect"
+# kind `kv3a` (ChurnPlan.kind): a tick re-draws the split (the third
+# field is the bitmask of the named nodes on side 1), the heal clears it
+# (the mask of the named nodes) and kills each named node; a restart
+# brings one back
+CHURN_PARTITION = "partition"
+CHURN_HEAL = "heal"
+CHURN_KILL = "kill"
+CHURN_RESTART = "restart"
 _CHURN_KEY_TAG = 0x4D414443  # engine/core.py CHURN_KEY_TAG
 _M32 = 0xFFFFFFFF
 
@@ -104,16 +112,23 @@ def churn_draw(seed: int, draw: int) -> list:
 
 class ChurnReference:
     """The churn process of one seed, stepped tick by tick: the tester's
-    loop in plain Python. `plan` is anything with ChurnPlan's fields."""
+    loop in plain Python. `plan` is anything with ChurnPlan's fields;
+    `nodes`, for a plan of kind `kv3a`, the nodes it acts on
+    (`Machine.churn_nodes()`; default all)."""
 
-    def __init__(self, seed: int, plan, n: int, until_us: int):
+    def __init__(self, seed: int, plan, n: int, until_us: int, nodes=None):
         self.seed, self.plan, self.n, self.until_us = seed, plan, n, until_us
+        self.kv3a = getattr(plan, "kind", "fig8") == "kv3a"
+        self.nodes = sorted(nodes) if nodes else list(range(n))
         self.majority = plan.majority or n // 2 + 1
         self.down: set = set()
         self.tick = 0
-        self.t_us = self._sleep(churn_draw(seed, 0))  # when tick 0 fires
+        # when tick 0 fires: kv3a's partitioner splits, then sleeps
+        self.t_us = 0 if self.kv3a else self._sleep(churn_draw(seed, 0))
 
     def _sleep(self, words) -> int:
+        if self.kv3a:
+            return self.plan.period_us + words[3] % self.plan.jitter_us
         is_long = words[2] % 1000 < self.plan.long_sleep_permille
         return words[3] % (
             self.plan.long_sleep_us if is_long else self.plan.short_sleep_us
@@ -134,6 +149,12 @@ class ChurnReference:
         applied: [(t_us, op, node)]."""
         words = churn_draw(self.seed, self.tick + 1)
         t, out = self.t_us, []
+        if self.kv3a:
+            # one coin a named node: bit i of the draw's word 0
+            sides = words[0] & sum(1 << i for i in self.nodes)
+            self.tick += 1
+            self.t_us = t + self._sleep(words)
+            return [(t, CHURN_PARTITION, sides)]
         if victim is None:
             up = [i for i in range(self.n) if i not in self.down]
             victim = up[words[4] % len(up)] if up else -1
@@ -150,27 +171,40 @@ class ChurnReference:
         return out
 
     def heal(self) -> list:
+        if self.kv3a:
+            t, back = self.until_us, self.until_us + self.plan.restart_after_us
+            return (
+                [(t, CHURN_HEAL, sum(1 << i for i in self.nodes))]
+                + [(t, CHURN_KILL, i) for i in self.nodes]
+                + [(back, CHURN_RESTART, i) for i in self.nodes]
+            )
         out = [(self.until_us, CHURN_RECONNECT, i) for i in sorted(self.down)]
         self.down.clear()
         return out
 
 
 def churn_reference(seed: int, plan, leader_at, *, n: int, until_us: int,
-                    horizon_us: Optional[int] = None) -> list:
+                    horizon_us: Optional[int] = None, nodes=None) -> list:
     """The faults the churn process of `seed` applies, [(t_us, op,
     node)] in order: tick times, coins and reconnect picks from the
     seed, the victim of tick i from `leader_at(t_us, i, connected)`
     (`connected`: bool per node, by the process's own book; return -1
     for no leader, None to let the process draw a connected node).
-    Events at or past `horizon_us` are never applied, as on the lane."""
-    ref = ChurnReference(seed, plan, n, until_us)
+    Events at or past `horizon_us` are never applied, as on the lane.
+    A plan of kind `kv3a` reads nothing off the run (`leader_at` may be
+    None): its splits, its heal, its kills and its restarts of `nodes`
+    are a function of the seed alone."""
+    ref = ChurnReference(seed, plan, n, until_us, nodes=nodes)
     out: list = []
     while not ref.over:
         if horizon_us is not None and ref.t_us >= horizon_us:
             return out
-        out += ref.fire(leader_at(ref.t_us, ref.tick, ref.connected()))
+        victim = None if ref.kv3a else leader_at(ref.t_us, ref.tick, ref.connected())
+        out += ref.fire(victim)
     if horizon_us is None or until_us < horizon_us:
-        out += ref.heal()
+        out += [
+            ev for ev in ref.heal() if horizon_us is None or ev[0] < horizon_us
+        ]
     return out
 
 
@@ -180,23 +214,41 @@ def applied_churn_faults(engine: Engine, seed: int, max_steps: int = 10_000,
     state trail: [(t_us, op, node)] in order. `on_tick(tick, t_us,
     state_before)`, when given, sees the state each churn tick found
     (where a test reads the leader from)."""
-    from .engine.core import F_CHURN_HEAL, F_CHURN_TICK
+    import numpy as np
+
+    from .engine.core import F_CHURN_HEAL, F_CHURN_RESTART, F_CHURN_TICK
     from .engine.replay import replay
 
     out: list = []
     before = [engine.init_lane(seed)]
+    kv3a = engine.config.faults.churn.kind == "kv3a"
+    n = engine.machine.NUM_NODES
 
     def hook(ev, state) -> None:
-        if ev.kind == "fault" and ev.payload[0] in (F_CHURN_TICK, F_CHURN_HEAL) \
+        op = ev.payload[0]
+        if ev.kind == "fault" and op in (F_CHURN_TICK, F_CHURN_HEAL, F_CHURN_RESTART) \
                 and ev.time_us < engine.config.horizon_us:
-            if on_tick is not None and ev.payload[0] == F_CHURN_TICK:
+            if on_tick is not None and op == F_CHURN_TICK:
                 on_tick(ev.payload[1], ev.time_us, before[0])
             cut, back = (int(x) for x in state.churn["last"])
-            n = engine.machine.NUM_NODES
-            out.extend((ev.time_us, CHURN_DISCONNECT, i)
-                       for i in range(n) if (cut >> i) & 1)
-            out.extend((ev.time_us, CHURN_RECONNECT, i)
-                       for i in range(n) if (back >> i) & 1)
+            if kv3a:
+                # the split or the healed set from the process's book; the
+                # kills and the restart from the lane's own `killed`
+                was, now = (np.asarray(s.killed) for s in (before[0], state))
+                if op == F_CHURN_TICK:
+                    out.append((ev.time_us, CHURN_PARTITION, cut))
+                elif op == F_CHURN_HEAL:
+                    out.append((ev.time_us, CHURN_HEAL, back))
+                    out.extend((ev.time_us, CHURN_KILL, i)
+                               for i in range(n) if now[i] and not was[i])
+                else:
+                    out.extend((ev.time_us, CHURN_RESTART, i)
+                               for i in range(n) if was[i] and not now[i])
+            else:
+                out.extend((ev.time_us, CHURN_DISCONNECT, i)
+                           for i in range(n) if (cut >> i) & 1)
+                out.extend((ev.time_us, CHURN_RECONNECT, i)
+                           for i in range(n) if (back >> i) & 1)
         before[0] = state
 
     replay(engine, seed, max_steps=max_steps, on_step=hook, trace=False)
@@ -562,4 +614,198 @@ def differential_raft(
         "schedule_mismatches": sum(1 for r in rows if not r["schedule_ok"]),
         "device_elected": sum(1 for r in rows if r["device"]["elected"]),
         "host_elected": sum(1 for r in rows if r["host"]["elected"]),
+    }
+
+
+# -- the KV service on Raft: a plain reference of the service layer -----------
+
+
+class _PlainKv:
+    """One server's state machine as the lab writes it: a dict of real
+    strings and a dict of sessions."""
+
+    def __init__(self):
+        self.data: Dict[str, str] = {}
+        self.sessions: Dict[int, tuple] = {}  # clerk -> (seq, reply)
+
+    def apply(self, op: int, clerk: int, seq: int, j: int) -> bool:
+        """Apply one committed command; False where the session table
+        refuses it as a duplicate."""
+        last = self.sessions.get(clerk)
+        if last is not None and seq <= last[0]:
+            return False
+        key = str(clerk)
+        if op == 1:  # Append(key, "x <clerk> <j> y")
+            self.data[key] = self.data.get(key, "") + f"x {clerk} {j} y"
+        self.sessions[clerk] = (seq, self.data.get(key, ""))
+        return True
+
+
+def kv_value_words(value: str) -> tuple:
+    """(length, rolling hash) of a value string `"x c j y"...`, as
+    `models/kvraft.py` holds it: the number of appended items and
+    `hash_step` folded over their j's."""
+    from .models.kvraft import hash_step
+
+    js = [int(item.split()[2]) for item in value.split("y") if item.strip()]
+    h = 0
+    for j in js:
+        h = hash_step(h, j)
+    return len(js), h
+
+
+def check_clnt_appends(clnt: int, value: str, count: int) -> List[str]:
+    """The source's `checkClntAppends`: `value` holds each of the
+    clerk's `count` appends exactly once, in order."""
+    bad, lastoff = [], -1
+    for j in range(count):
+        wanted = f"x {clnt} {j} y"
+        off = value.find(wanted)
+        if off < 0:
+            bad.append(f"clerk {clnt}: missing element {wanted!r} in {value!r}")
+            continue
+        if value.rfind(wanted) != off:
+            bad.append(f"clerk {clnt}: duplicate element {wanted!r} in {value!r}")
+        if off <= lastoff:
+            bad.append(f"clerk {clnt}: wrong order for element {wanted!r} in {value!r}")
+        lastoff = off
+    return bad
+
+
+def differential_kvraft(engine: Engine, seed: int, max_steps: int = 10_000) -> Dict:
+    """One seed of `--machine kvraft`, its service layer against a plain
+    store: the plain reference of `kvraft5`.
+
+    The lane is replayed on the CPU. After every event, every command a
+    server has newly applied — read from ITS log in ITS applied order —
+    is put to a `_PlainKv` of that server (a dict of real strings
+    `"x c j y"` concatenated, a dict of sessions; a fresh one when a
+    restart has wiped the server), and the machine's (length, hash) of
+    every key on that server is compared with the strings' (`at every
+    apply`). Every answer a clerk accepts is compared with what the
+    answering server's store recorded for that `(clerk, seq)` — an
+    answer that no applied command produced is a mismatch — and, for a
+    Get, with the clerk's own model of its value, the concatenation of
+    the appends it has had acknowledged (the source's "get wrong
+    value"). At the end the source's own check runs on the strings:
+    `checkClntAppends` on every closing Get's value, and on every
+    server's final value for every key.
+
+    Where this departs from the source, and what it does not cover:
+
+    * A value is a string here and (length, rolling hash of the j's) in
+      the machine; the two are compared through `kv_value_words`.
+    * One key a clerk (the source's 3A tests use key = clerk id too).
+    * A command's identity is `(clerk, seq)`; the source's solutions
+      carry the same pair.
+    * The Raft layer is NOT checked here: which commands commit, in what
+      order, under which leader. There the chain is the machine's own
+      invariants 101 / 102 (over terms and commands) and 173 on every
+      event, and device == CPU replay in every run (`ROADMAP.md` M8: the
+      host example Raft cannot stand as a reference under load)."""
+    import numpy as np
+
+    from .engine.replay import replay
+    from .models import kvraft as K
+
+    machine = engine.machine
+    s_n, c_n = machine.servers, machine.clerks
+    stores = [_PlainKv() for _ in range(s_n)]
+    ref_applied = [0] * s_n
+    model = [""] * c_n  # a clerk's own model: what it has had acknowledged
+    acked_appends = [0] * c_n
+    closing: Dict[int, str] = {}
+    mismatches: List[str] = []
+    counts = {"applies": 0, "replies": 0, "refused": 0}
+    before = [engine.init_lane(seed)]
+
+    def hook(ev, state) -> None:
+        prev, nodes = before[0].nodes, state.nodes
+        before[0] = state
+        if ev.time_us >= engine.config.horizon_us:
+            return
+        applied = np.asarray(nodes.last_applied)
+        if (applied != np.asarray(prev.last_applied)).any():
+            log_cmd = np.asarray(nodes.raft.log_cmd)
+            kv_len, kv_hash = np.asarray(nodes.kv_len), np.asarray(nodes.kv_hash)
+            for srv in range(s_n):
+                if applied[srv] < ref_applied[srv]:  # a restart wiped it
+                    stores[srv], ref_applied[srv] = _PlainKv(), 0
+                moved = applied[srv] > ref_applied[srv]
+                while ref_applied[srv] < applied[srv]:
+                    ref_applied[srv] += 1
+                    op, clerk, seq, j = (
+                        int(x) for x in K.unpack_cmd(int(log_cmd[srv, ref_applied[srv]])))
+                    counts["applies"] += 1
+                    if not stores[srv].apply(op, clerk, seq, j):
+                        counts["refused"] += 1
+                if moved:
+                    for key in range(c_n):
+                        want = kv_value_words(stores[srv].data.get(str(key), ""))
+                        got = (int(kv_len[srv, key]), int(kv_hash[srv, key]))
+                        if got != want:
+                            mismatches.append(
+                                f"t={ev.time_us} server {srv} key {key} after index "
+                                f"{ref_applied[srv]}: machine {got} != store {want}")
+        if ev.kind == "msg" and ev.node >= s_n and ev.payload[0] == K.M_REPLY \
+                and ev.payload[2] == K.ST_OK and not bool(before_killed[0][ev.node]):
+            clk = ev.node - s_n
+            if bool(prev.inflight[clk]) and ev.payload[1] == int(prev.seq[clk]):
+                counts["replies"] += 1
+                seq, got = ev.payload[1], (ev.payload[3], ev.payload[4])
+                said = stores[ev.src].sessions.get(clk)
+                if said is None or said[0] != seq:
+                    mismatches.append(
+                        f"t={ev.time_us} clerk {clk} seq {seq}: server {ev.src} answered "
+                        f"{got}, but its store applied no such command ({said})")
+                    value = None
+                else:
+                    value = said[1]
+                    if got != kv_value_words(value):
+                        mismatches.append(
+                            f"t={ev.time_us} clerk {clk} seq {seq}: answer {got} != "
+                            f"store {kv_value_words(value)} ({value!r})")
+                if int(prev.op[clk]) == K.OP_APPEND:
+                    model[clk] += f"x {clk} {acked_appends[clk]} y"
+                    acked_appends[clk] += 1
+                elif value is not None and value != model[clk]:
+                    mismatches.append(
+                        f"t={ev.time_us} clerk {clk} seq {seq}: get wrong value "
+                        f"{value!r}, expected {model[clk]!r}")
+                if int(prev.phase[clk]) == K.CLOSING and value is not None:
+                    closing[clk] = value
+        before_killed[0] = np.asarray(state.killed)
+
+    before_killed = [np.asarray(before[0].killed)]
+    rp = replay(engine, seed, max_steps=max_steps, on_step=hook, trace=False)
+    # the source's own check, on the strings
+    for clk, value in sorted(closing.items()):
+        mismatches += check_clnt_appends(clk, value, acked_appends[clk])
+        if kv_value_words(value)[0] != acked_appends[clk]:
+            mismatches.append(
+                f"clerk {clk}: closing value holds {kv_value_words(value)[0]} "
+                f"appends, {acked_appends[clk]} were acknowledged")
+    for srv, store in enumerate(stores):
+        for key, value in sorted(store.data.items()):
+            mismatches += [
+                f"server {srv}: {m}" for m in
+                check_clnt_appends(int(key), value, kv_value_words(value)[0])]
+    nodes = rp.state.nodes
+    counters = dict(zip(
+        machine.STREAM_COUNTERS, (int(v) for v in machine.stream_counters(nodes))))
+    if counters["closing_gets_acked"] != len(closing) and not mismatches:
+        mismatches.append(
+            f"closing_gets_acked: machine {counters['closing_gets_acked']} != "
+            f"reference {len(closing)}")
+    return {
+        "ok": not mismatches,
+        "mismatches": mismatches,
+        "applies": counts["applies"],
+        "refused": counts["refused"],
+        "replies": counts["replies"],
+        "closing_values": len(closing),
+        "acked_appends": list(acked_appends),
+        "counters": counters,
+        "replay_failed": rp.failed,
+        "fail_code": rp.fail_code,
     }

@@ -72,7 +72,7 @@ from ..ops.step_rng import (
 )
 from ..perf import xprof as _xprof
 from ..utils import set2d, tree_where
-from .machine import BOOT, Machine, Outbox
+from .machine import BOOT, Machine, Outbox, RoleRows
 
 # Event kinds
 EV_TIMER = 0
@@ -115,6 +115,10 @@ F_HASYM_HEAL = 19  # heal op unclogs ONE direction arg1->arg2 — the two
 # the process is over. payload[1] of a tick is its index.
 F_CHURN_TICK = 20
 F_CHURN_HEAL = 21
+# kind `kv3a` only: the heal kills every named node, and this op brings
+# them back, one an event at one instant — payload[1] is the node, and
+# the slot re-arms itself for the next until all are up
+F_CHURN_RESTART = 22
 
 # FaultPlan kind indices (op_apply = 2*kind)
 K_PAIR = 0
@@ -292,17 +296,36 @@ class ChurnPlan:
     i + 1 the coins of tick i and the sleep after it. So the ticks'
     times, coins and reconnect picks are a function of the seed alone
     (`differential.churn_reference` re-derives them in plain Python),
-    no other stream moves, and only the victim depends on the run."""
+    no other stream moves, and only the victim depends on the run.
+
+    `kind="kv3a"` is another loop on the same slot and the same stream:
+    the partitioner and the crash of 6.824's lab 3A tester
+    (`TestPersistPartitionUnreliable3A`, MadRaft's
+    `persist_partition_unreliable_3a`). Tick 0 fires at t = 0, then one
+    every `period_us` + U[0, `jitter_us`). A tick draws a side, 0 or 1,
+    for each node the machine names (`Machine.churn_nodes()`, default
+    all: bit i of the draw's word 0) and sets the clog rows so that a
+    link between two named nodes carries traffic iff they drew the same
+    side; links with an unnamed end are not touched (the tester's
+    clerks reach every server). At `churn_until_us` every link between
+    named nodes heals and every named node is KILLED at that instant;
+    `restart_after_us` later they restart, each through the machine's
+    restart hook and its BOOT, one an event at one virtual instant."""
 
     disconnect_permille: int = 500
     long_sleep_permille: int = 100
     long_sleep_us: int = 500_000
     short_sleep_us: int = 13_000
     majority: int = 0  # 0 = NUM_NODES // 2 + 1
+    kind: str = "fig8"  # or "kv3a"; the fields below are kv3a's
+    period_us: int = 1_000_000
+    jitter_us: int = 200_000
+    restart_after_us: int = 150_000
 
 
+CHURN_KINDS = ("fig8", "kv3a")
 # `--churn <name>`: the named parameter sets
-CHURN_PRESETS = {"fig8": ChurnPlan()}
+CHURN_PRESETS = {"fig8": ChurnPlan(), "kv3a": ChurnPlan(kind="kv3a")}
 
 # second key word of the churn stream ("MADC"); the first is the seed
 CHURN_KEY_TAG = 0x4D414443
@@ -310,6 +333,14 @@ CHURN_DRAW_WORDS = 6  # coin, reconnect pick, long coin, sleep, victim, spare
 CHURN_DRAW_STRIDE = 8  # counters of draw d: 8*d + [0, 6)
 # LaneState.churn's counters, in the order they ride fr_metrics' tail
 CHURN_COUNTER_NAMES = _kinds.FR_CHURN_NAMES
+
+
+def churn_counter_names(plan: "ChurnPlan") -> tuple:
+    """The counters the process of `plan` keeps: kind `kv3a` adds its
+    two AFTER the three every kind has, so those keep their places."""
+    return CHURN_COUNTER_NAMES + (
+        _kinds.FR_CHURN_KV3A_NAMES if plan.kind == "kv3a" else ()
+    )
 
 
 def churn_key(seed) -> jax.Array:
@@ -332,6 +363,10 @@ def churn_words(key, draw) -> jax.Array:
 
 def churn_sleep_us(plan: ChurnPlan, words) -> jax.Array:
     """The sleep a draw's words give (int32 us)."""
+    if plan.kind == "kv3a":
+        return (
+            jnp.uint32(plan.period_us) + words[3] % jnp.uint32(plan.jitter_us)
+        ).astype(jnp.int32)
     is_long = (words[2] % jnp.uint32(1000)) < jnp.uint32(plan.long_sleep_permille)
     return jnp.where(
         is_long,
@@ -799,6 +834,28 @@ class Engine:
                 )
             if min(fp.churn.long_sleep_us, fp.churn.short_sleep_us) < 1:
                 raise ValueError("ChurnPlan sleeps are U[0, x) us with x >= 1")
+            if fp.churn.kind not in CHURN_KINDS:
+                raise ValueError(
+                    f"ChurnPlan.kind {fp.churn.kind!r}: one of {CHURN_KINDS}"
+                )
+            if fp.churn.kind == "kv3a" and min(
+                fp.churn.period_us, fp.churn.jitter_us, fp.churn.restart_after_us
+            ) < 1:
+                raise ValueError(
+                    "ChurnPlan kv3a: period_us, jitter_us and "
+                    "restart_after_us are >= 1"
+                )
+        # the nodes a kv3a process acts on, ascending (all by default)
+        self._churn_nodes = tuple(sorted(
+            (machine.churn_nodes() or range(n)) if fp.churn is not None else ()
+        ))
+        if self._churn_nodes and not (
+            0 <= self._churn_nodes[0] and self._churn_nodes[-1] < n
+        ):
+            raise ValueError(
+                f"{type(machine).__name__}.churn_nodes() {self._churn_nodes} "
+                f"names nodes outside 0..{n - 1}"
+            )
         self._churn_majority = (
             (fp.churn.majority or n // 2 + 1) if fp.churn is not None else 0
         )
@@ -808,7 +865,7 @@ class Engine:
         # STREAM_COUNTERS`: none on a machine that declares none)
         self._fr_metrics_len = (
             FR_METRICS_LEN
-            + (len(CHURN_COUNTER_NAMES) if fp.churn is not None else 0)
+            + (len(churn_counter_names(fp.churn)) if fp.churn is not None else 0)
             + len(machine.STREAM_COUNTERS)
         ) if config.flight_recorder else 0
         _check_lane_spec(machine)
@@ -1126,7 +1183,10 @@ class Engine:
         churn = {}
         if fp.churn is not None:
             ckey = churn_key(seed)
-            t0 = churn_sleep_us(fp.churn, churn_words(ckey, 0))
+            if fp.churn.kind == "kv3a":
+                t0 = jnp.int32(0)  # the partitioner splits, then sleeps
+            else:
+                t0 = churn_sleep_us(fp.churn, churn_words(ckey, 0))
             over = t0 >= fp.churn_until_us
             msk = slots == n + fp.slots_per_fault * fp.n_faults
             eq_time = jnp.where(
@@ -1146,7 +1206,7 @@ class Engine:
                 "down": jnp.int32(0),
                 "until_us": jnp.int32(fp.churn_until_us),
                 "last": jnp.zeros((2,), jnp.int32),
-                **{k: jnp.int32(0) for k in CHURN_COUNTER_NAMES},
+                **{k: jnp.int32(0) for k in churn_counter_names(fp.churn)},
             }
 
         return LaneState(
@@ -1460,6 +1520,13 @@ class Engine:
                 a_mask = jnp.arange(nn) == a
                 kill_op = op == F_KILL
                 restart_op = op == F_RESTART
+                churn_restarts = (
+                    cfg.faults.churn is not None and cfg.faults.churn.kind == "kv3a"
+                )
+                if churn_restarts:
+                    # the process's restart of one named node (payload[1]):
+                    # this branch's own restart, so the hook is traced once
+                    restart_op = restart_op | (op == F_CHURN_RESTART)
                 if cfg.faults.allow_torn:
                     # a torn fault is a kill whose restart goes through the
                     # torn_spec() storage contract instead of the model hook
@@ -1504,8 +1571,11 @@ class Engine:
                 # select here (XLA CSEs it inside the fused loop, but eager
                 # step_batch paid ~30% for it, and masked writes are strictly
                 # less work for any backend)
+                plain_restart = op == F_RESTART
+                if churn_restarts:
+                    plain_restart = plain_restart | (op == F_CHURN_RESTART)
                 nodes = m.restart_node_if(
-                    s.nodes, a, op == F_RESTART, k_restart,
+                    s.nodes, a, plain_restart, k_restart,
                     strict=cfg.faults.strict_restart,
                 )
                 if cfg.faults.allow_torn:
@@ -1556,7 +1626,13 @@ class Engine:
             # popped slot below, so the process never holds a second one.
             churn = s.churn
             churn_next = None
-            if cfg.faults.churn is not None:
+            if cfg.faults.churn is not None and cfg.faults.churn.kind == "kv3a":
+                churn, churn_next, churn_prov_bits, clogged, killed = (
+                    self._churn_kv3a(
+                        s, effective, ev_kind, ev_payload, ev_time, clogged, killed
+                    )
+                )
+            elif cfg.faults.churn is not None:
                 cp = cfg.faults.churn
                 nn = s.killed.shape[0]
                 node_ids = jnp.arange(nn)
@@ -2099,6 +2175,108 @@ class Engine:
                 churn=churn,
             )
 
+    def _churn_kv3a(self, s, effective, ev_kind, ev_payload, ev_time,
+                    clogged, killed):
+        """The churn slot's event under `ChurnPlan.kind == "kv3a"` (see
+        ChurnPlan): a tick re-draws the split of the named nodes, the
+        heal clears it and kills them all, a restart (applied by the
+        fault branch) brings one back and re-arms the slot for the
+        next. Returns (churn book, re-arm, provenance bits, clogged,
+        killed)."""
+        cfg = self.config
+        cp = cfg.faults.churn
+        churn = s.churn
+        named = self._churn_nodes
+        named_bits = sum(1 << i for i in named)
+        ids = jnp.arange(s.killed.shape[0])
+        is_named = ((jnp.int32(named_bits) >> ids) & 1) == 1
+        is_churn = effective & (ev_kind == EV_FAULT)
+        op, arg = ev_payload[0], ev_payload[1]
+        is_tick = is_churn & (op == F_CHURN_TICK)
+        is_heal = is_churn & (op == F_CHURN_HEAL)
+        is_restart = is_churn & (op == F_CHURN_RESTART)
+        cw = churn_words(churn["key"], arg + 1)
+
+        # a tick: one coin a named node; row i's links to the named
+        # nodes of the other side are cut, to those of its own side open
+        sides = (cw[0] & jnp.uint32(named_bits)).astype(jnp.int32)
+        on_one = ((sides >> ids) & 1) == 1
+        if cfg.clog_packed:
+            w0 = clogged[:, 0]
+            across = jnp.where(on_one, ~sides & named_bits, sides)
+            w0 = jnp.where(
+                is_named & is_tick, (w0 & ~named_bits) | across,
+                jnp.where(is_named & is_heal, w0 & ~named_bits, w0),
+            )
+            clogged = jnp.stack([w0, clogged[:, 1]], axis=1)
+        else:
+            both = is_named[:, None] & is_named[None, :]
+            clogged = jnp.where(
+                both & is_tick, on_one[:, None] != on_one[None, :],
+                jnp.where(both & is_heal, False, clogged),
+            )
+        # the heal kills every named node (a restart of `arg` — the wipe,
+        # the `killed` bit, the BOOT — is the fault branch's own)
+        killed = killed | (is_named & is_heal)
+
+        def minority(mask):
+            n_one = lax.population_count(mask)
+            return jnp.where(2 * n_one > len(named), ~mask & named_bits, mask)
+
+        cut = jnp.where(is_tick, lax.population_count(minority(sides)), 0)
+        healed = jnp.where(is_heal, lax.population_count(minority(churn["down"])), 0)
+        back_bit = jnp.int32(1) << jnp.clip(arg, 0, ids.shape[0] - 1)
+        churn = dict(
+            churn,
+            # the side-1 set of the split that stands (none after the heal)
+            down=jnp.where(is_tick, sides, jnp.where(is_heal, 0, churn["down"])),
+            # what `differential.applied_churn_faults` reads: a tick's
+            # side-1 set; the set a heal killed, the node a restart raised
+            last=jnp.where(
+                is_tick, jnp.stack([sides, jnp.int32(0)]),
+                jnp.where(
+                    is_heal, jnp.stack([jnp.int32(0), jnp.int32(named_bits)]),
+                    jnp.where(
+                        is_restart, jnp.stack([jnp.int32(0), back_bit]),
+                        churn["last"],
+                    ),
+                ),
+            ),
+            ticks=churn["ticks"] + is_tick.astype(jnp.int32),
+            # nodes on the smaller side of a split, and brought back by the heal
+            disconnects=churn["disconnects"] + cut,
+            reconnects=churn["reconnects"] + healed,
+            partitions=churn["partitions"] + is_tick.astype(jnp.int32),
+            crashes=churn["crashes"] + is_heal.astype(jnp.int32),
+        )
+        # the slot's next event: the next tick or the heal; after the
+        # heal the first restart; after a restart the next node's, at
+        # the same instant, until the last is up
+        t_tick = ev_time + churn_sleep_us(cp, cw)
+        over = t_tick >= churn["until_us"]
+        following = sum(
+            jnp.where(arg == a, b, 0) for a, b in zip(named, named[1:])
+        ) if len(named) > 1 else jnp.int32(0)
+        churn_next = (
+            is_tick | is_heal | (is_restart & (arg != named[-1])),
+            jnp.where(
+                is_tick, jnp.where(over, churn["until_us"], t_tick),
+                jnp.where(is_heal, ev_time + cp.restart_after_us, ev_time),
+            ),
+            jnp.where(
+                is_tick & ~over, F_CHURN_TICK,
+                jnp.where(is_tick, F_CHURN_HEAL, F_CHURN_RESTART),
+            ).astype(jnp.int32),
+            jnp.where(
+                is_tick, arg + 1, jnp.where(is_heal, named[0], following)
+            ).astype(jnp.int32),
+        )
+        prov_bits = jnp.where(
+            is_tick | is_heal, jnp.int32(named_bits),
+            jnp.where(is_restart, back_bit, 0),
+        ).astype(jnp.uint32)
+        return churn, churn_next, prov_bits, clogged, killed
+
     # -- batch runners -------------------------------------------------------
 
     def init_batch(self, seeds: jax.Array) -> LaneState:
@@ -2514,7 +2692,9 @@ class Engine:
                             parts.append(jnp.stack([
                                 # madsim: collective(fr-fold, reduce=sum)
                                 fr_metrics[base + i] + jnp.where(done, book[k], 0).sum()
-                                for i, k in enumerate(CHURN_COUNTER_NAMES)
+                                for i, k in enumerate(
+                                    churn_counter_names(self.config.faults.churn)
+                                )
                             ]))
                     names = self.machine.STREAM_COUNTERS
                     if names:
@@ -3454,16 +3634,29 @@ def _check_lane_spec(machine: Machine) -> None:
     shapes = jax.eval_shape(machine.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
     held = jax.tree.leaves(spec)
     named = jax.tree_util.tree_flatten_with_path(shapes)[0]
-    if len(held) != len(named) or not all(isinstance(h, bool) for h in held):
+    if len(held) != len(named) or not all(
+        isinstance(h, (bool, RoleRows)) for h in held
+    ):
         raise ValueError(
             f"{type(machine).__name__}.lane_spec() must be congruent to "
-            f"init() with a python bool at every leaf"
+            f"init() with a python bool or a RoleRows at every leaf"
         )
     for h, (path, leaf) in zip(held, named):
-        if not h and (leaf.ndim < 1 or leaf.shape[0] != n):
+        where = f"{type(machine).__name__}: leaf {jax.tree_util.keystr(path)}"
+        if isinstance(h, RoleRows):
+            if not (0 <= h.first and h.count >= 1 and h.first + h.count <= n):
+                raise ValueError(f"{where}: {h} names nodes outside 0..{n - 1}")
+            want = (h.count * h.width,) if h.width else None
+            if leaf.ndim < 1 or (
+                leaf.shape != want if want else leaf.shape[0] != h.count
+            ):
+                raise ValueError(
+                    f"{where} has shape {leaf.shape}, not one row for each "
+                    f"of the {h.count} nodes of {h}"
+                )
+        elif not h and (leaf.ndim < 1 or leaf.shape[0] != n):
             raise ValueError(
-                f"{type(machine).__name__}: leaf {jax.tree_util.keystr(path)} "
-                f"has shape {leaf.shape}, no node axis of {n}, and "
+                f"{where} has shape {leaf.shape}, no node axis of {n}, and "
                 f"lane_spec() does not declare it role-held"
             )
 

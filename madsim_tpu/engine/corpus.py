@@ -56,6 +56,10 @@ def config_to_dict(cfg: EngineConfig) -> dict:
     if d["faults"].get("churn") is None:
         d["faults"].pop("churn", None)
         d["faults"].pop("churn_until_us", None)
+    elif d["faults"]["churn"].get("kind") == "fig8":
+        # and a fig8 process without the fields only kind kv3a reads
+        for k in ("kind", "period_us", "jitter_us", "restart_after_us"):
+            d["faults"]["churn"].pop(k, None)
     return d
 
 

@@ -26,7 +26,8 @@ reference: sim/runtime/mod.rs:359-375).
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import dataclasses
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,26 @@ def torn_hash(seed, leaf_idx: int) -> jax.Array:
     h = (h ^ (h >> 16)) * jnp.uint32(_TORN_M1)
     h = (h ^ (h >> 13)) * jnp.uint32(_TORN_M2)
     return h ^ (h >> 16)
+
+
+@dataclasses.dataclass(frozen=True)
+class RoleRows:
+    """A `Machine.lane_spec` leaf value: the leaf is held by a role of
+    SEVERAL nodes — axis 0 has one row for each of the nodes `first ..
+    first + count - 1`, and none for the others (five servers' logs in a
+    lane of ten are `[5, CAP]`, not `[10, CAP]`). Row `i - first` is
+    node i's, so the generic restarts treat it as they treat row i of a
+    leaf with the node axis: by `durable_spec` and `torn_spec`.
+
+    `width` > 0: the rows lie end to end in ONE flat axis, `[count *
+    width]`, node i's the block at `(i - first) * width` — the shape a
+    TPU holds cheaply (a batch of `[5, 5]` tables is tiled to 8 x 128
+    words a lane, a batch of `[25]` to 128). The generic restarts view
+    such a leaf as `[count, width]`."""
+
+    first: int
+    count: int
+    width: int = 0
 
 
 @struct.dataclass
@@ -186,7 +207,10 @@ class Machine:
         `torn_restart_if`) index axis 0 by node and therefore leave a
         role-held leaf alone: what a restart of the holding node does to
         it is the machine's own `restart_lane_if`, which they call
-        (under `--strict-restart` and torn writes too). Everything else
+        (under `--strict-restart` and torn writes too). A leaf may also
+        be a `RoleRows(first, count)`: held by a role of several nodes,
+        one row each — that one the generic restarts DO reach, at row
+        `i - first`, under the same contracts. Everything else
         the engine does with the node tree — the step's write-back, the
         lane freeze, replay, the mesh's lane sharding — is shape-blind;
         provenance words and the digest trail are per node and per
@@ -205,15 +229,28 @@ class Machine:
         node goes through this one hook. Default: nothing is lost."""
         return nodes
 
-    def _map_node_leaves(self, fn, nodes: Any, *rest: Any) -> Any:
-        """`jax.tree.map(fn, nodes, *rest)` over the leaves that have
-        the node axis; role-held leaves (`lane_spec`) pass through."""
+    def _map_node_leaves(self, fn, i, nodes: Any, *rest: Any) -> Any:
+        """`fn(row, cur, *rest)` over the leaves that have a row for
+        node i: `row` is i where the leaf has the node axis and `i -
+        first` where a role of several nodes holds it (`RoleRows`: out
+        of range, so no row, when i is not of the role). Leaves held
+        once a lane (`lane_spec` True) pass through."""
         spec = self.lane_spec()
         if spec is None:
-            return jax.tree.map(fn, nodes, *rest)
-        return jax.tree.map(
-            lambda held, cur, *r: cur if held else fn(cur, *r), spec, nodes, *rest
-        )
+            return jax.tree.map(lambda cur, *r: fn(i, cur, *r), nodes, *rest)
+
+        def one(held, cur, *r):
+            if held is True:
+                return cur
+            if not isinstance(held, RoleRows):
+                return fn(i, cur, *r)
+            if not held.width:
+                return fn(i - held.first, cur, *r)
+            rows = (held.count, held.width)  # the flat leaf, a row a node
+            r = [x.reshape(rows) if hasattr(x, "reshape") else x for x in r]
+            return fn(i - held.first, cur.reshape(rows), *r).reshape(cur.shape)
+
+        return jax.tree.map(one, spec, nodes, *rest)
 
     def _wipe_node_if(self, nodes: Any, i, cond, rng_key) -> Any:
         """Non-virtual building block: copy row i from a fresh init()
@@ -222,7 +259,7 @@ class Machine:
         row i and are left alone."""
         fresh = self.init(rng_key)
         return self._map_node_leaves(
-            lambda cur, f: set_at(cur, i, f, cond), nodes, fresh
+            lambda row, cur, f: set_at(cur, row, f, cond), i, nodes, fresh
         )
 
     def init_node(self, nodes: Any, i, rng_key) -> Any:
@@ -273,8 +310,8 @@ class Machine:
             )
         fresh = self.init(rng_key)
         nodes = self._map_node_leaves(
-            lambda cur, durable, f: cur if durable else set_at(cur, i, f, cond),
-            nodes, spec, fresh,
+            lambda row, cur, durable, f: cur if durable else set_at(cur, row, f, cond),
+            i, nodes, spec, fresh,
         )
         # a role-held leaf's entry in the contract is documentation: the
         # machine's own hook is what wipes it
@@ -319,7 +356,7 @@ class Machine:
         fresh = self.init(rng_key)
         leaf_idx = [0]
 
-        def damage(cur, durable, cls, f):
+        def damage(i, cur, durable, cls, f):  # i: the leaf's row of the node
             li = leaf_idx[0]
             leaf_idx[0] += 1
             if not durable:
@@ -345,7 +382,7 @@ class Machine:
 
         # role-held leaves take no generic damage (the classes tear node
         # rows); their volatile part goes through the machine's hook
-        nodes = self._map_node_leaves(damage, nodes, spec, tspec, fresh)
+        nodes = self._map_node_leaves(damage, i, nodes, spec, tspec, fresh)
         return self.restart_lane_if(nodes, i, cond, rng_key)
 
     def restart_node_if(self, nodes: Any, i, cond, rng_key, strict: bool = False) -> Any:
@@ -404,6 +441,13 @@ class Machine:
         """int32[len(STREAM_COUNTERS)], in that order, read off one
         lane's final state."""
         return jnp.zeros((0,), jnp.int32)
+
+    def churn_nodes(self) -> Optional[Tuple[int, ...]]:
+        """Optional: the nodes a churn process of kind `kv3a` acts on
+        (`ChurnPlan.kind`): the ones it repartitions, kills and
+        restarts — a service's servers, where its clients are nodes of
+        the lane too and reach every server. Default None: all."""
+        return None
 
     def churn_victim(self, nodes: Any, connected):
         """Optional: the node a churn tick disconnects

@@ -254,7 +254,10 @@ def shrink(
     # 3b. the churn process, ended as early as still reproduces. Ending
     #     it just past the failure is sound by construction (the heal
     #     lands after the failing event); below that, bisect: an end at
-    #     `mid` keeps every tick before `mid` as it was and heals there.
+    #     `mid` keeps every tick before `mid` as it was and heals there
+    #     (kind kv3a: heals, kills the named nodes and restarts them —
+    #     the same bisection; a find that needs a split standing, as
+    #     `demo-localget-kvraft`'s, keeps the end just past the failure).
     #     Not monotone in general (a heal can also CAUSE the failing
     #     interleaving), so the bisection is a search order, and only
     #     replays that reproduce the code move `hi`.

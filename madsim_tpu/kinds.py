@@ -42,6 +42,10 @@ FR_EXTRA_NAMES = ("dup", "amnesia")
 # ride the flight recorder's metrics vector after the high-water marks
 # while the process is on.
 FR_CHURN_NAMES = ("ticks", "disconnects", "reconnects")
+# a process of kind `kv3a` keeps two more, after those three: the
+# re-draws of the split, and the kills of every named node at once (one
+# a kill, however many nodes it takes)
+FR_CHURN_KV3A_NAMES = ("partitions", "crashes")
 
 # kind name -> FaultPlan field, in K_* index order.
 KIND_TO_FLAG = (

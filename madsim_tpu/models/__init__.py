@@ -25,11 +25,28 @@ depth).
 `etcd_mvcc` — MVCC etcd server (revisions, txns, leases with ghost
 expiry) + retrying clients; revision-accounting, txn-atomicity,
 lease-expiry-safety and exactly-once invariants.
+`raft_compact` — `raft` with snapshots and log compaction (a torn
+snapshot file is its demo bug).
+`gossip` — 33-node epidemic broadcast with quorum commit, the lane of
+more than 30 nodes.
+`s3` — object store: multipart uploads, versions, expiry, retried
+writes; five demo bugs, one an invariant.
+`kafka` — madsim-rdkafka's whole pipeline: `mq`'s produce path over
+three live partition logs and `kafka_group`'s rebalancing group, the
+broker's state held once a lane (the `kafka_pc5` configuration).
+`kvraft` — MadRaft's lab 3: a key/value service layered on `raft`'s
+handlers (commands in log entries, apply at commit, a session table),
+its clerks as nodes of the same lane, reads checked against what was
+acknowledged (the `kvraft5` configuration, under `--churn kv3a`).
 """
 
-from . import echo, etcd, etcd_mvcc, kafka_group, kv, mq, multipaxos, paxos, raft, twopc
+from . import (
+    echo, etcd, etcd_mvcc, gossip, kafka, kafka_group, kv, kvraft, mq,
+    multipaxos, paxos, raft, raft_compact, s3, twopc,
+)
 
 __all__ = [
-    "echo", "etcd", "etcd_mvcc", "kafka_group", "kv", "mq", "multipaxos",
-    "paxos", "raft", "twopc",
+    "echo", "etcd", "etcd_mvcc", "gossip", "kafka", "kafka_group", "kv",
+    "kvraft", "mq", "multipaxos", "paxos", "raft", "raft_compact", "s3",
+    "twopc",
 ]

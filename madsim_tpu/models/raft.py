@@ -18,6 +18,24 @@ bumps the node's epoch at BOOT so timer chains from a previous
 incarnation die instead of double-arming — the fixed-shape analogue of
 the reference dropping a killed node's timers with its futures
 (madsim/src/sim/task/mod.rs:133-140).
+
+Embedding (`models/kvraft.py`). A machine that layers a service on the
+log builds a `RaftMachine` over the PEERS only — `num_nodes` is the
+size of the peer set, nodes `0 .. num_nodes - 1` of a lane that may
+hold more (clients) — keeps its state `[peers, ...]` as one sub-tree of
+its own, and calls `on_timer` / `on_message` for the events that are
+Raft's. Two class switches serve it, both off here so that `raft` and
+its demo twins trace the program they always traced: `LOG_COMMANDS`
+(an entry is a term AND a command word: `log_cmd`, carried by
+AppendEntries in payload word 6, compared by LogMatching) and
+`CLIENT_TIMER` (False: no leader-side client timer; the embedding
+machine appends through `propose_if`). It learns that `commit` moved
+from the state the handlers return: `commit[node]` against its own
+`last_applied[node]`. An event that is not Raft's is handed over under
+a node index OUTSIDE the peer set (`num_nodes`): every write of these
+handlers is a row mask over the peers, so such a call writes nothing,
+and the embedding machine drops its outbox — no select over the whole
+Raft state is needed to keep it.
 """
 
 from __future__ import annotations
@@ -68,9 +86,26 @@ class RaftState:
     match_idx: jax.Array  # int32[N, N]
 
 
+@struct.dataclass
+class RaftCmdState(RaftState):
+    """`RaftState` of a machine with `LOG_COMMANDS`: a command word
+    beside each entry's term (persistent, as the log is)."""
+
+    log_cmd: jax.Array  # int32[N, CAP+1]; slot 0 unused
+
+
 class RaftMachine(Machine):
     PAYLOAD_WIDTH = 6
     MAX_TIMERS = 2
+
+    # An entry holds a command word beside its term (see "Embedding"
+    # above): state is `RaftCmdState`, AppendEntries carries the word in
+    # payload[6] (so PAYLOAD_WIDTH >= 7), LogMatching compares it too.
+    LOG_COMMANDS = False
+    # The leader-side client timer (T_CLIENT: one entry every 30 ms).
+    # False: nothing appends but `propose_if`, and timer base 3 is the
+    # embedding machine's.
+    CLIENT_TIMER = True
 
     # Follower commit bound on AppendEntries. False (correct, Raft §5.3
     # "index of last new entry"): commit caps at prev_idx(+1 with an
@@ -147,7 +182,7 @@ class RaftMachine(Machine):
     def init(self, rng_key) -> RaftState:
         n, cap = self.NUM_NODES, self.log_capacity
         z = jnp.zeros((n,), jnp.int32)
-        return RaftState(
+        state = RaftState(
             term=z,
             voted_for=jnp.full((n,), -1, jnp.int32),
             log_term=jnp.zeros((n, cap + 1), jnp.int32),
@@ -159,6 +194,18 @@ class RaftMachine(Machine):
             commit=z,
             next_idx=jnp.ones((n, n), jnp.int32),
             match_idx=jnp.zeros((n, n), jnp.int32),
+        )
+        return self._with_cmd(state, jnp.zeros((n, cap + 1), jnp.int32))
+
+    def _with_cmd(self, state: RaftState, log_cmd) -> RaftState:
+        """`state` as this machine holds it: with the command leaf where
+        entries have commands (`log_cmd`: an array, or the leaf's
+        durable / torn class for a spec)."""
+        if not self.LOG_COMMANDS:
+            return state
+        return RaftCmdState(
+            **{f: getattr(state, f) for f in RaftState.__dataclass_fields__},
+            log_cmd=log_cmd,
         )
 
     def init_node(self, nodes: RaftState, i, rng_key) -> RaftState:
@@ -174,7 +221,7 @@ class RaftMachine(Machine):
         this spec is leaf-for-leaf identical to `restart_if` — strict
         ON/OFF is bit-identical for the honest machine (tests assert)."""
         log_durable = not self.PERSIST_COMMIT_NOT_LOG
-        return RaftState(
+        return self._with_cmd(RaftState(
             term=True,
             voted_for=True,
             log_term=log_durable,
@@ -186,7 +233,7 @@ class RaftMachine(Machine):
             commit=bool(self.PERSIST_COMMIT_NOT_LOG),
             next_idx=False,
             match_idx=False,
-        )
+        ), log_durable)
 
     def restart_if(self, nodes: RaftState, i, cond, rng_key) -> RaftState:
         """Masked restart: cond folds into the row mask, so the engine's
@@ -259,7 +306,8 @@ class RaftMachine(Machine):
             elec_deadline=jnp.where(is_boot & live, boot_deadline, nodes.elec_deadline[node]),
         )
         outbox = set_timer_if(outbox, 0, is_boot & live, timeout, self._tid(nodes, node, T_ELECTION))
-        outbox = set_timer_if(outbox, 1, is_boot & live, CLIENT_APPEND_US, self._tid(nodes, node, T_CLIENT))
+        if self.CLIENT_TIMER:
+            outbox = set_timer_if(outbox, 1, is_boot & live, CLIENT_APPEND_US, self._tid(nodes, node, T_CLIENT))
 
         # ---- ELECTION ----
         is_elec = live & (base == T_ELECTION) & ~is_boot
@@ -301,13 +349,23 @@ class RaftMachine(Machine):
             prev_term = nodes.log_term[node, prev_idx]
             has_entry = ni <= nodes.log_len[node]
             entry_term = jnp.where(has_entry, nodes.log_term[node, jnp.minimum(ni, self.log_capacity)], 0)
-            ae = self._pay(M_AE, nodes.term[node], prev_idx, prev_term, entry_term, nodes.commit[node])
-            outbox = send_if(outbox, s, do_hb, peer, ae)
+            ae = (M_AE, nodes.term[node], prev_idx, prev_term, entry_term, nodes.commit[node])
+            if self.LOG_COMMANDS:
+                ae += (nodes.log_cmd[node, jnp.minimum(ni, self.log_capacity)],)
+            outbox = send_if(outbox, s, do_hb, peer, self._pay(*ae))
 
         # ---- CLIENT (leader appends an entry) ----
-        is_client = live & (base == T_CLIENT) & ~is_boot
-        outbox = set_timer_if(outbox, 1, is_client & ~do_hb, CLIENT_APPEND_US, self._tid(nodes, node, T_CLIENT))
-        can_append = is_client & is_leader & (nodes.log_len[node] < self.log_capacity)
+        if self.CLIENT_TIMER:
+            is_client = live & (base == T_CLIENT) & ~is_boot
+            outbox = set_timer_if(outbox, 1, is_client & ~do_hb, CLIENT_APPEND_US, self._tid(nodes, node, T_CLIENT))
+            nodes, _ = self.propose_if(nodes, node, is_client & is_leader)
+        return nodes, outbox
+
+    def propose_if(self, nodes: RaftState, node, want, cmd=0):
+        """Append one entry of the node's current term to its own log
+        where `want` (traced; the caller has checked that the node is
+        the leader) and the log has room. Returns (nodes, appended)."""
+        can_append = want & (nodes.log_len[node] < self.log_capacity)
         new_len = nodes.log_len[node] + 1
         nodes = update_node(
             nodes, node,
@@ -329,7 +387,13 @@ class RaftMachine(Machine):
                 nodes.match_idx,
             )
         )
-        return nodes, outbox
+        if self.LOG_COMMANDS:
+            nodes = nodes.replace(log_cmd=jnp.where(
+                can_append,
+                set2d(nodes.log_cmd, node, jnp.minimum(new_len, self.log_capacity), cmd),
+                nodes.log_cmd,
+            ))
+        return nodes, can_append
 
     # -- messages ------------------------------------------------------------
 
@@ -461,6 +525,10 @@ class RaftMachine(Machine):
                     nodes.commit[node],
                 ),
             )
+            if self.LOG_COMMANDS:
+                nodes = nodes.replace(log_cmd=jnp.where(
+                    append, set2d(nodes.log_cmd, node, slot, payload[6]), nodes.log_cmd
+                ))
             match = jnp.where(has_entry, prev_idx + 1, prev_idx)
             aer = self._pay(M_AER, nodes.term[node], ok.astype(jnp.int32), match)
             outbox = send_if(outbox, 0, jnp.bool_(True), src, aer)
@@ -538,6 +606,10 @@ class RaftMachine(Machine):
         t_min = jnp.min(jnp.where(committed, nodes.log_term, big), axis=0)
         t_max = jnp.max(jnp.where(committed, nodes.log_term, -big), axis=0)
         log_viol = jnp.any(t_max > t_min)
+        if self.LOG_COMMANDS:
+            c_min = jnp.min(jnp.where(committed, nodes.log_cmd, big), axis=0)
+            c_max = jnp.max(jnp.where(committed, nodes.log_cmd, -big), axis=0)
+            log_viol = log_viol | jnp.any(c_max > c_min)
 
         ok = ~(elec_viol | log_viol)
         code = jnp.where(elec_viol, ELECTION_SAFETY, jnp.where(log_viol, LOG_MATCHING, 0))

@@ -19,6 +19,7 @@ if TYPE_CHECKING:
 # bind — via madsim_tpu/kinds.py (pure literals, no jax import), so
 # this host-side decoder can never drift from the device counters.
 from ..kinds import FAULT_KIND_NAMES as FR_FAULT_KINDS
+from ..kinds import FR_CHURN_KV3A_NAMES as FR_CHURN_KV3A
 from ..kinds import FR_CHURN_NAMES as FR_CHURN
 from ..kinds import FR_EXTRA_NAMES as FR_EXTRAS
 
@@ -59,10 +60,12 @@ def fr_metrics_dict(
     nk, ne = len(FR_FAULT_KINDS), len(FR_EXTRAS)
     base = nk + ne + 3
     n_mine = len(machine_counters)
-    if len(v) - n_mine not in (base, base + len(FR_CHURN)):
+    churn_names = FR_CHURN + FR_CHURN_KV3A
+    if len(v) - n_mine not in (base, base + len(FR_CHURN), base + len(churn_names)):
         raise ValueError(
             f"expected {base} metric words (+{len(FR_CHURN)} with a churn "
-            f"process, +{n_mine} of the machine's), got {len(v)}"
+            f"process, +{len(churn_names)} with one of kind kv3a, +{n_mine} "
+            f"of the machine's), got {len(v)}"
         )
     mine, v = v[len(v) - n_mine:], v[:len(v) - n_mine]
     out = {
@@ -75,7 +78,8 @@ def fr_metrics_dict(
     }
     if len(v) > base:
         # FaultPlan.churn: the ticks fired and the faults they applied
-        out["churn"] = dict(zip(FR_CHURN, v[base:]))
+        # (kind kv3a: its two after the three every kind has)
+        out["churn"] = dict(zip(churn_names, v[base:]))
     if machine_counters:
         out["machine"] = dict(zip(machine_counters, mine))
     return out
