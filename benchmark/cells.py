@@ -121,6 +121,31 @@ def load_reader(cell: Cell, metric: str):
     )
 
 
+def checked_configs(traffic: dict) -> list:
+    """The configurations `pool_check.py` ran the file's `slots` under:
+    `slots_checked.config`, one name or a list of names."""
+    config = (traffic.get("slots_checked") or {}).get("config", [])
+    return [config] if isinstance(config, str) else list(config)
+
+
+def unchecked_ranges(cell: Cell) -> str | None:
+    """Why a sweep cell could run a seed range nobody has run whole under
+    its configuration (a lane such a range loses is counted `failed`, so
+    the cell's failed share would move with the program's rate); None
+    where every range it can reach is a checked one."""
+    slots = cell.traffic.get("slots")
+    if not slots or len(slots) < int(cell.traffic["pool"]):
+        return (f"traffic {cell.traffic_name} names no checked seed ranges "
+                f"(`slots`, at least its pool of {cell.traffic['pool']}): run "
+                f"pool_check.py --config {cell.config_name} on its candidates "
+                f"and list the clean ones")
+    if cell.config_name not in checked_configs(cell.traffic):
+        return (f"traffic {cell.traffic_name}'s ranges were checked under "
+                f"{checked_configs(cell.traffic)}, not under {cell.config_name}: "
+                f"a new configuration brings a traffic file checked under it")
+    return None
+
+
 def load_peaks(cell: Cell, device_kind: str) -> dict:
     peaks = load_json(os.path.join(cell.data_root, "peaks.json"))
     if device_kind not in peaks:
@@ -196,6 +221,8 @@ def validate(bench: dict, data_root: str | None = None) -> list:
         except (BenchmarkError, KeyError) as exc:
             bad.append(f"{w['name']}: {exc}")
             continue
+        if cell.kind == "sweep" and (why := unchecked_ranges(cell)):
+            bad.append(f"{w['name']}: {why}")
         reported = {m["name"] for m in cell.end_to_end}
         if "setup_s" not in reported or len(reported) < 2:
             bad.append(f"{w['name']}: reports {sorted(reported)} end to end")

@@ -59,28 +59,40 @@ def campaign_seeds(traffic: dict, seed: int):
     """The one traffic generator: where each campaign of a run starts in
     the seed space. Every --seed runs the same pool of `pool` campaigns,
     in an order drawn from the seed, so runs with different seeds do the
-    same work; past the pool the campaigns go on to fresh seeds, never
-    back to ones this process has already traced and compiled for.
+    same work; past the pool the campaigns go on to fresh seeds. A file
+    without `slots` never comes back to a range: a hunt's replay
+    programs are keyed by the seed shrunk, so a second visit would find
+    compiled what the first one paid for.
 
     Where the traffic file names its ranges (`slots`: slot numbers that
     `pool_check.py` has run whole and found to lose no lane), position k
     is the k-th of that list: the first `pool` of it are the pool, the
     rest the fresh ranges in list order. Past the list's end the
-    campaigns go on with the slot numbers after its last — ranges nobody
-    has checked, so a `benchmark:` line says so."""
+    campaigns go round the fresh ranges again, in list order (round the
+    pool, in this seed's order, where the list is the pool alone): a
+    run never leaves the checked ranges, at whatever rate the program
+    runs, and one `benchmark:` line says when it first wraps. A second
+    visit is the same work as the first: a lane's result depends on its
+    seed alone, a sweep campaign is one CLI call with a carry of its
+    own, and nothing of a sweep is keyed by seed between calls (PR 37
+    on the chip: a range's second, third and fourth visits resolved its
+    first one's seeds in its first one's seconds to 0.02%, PERF.md
+    section 6)."""
     pool = int(traffic["pool"])
     order = list(range(pool))
     random.Random(int(seed)).shuffle(order)
     slots = traffic.get("slots")
+    fresh = len(slots) - pool if slots is not None else 0
     k = 0
     while True:
         slot = order[k] if k < pool else k
-        if slots is not None and slot < len(slots):
+        if slots is not None:
+            if k == len(slots):
+                say(f"benchmark: campaign {k} wraps: the traffic file names "
+                    f"{len(slots)} checked ranges, and a run never leaves them")
+            if k >= len(slots):
+                slot = pool + (k - pool) % fresh if fresh else order[k % pool]
             slot = int(slots[slot])
-        elif slots is not None:  # past the list: the numbers after its last
-            slot = int(slots[-1]) + 1 + slot - len(slots)
-            say(f"benchmark: campaign {k} is past the {len(slots)} checked "
-                f"ranges of the traffic file: slot {slot}, unchecked")
         yield int(traffic["base_seed"]) + slot * int(traffic["stride"])
         k += 1
 

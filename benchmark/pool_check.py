@@ -7,7 +7,9 @@ Does a sweep's traffic file name only seed ranges that lose no lane?
 overflowed the configuration's own Q) or `abandoned` (over --max-steps)
 as a failed operation. A range that holds such a lane makes a cell's
 failed share depend on whether a window reaches it, so a traffic file
-lists (`slots`) only ranges this check has passed. A lane's result
+lists (`slots`) only ranges this check has passed under the cell's
+configuration, and `harness.campaign_seeds` never leaves the list (past
+its end it goes round the fresh ranges again). A lane's result
 depends on its seed alone and is bit-identical on every backend (the
 guarantee `correct` holds every run to), so jax's CPU backend is enough:
 

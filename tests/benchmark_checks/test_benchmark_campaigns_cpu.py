@@ -30,7 +30,8 @@ TINY_MVCC = {
 }
 TINY_SWEEP = {"name": "sweep_tiny", "kind": "sweep", "seeds": 32, "stride": 256,
               "pool": 3, "base_seed": 4096, "warmup_seed": 1024,
-              "trace_campaigns": 1}
+              "trace_campaigns": 1, "slots": [0, 1, 2],
+              "slots_checked": {"config": "raft_tiny", "by": "this test's window"}}
 TINY_HUNT = {"name": "hunt_tiny", "kind": "hunt", "seeds": 32, "stride": 256,
              "limit": 1, "pool": 3, "base_seed": 4096, "warmup_seed": 1024,
              "trace_campaigns": 1}

@@ -2,7 +2,8 @@
 waiting cell `etcd_mvcc4_sweep`, whose files stay in place but which is not
 in BENCHMARK.json (its `seeds_per_s` spread 2.9-4.2% over six runs a side
 on the chip, PERF.md §7). The new cell loads, its flags parse through the
-CLI's own parser, the waiting cell's files would load the day it is listed,
+CLI's own parser, the waiting cell's files would load the day it is listed
+(`validate` asks it for checked seed ranges first, PR 37),
 and a tiny twin of the new configuration runs through the real harness on
 the CPU backend with the two new readers on its line. A time taken here is
 no device number."""
@@ -33,7 +34,11 @@ def _bench_with_waiting_cell() -> dict:
 @pytest.mark.parametrize("name", NEW_CELLS + (WAITING["name"],))
 def test_new_cell_loads_and_its_argv_parses(name):
     bench = _bench_with_waiting_cell()
-    assert cells.validate(bench) == []
+    # the waiting cell's files load, but `sweep_100k` names no checked
+    # ranges (PR 37): the day it is listed it brings a checked file
+    (refusal,) = cells.validate(bench)
+    assert refusal.startswith("etcd_mvcc4_sweep: traffic sweep_100k names no "
+                              "checked seed ranges")
     cell = cells.load_cell(name, bench)
     assert cell.chips == 1 and cell.kind == "sweep"
     assert {m["name"] for m in cell.end_to_end} == {"seeds_per_s", "setup_s"}
@@ -85,7 +90,8 @@ TINY_FIG8 = {
 }
 TINY_SWEEP = {"name": "sweep_tiny", "kind": "sweep", "seeds": 16, "stride": 256,
               "pool": 2, "base_seed": 4096, "warmup_seed": 1024,
-              "trace_campaigns": 1}
+              "trace_campaigns": 1, "slots": [0, 1],
+              "slots_checked": {"config": "fig8_tiny", "by": "this test's window"}}
 
 
 def test_tiny_fig8_cell_through_the_harness_on_the_cpu(tmp_path, monkeypatch,

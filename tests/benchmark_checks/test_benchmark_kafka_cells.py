@@ -3,8 +3,9 @@ consumer pipeline), and the four-chip cell `raft5_sweep_x4` with its traffic
 file `sweep_16k`. The new cell loads, its flags parse through the CLI's own
 parser, a tiny twin of the configuration runs through the real harness on
 the CPU backend with the two new readers on its line, `sweep_16k` names
-`sweep_10k`'s ranges, and BENCHMARK.json validates with one and with two
-new cells. A time taken here is no device number."""
+`sweep_10k`'s ranges, BENCHMARK.json validates with one and with two
+new cells, and `validate` refuses a sweep cell whose traffic file names no
+ranges checked under the cell's configuration (PR 37). A time taken here is no device number."""
 
 import itertools
 import json
@@ -120,12 +121,57 @@ def test_sweep_16k_names_sweep_10ks_checked_ranges():
     assert t16["seeds"] == 16384
     # a campaign consumes ~23.7k seeds of its slot: inside what was checked
     assert t16["slots_checked"]["seeds_per_slot"] == 32768 >= 2 * t16["seeds"]
+    n = len(t10["slots"])
+    assert n >= 96 and t16["slots"][:39] == [s for s in range(42) if s not in (7, 15, 16)]
     for seed in (0, 7, 2**31 + 11):
-        a = list(itertools.islice(harness.campaign_seeds(t16, seed), 39))
-        b = list(itertools.islice(harness.campaign_seeds(t10, seed), 39))
+        a = list(itertools.islice(harness.campaign_seeds(t16, seed), 2 * n))
+        b = list(itertools.islice(harness.campaign_seeds(t10, seed), 2 * n))
         assert a == b
         assert sorted(a[:8]) == [1_000_000 + s * 65536 for s in t10["slots"][:8]]
         assert 1_000_000 + 7 * 65536 not in a  # the overflowing lane's slot
+        assert set(a) == {1_000_000 + s * 65536 for s in t16["slots"]}  # wrapped, not beyond
+
+
+def _doctored_sweep_8k(fault: str) -> dict:
+    t = cells.load_json(os.path.join(cells.DATA_ROOT, "traffic", "sweep_8k.json"))
+    if fault == "no_slots":  # the file as it stood before PR 37
+        del t["slots"], t["slots_checked"]
+    elif fault == "slots_shorter_than_the_pool":
+        t["slots"] = t["slots"][:1]
+    elif fault == "checked_under_other_configurations":
+        t["slots_checked"]["config"] = [
+            c for c in t["slots_checked"]["config"] if c != "kafka_pc5"]
+    elif fault == "checked_under_one_other_configuration":
+        t["slots_checked"]["config"] = "raft5_fig8"
+    return t
+
+
+@pytest.mark.parametrize("fault, refused, words", [
+    ("none", [], ""),
+    ("no_slots", ["raft5_fig8_sweep", "kafka_pc5_sweep", "kvraft5_sweep"],
+     "traffic sweep_8k names no checked seed ranges"),
+    ("slots_shorter_than_the_pool",
+     ["raft5_fig8_sweep", "kafka_pc5_sweep", "kvraft5_sweep"],
+     "traffic sweep_8k names no checked seed ranges"),
+    ("checked_under_other_configurations", ["kafka_pc5_sweep"],
+     "['raft5_fig8', 'kvraft5'], not under kafka_pc5"),
+    ("checked_under_one_other_configuration", ["kafka_pc5_sweep", "kvraft5_sweep"],
+     "['raft5_fig8'], not under k"),
+])
+def test_validate_refuses_a_sweep_cell_without_ranges_checked_under_its_configuration(
+        tmp_path, fault, refused, words):
+    root = tmp_path / "benchmark"
+    for group in ("configs", "traffic", "campaigns", "layer_metrics"):
+        shutil.copytree(os.path.join(cells.DATA_ROOT, group), root / group)
+    (root / "traffic" / "sweep_8k.json").write_text(
+        json.dumps(_doctored_sweep_8k(fault)))
+    bench = cells.load_benchmark()
+    assert cells.validate(bench) == []  # the accepted file, as it stands
+    bad = cells.validate(bench, str(root))
+    assert [b.split(":")[0] for b in bad] == refused
+    assert all(words in b for b in bad)
+    # a hunt's file names no ranges and needs none: it counts no lost lane
+    assert "slots" not in cells.load_cell("etcd_mvcc4_hunt", bench, str(root)).traffic
 
 
 TINY_KAFKA = {
@@ -139,7 +185,8 @@ TINY_KAFKA = {
 }
 TINY_SWEEP = {"name": "sweep_tiny", "kind": "sweep", "seeds": 16, "stride": 256,
               "pool": 2, "base_seed": 4096, "warmup_seed": 1024,
-              "trace_campaigns": 1}
+              "trace_campaigns": 1, "slots": [0, 1],
+              "slots_checked": {"config": "kafka_tiny", "by": "this test's window"}}
 
 
 def test_tiny_kafka_cell_through_the_harness_on_the_cpu(tmp_path, monkeypatch,

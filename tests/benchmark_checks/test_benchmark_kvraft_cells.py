@@ -137,7 +137,8 @@ TINY_KV = {
 }
 TINY_SWEEP = {"name": "sweep_tiny", "kind": "sweep", "seeds": 16, "stride": 256,
               "pool": 2, "base_seed": 4096, "warmup_seed": 1024,
-              "trace_campaigns": 1}
+              "trace_campaigns": 1, "slots": [0, 1],
+              "slots_checked": {"config": "kvraft_tiny", "by": "this test's window"}}
 
 
 def test_tiny_kvraft_cell_through_the_harness_on_the_cpu(tmp_path, monkeypatch,

@@ -1,6 +1,8 @@
 """A sweep's named seed ranges (`slots`): what `harness.campaign_seeds`
-makes of them, that a traffic file without them yields what it always
-did, what `sweep_10k.json` itself promises, and `pool_check.py` — the
+makes of them (past the list's end it goes round the fresh ranges again,
+never to a range nobody checked), that a traffic file without them
+yields what it always did, what `sweep_10k.json` and `sweep_8k.json`
+themselves promise, and `pool_check.py` — the
 check a range has to pass before a traffic file may list it — at a tiny
 size on the CPU backend."""
 
@@ -52,25 +54,26 @@ def first(traffic: dict, seed: int, n: int) -> list:
     return list(itertools.islice(harness.campaign_seeds(traffic, seed), n))
 
 
-def test_named_slots_same_pool_another_order_then_the_list_then_beyond(capsys):
+def test_named_slots_same_pool_another_order_then_the_list_then_round_the_fresh_ranges_again(
+        capsys):
     traffic = {"pool": 3, "base_seed": 1000, "stride": 100, "warmup_seed": 500,
                "slots": [0, 2, 5, 6, 9]}
-    runs = {seed: first(traffic, seed, 8) for seed in SEEDS + (1, 2)}
+    n = 10 * len(traffic["slots"])
+    runs = {seed: first(traffic, seed, n) for seed in SEEDS + (1, 2)}
     said = capsys.readouterr().out.splitlines()
     for r in runs.values():
         assert sorted(r[:3]) == [1000, 1200, 1500]  # the same pool
         assert r[3:5] == [1600, 1900]  # then the fresh ranges, in list order
-        assert r[5:] == [2000, 2100, 2200]  # then the slots after the last
-        assert len(set(r)) == len(r)  # never a repeat
+        assert r[5:] == [1600, 1900] * ((n - 5) // 2) + [1600]  # and again
+        assert set(r) == {1000, 1200, 1500, 1600, 1900}  # never off the list
     assert len({tuple(r[:3]) for r in runs.values()}) > 1  # another order
-    assert runs[7] == first(traffic, 7, 8)
-    # every campaign past the list is announced, and no other
-    assert len(said) == 3 * len(runs)
-    assert all(ln.startswith("benchmark: campaign ") and "unchecked" in ln
-               for ln in said)
-    capsys.readouterr()
-    first(traffic, 0, 5)
+    # ONE announcement a run, whatever its length, and it says what the list held
+    assert len(said) == len(runs)
+    assert all(ln.startswith("benchmark: campaign 5 wraps") and " 5 checked ranges" in ln
+               and "unchecked" not in ln for ln in said)
+    first(traffic, 0, 5)  # none before the wrap
     assert capsys.readouterr().out == ""
+    assert runs[7] == first(traffic, 7, n)
     # the shuffle is the one a file without `slots` gets: `slots` only
     # renames the positions
     plain = {k: v for k, v in traffic.items() if k != "slots"}
@@ -79,43 +82,123 @@ def test_named_slots_same_pool_another_order_then_the_list_then_beyond(capsys):
             == [(s - 1000) // 100 for s in runs[seed][:5]]
 
 
+def test_a_list_that_is_the_pool_alone_wraps_over_the_pool_in_the_seeds_order(capsys):
+    traffic = {"pool": 3, "base_seed": 1000, "stride": 100, "warmup_seed": 500,
+               "slots": [0, 2, 5]}
+    for seed in SEEDS:
+        r = first(traffic, seed, 30)
+        assert sorted(r[:3]) == [1000, 1200, 1500]
+        assert r == r[:3] * 10
+    said = capsys.readouterr().out.splitlines()
+    assert len(said) == len(SEEDS)
+    assert all(ln.startswith("benchmark: campaign 3 wraps") for ln in said)
+
+
+SWEEP_CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]
+               if traffic_file(w["traffic"])["kind"] == "sweep"]
+
+
+@pytest.mark.parametrize("name", SWEEP_CELLS)
+def test_a_sweep_cell_never_leaves_the_ranges_checked_under_its_configuration(
+        name, capsys):
+    cell = cells.load_cell(name)
+    t = cell.traffic
+    assert cells.unchecked_ranges(cell) is None
+    assert cell.config_name in cells.checked_configs(t)
+    named = {t["base_seed"] + s * t["stride"] for s in t["slots"]}
+    length, pool = len(t["slots"]), t["pool"]
+    for seed in SEEDS + (1, 2**32 + 5):
+        r = first(t, seed, 10 * length)
+        assert set(r) == named  # every range named is run, and no other
+        assert sorted(r[:length]) == sorted(named)  # each once before any twice
+        again = itertools.cycle(r[pool:length])  # the fresh ranges, in list order
+        assert r[length:] == list(itertools.islice(again, 9 * length))
+    said = capsys.readouterr().out.splitlines()
+    assert len(said) == len(SEEDS) + 2 and all(f" {length} checked" in ln for ln in said)
+
+
 @pytest.mark.parametrize("name", sorted(GOLDEN))
-def test_a_traffic_file_without_slots_yields_the_parents_sequences(name, capsys):
+def test_the_first_twelve_campaigns_are_the_parents_sequences(name, capsys):
+    """`hunt_highfind` and `sweep_100k` name no ranges and yield what they
+    always did; `sweep_8k` names its own since PR 37, and its first twelve
+    positions are the ones every ledger line was measured on."""
     traffic = traffic_file(name)
-    assert "slots" not in traffic
+    assert ("slots" in traffic) == (name == "sweep_8k")
     for seed, golden in GOLDEN[name].items():
         assert first(traffic, seed, 12) == golden
     assert capsys.readouterr().out == ""
 
 
-def test_sweep_10k_names_checked_disjoint_ranges_and_keeps_seven_of_its_pool():
-    t = traffic_file("sweep_10k")
+def assert_ranges_sound(t: dict, batch: int) -> None:
+    """What every file that names its ranges promises, whatever its sizes."""
     slots, checked = t["slots"], t["slots_checked"]
-    assert len(slots) >= 32 and len(set(slots)) == len(slots)
+    assert len(set(slots)) == len(slots)
     assert all(isinstance(s, int) and s >= 0 for s in slots)
     assert slots == sorted(slots)  # the lowest clean slots, in order
-    dropped = {int(s): lanes for s, lanes in checked["dropped"].items()}
-    assert not set(slots) & set(dropped) and dropped[7] == [1471132]
-    # every slot below the last listed one was either passed or dropped
-    assert set(slots) | set(dropped) >= set(range(slots[-1] + 1))
-    for slot, lanes in dropped.items():
-        start = t["base_seed"] + slot * t["stride"]
-        assert lanes and all(start <= s < start + checked["seeds_per_slot"]
-                             for s in lanes)
     # what was checked is the most a campaign may consume (checks.py's
     # gap rule), ranges do not overlap, sit above the warm-up's and the
     # lane sample's seeds, and fit the engine's uint32
-    batch = cells.load_cell("raft5_sweep").config["flags"]["batch"]
-    assert checked["config"] == "raft5"
     assert checked["seeds_per_slot"] == t["seeds"] + -(-t["seeds"] // batch) * batch
     assert checked["seeds_per_slot"] <= t["stride"]
     assert t["warmup_seed"] + t["stride"] <= t["base_seed"]
     assert t["base_seed"] + (slots[-1] + 1) * t["stride"] < 2**32
+
+
+def dropped_by_slot(t: dict, dropped: dict) -> dict:
+    """{slot: lanes} of a `dropped` table, each lane inside its slot and
+    no dropped slot listed."""
+    dropped = {int(s): lanes for s, lanes in dropped.items()}
+    assert not set(t["slots"]) & set(dropped)
+    # a lane lies in its slot: in the seeds the lanes pass ran, or past
+    # them where pool_check's stream (twice a campaign's budget) reached it
+    for slot, lanes in dropped.items():
+        start = t["base_seed"] + slot * t["stride"]
+        assert lanes and all(start <= s < start + t["stride"] for s in lanes)
+    return dropped
+
+
+def test_sweep_10k_names_checked_disjoint_ranges_and_keeps_seven_of_its_pool():
+    t = traffic_file("sweep_10k")
+    slots, checked = t["slots"], t["slots_checked"]
+    # a 77-campaign window (PR 36 on four chips) and one a quarter faster
+    assert len(slots) >= 96
+    assert_ranges_sound(t, cells.load_cell("raft5_sweep").config["flags"]["batch"])
+    dropped = dropped_by_slot(t, checked["dropped"])
+    assert dropped[7] == [1471132]
+    assert dropped[45] == [3977016] and dropped[48] == [4183821]  # ISSUE 37's leads
+    # every slot below the last listed one was either passed or dropped
+    assert set(slots) | set(dropped) == set(range(slots[-1] + 1))
+    assert checked["config"] == "raft5" and checked["seeds_per_slot"] == 32768
     # seven eighths of the pool's work is what every ledger line measured
     assert t["pool"] == 8 and slots[:7] == list(range(7)) and slots[7] > 7
     pools = {seed: sorted(first(t, seed, 8)) for seed in SEEDS}
     assert len({tuple(p) for p in pools.values()}) == 1
-    assert 1458752 not in first(t, 7, len(slots))  # slot 7 is never run
+    assert 1458752 not in first(t, 7, 3 * len(slots))  # slot 7 is never run
+    # the 39 positions every line to PR 36 could reach are PR 31's
+    assert slots[:39] == [s for s in range(42) if s not in (7, 15, 16)]
+
+
+@pytest.mark.parametrize("config", ["raft5_fig8", "kafka_pc5", "kvraft5"])
+def test_sweep_8k_names_ranges_checked_under_each_configuration_that_sweeps_with_it(
+        config):
+    t = traffic_file("sweep_8k")
+    slots, checked = t["slots"], t["slots_checked"]
+    assert len(slots) >= 12  # 1.5x a window of PR 36's program (5-8 campaigns)
+    assert config in checked["config"] == cells.checked_configs(t)
+    users = {w["config"] for w in cells.load_benchmark()["workloads"]
+             if w["traffic"] == "sweep_8k"}
+    assert users == set(checked["config"])
+    doc = cells.load_json(os.path.join(cells.DATA_ROOT, "configs", config + ".json"))
+    assert_ranges_sound(t, doc["flags"]["batch"])
+    assert checked["seeds_per_slot"] == 16384
+    # dropped: per configuration, the slots that lose a lane under it
+    assert set(checked["dropped"]) == set(checked["config"])
+    dropped_by_slot(t, checked["dropped"][config])
+    lossy = {int(s) for by in checked["dropped"].values() for s in by}
+    assert set(slots) | lossy == set(range(slots[-1] + 1)) and not set(slots) & lossy
+    # the pool is what every ledger line measured: slots 0 and 1
+    assert t["pool"] == 2 and slots[:2] == [0, 1]
+    assert {tuple(sorted(first(t, seed, 2))) for seed in SEEDS} == {(1000000, 1065536)}
 
 
 TIGHT_RAFT = {
