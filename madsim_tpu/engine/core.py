@@ -72,7 +72,7 @@ from ..ops.step_rng import (
 )
 from ..perf import xprof as _xprof
 from ..utils import set2d, tree_where
-from .machine import BOOT, Machine, Outbox, RoleRows
+from .machine import BOOT, Machine, Outbox, RoleRows, get_at
 
 # Event kinds
 EV_TIMER = 0
@@ -1353,18 +1353,18 @@ class Engine:
 
         with _xprof.scope("step.pop"):
             if popped is None:
-                ev_time = s.eq_time[idx]
-                ev_kind = s.eq_kind[idx]
-                ev_node = s.eq_node[idx]
-                ev_src = s.eq_src[idx]
-                ev_payload = s.eq_payload[idx]
+                ev_time = get_at(s.eq_time, idx)
+                ev_kind = get_at(s.eq_kind, idx)
+                ev_node = get_at(s.eq_node, idx)
+                ev_src = get_at(s.eq_src, idx)
+                ev_payload = get_at(s.eq_payload, idx)
             else:
                 ev_time, ev_kind, ev_node, ev_src, ev_payload = popped
 
             if cfg.provenance:
                 # the popped event's lineage word (fault slots carry their
                 # bit from init; messages/timers carry their sender's word)
-                ev_prov = s.eq_prov[idx]
+                ev_prov = get_at(s.eq_prov, idx)
 
             new_now = jnp.maximum(s.now_us, ev_time)
             hz = cfg.horizon_us if horizon_us is None else horizon_us
@@ -1374,7 +1374,7 @@ class Engine:
             live = any_valid if active is None else any_valid & active
             horizon_hit = live & (new_now >= hz)
             process = live & ~horizon_hit
-            node_alive = ~s.killed[ev_node]
+            node_alive = ~get_at(s.killed, ev_node)
             # pause windows: a handler event targeting a paused (alive) node
             # is DEFERRED — the popped slot stays valid and only its time
             # moves to the node's resume point (the state survives, nothing
@@ -1383,7 +1383,7 @@ class Engine:
             # itself is a popped event (trace ring / digest / coverage see
             # it) — host replay pops it identically, so the contract holds.
             if cfg.faults.allow_pause:
-                node_resume_us = s.paused_until[ev_node]
+                node_resume_us = get_at(s.paused_until, ev_node)
                 defer = (
                     process
                     & (ev_kind != EV_FAULT)
@@ -1756,7 +1756,7 @@ class Engine:
                 )
                 # the word every push below inherits (fault events push only
                 # the restart boot timer, whose node is ev_node == a)
-                sender_prov = node_prov[ev_node]
+                sender_prov = get_at(node_prov, ev_node)
             else:
                 node_prov = s.node_prov
                 sender_prov = None
@@ -1790,7 +1790,7 @@ class Engine:
                     # the F_PAUSE apply touched it) folds into the deferred
                     # event's lineage
                     eq["prov"] = jnp.where(
-                        defer_slot, eq["prov"] | s.node_prov[ev_node], eq["prov"]
+                        defer_slot, eq["prov"] | get_at(s.node_prov, ev_node), eq["prov"]
                     )
             # The event's pushes are collected in push order — message 0,
             # (its duplicate), message 1, ..., timers, the restart boot —
@@ -1855,17 +1855,17 @@ class Engine:
             # the handling node's outbound clog row, read ONCE (pre-fault
             # state, matching the unpacked path's s.clogged[ev_node, dst])
             # and expanded to bool[N] so each message pays the same tiny
-            # gather as the bool-matrix path, not a shift/mask per slot
+            # one-hot read as the bool-matrix path, not a shift/mask per slot
             if cfg.clog_packed:
-                clog_row_bool = _clog_row_bools(s.clogged[ev_node], s.killed.shape[0])
+                clog_row_bool = _clog_row_bools(get_at(s.clogged, ev_node), s.killed.shape[0])
 
             for mi in range(m.MAX_MSGS):
                 want = outbox_valid_msgs[mi]
                 dst = outbox.msg_dst[mi]
                 if cfg.clog_packed:
-                    blocked = clog_row_bool[dst]
+                    blocked = get_at(clog_row_bool, dst)
                 else:
-                    blocked = s.clogged[ev_node, dst]
+                    blocked = get_at(s.clogged, (ev_node, dst))
                 if layout.loss_active:
                     blocked = blocked | (drop_bits[mi] < loss_threshold)
                 latency = jnp.int32(cfg.latency_min_us) + (
@@ -1913,7 +1913,7 @@ class Engine:
                 # stretched/compressed by its active q10 factor (read from
                 # the pre-step state — handler events never change skew, and
                 # fault events arm no timers, so pre == post here)
-                node_skew_q10 = s.skew_q10[ev_node]
+                node_skew_q10 = get_at(s.skew_q10, ev_node)
             for ti in range(m.MAX_TIMERS):
                 tpay = jnp.where(slot0, outbox.timer_id[ti], 0).astype(jnp.int32)
                 t_delay = outbox.timer_delay_us[ti]

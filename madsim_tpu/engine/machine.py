@@ -13,7 +13,14 @@ Per-lane calling convention (the engine vmaps over lanes):
     state ONE role of the lane holds (a broker's partition logs), stored
     once a lane, without the node axis
   * handlers receive the whole pytree + a scalar node index and return
-    (new pytree, Outbox); use `update_node` / `.at[i]` scatters
+    (new pytree, Outbox). The index is traced, and so are the indices a
+    message carries: read `table[i]` with `get_at(table, i)` and write
+    it with `set_at` / `update_node`, both one-hot selects over the
+    indexed axis — a plain `table[i]` under the engine's vmap is a
+    gather, and on a TPU each gather of a step is a fusion of its own,
+    ~100 us a step of 8192 lanes whatever the table's size, where the
+    select-reduce is a few and fuses with its neighbours (`PERF.md` §6,
+    PR 38); a `.at[i].set` is a scatter and costs the same
   * Outbox: fixed-width message/timer slots with validity masks — the
     fixed-shape equivalent of the reference's dynamic spawn/send
     (sim/net/mod.rs send path); invalid slots are ignored
@@ -108,9 +115,10 @@ def empty_outbox(max_msgs: int, max_timers: int, payload_width: int) -> Outbox:
     )
 
 
-# All writes below are mask-based `where` selects rather than scatters:
-# scatters with traced indices are hostile to the TPU vectorizer, while a
-# masked select over a small fixed axis is pure VPU work.
+# All reads and writes below are mask-based `where` selects rather than
+# gathers and scatters: those with traced indices are hostile to the TPU
+# vectorizer, while a masked select over a small fixed axis is pure VPU
+# work.
 
 
 def _slot_mask(n: int, slot) -> jax.Array:
@@ -152,6 +160,30 @@ def set_at(arr: jax.Array, i, value, cond=True) -> jax.Array:
     while mask.ndim < arr.ndim:
         mask = mask[..., None]
     return jnp.where(mask, value, arr)
+
+
+def get_at(arr: jax.Array, i) -> jax.Array:
+    """`arr[i]` for traced i — `arr[i, j]` for `i = (i, j)` — as a
+    one-hot select-reduce over the indexed axis: `set_at`'s twin on the
+    read side. Returns what `arr[i]` returns, bit for bit, for an index
+    out of range too (a message carries indices its sender chose): a
+    negative index wraps once, then the index is clamped to the axis.
+    Keeps the leaf's dtype (a `bool` leaf reduces with `any`), reads a
+    row of a `[N, W]` leaf as well as a word, and an index vector reads
+    one row an index. `(i, j)` reads row i and then word j of it, the
+    cheaper of the two one-hot forms on a TPU."""
+    if isinstance(i, tuple):
+        for k in i:
+            arr = get_at(arr, k)
+        return arr
+    n = arr.shape[0]
+    i = jnp.asarray(i).astype(jnp.int32)
+    i = jnp.clip(jnp.where(i < 0, i + n, i), 0, n - 1)
+    hit = jnp.arange(n) == i[..., None]
+    hit = hit.reshape(hit.shape + (1,) * (arr.ndim - 1))
+    if arr.dtype == bool:
+        return jnp.any(hit & arr, axis=i.ndim)
+    return jnp.sum(jnp.where(hit, arr, 0), axis=i.ndim, dtype=arr.dtype)
 
 
 def update_node(nodes: Any, i, **updates) -> Any:

@@ -74,6 +74,7 @@ from flax import struct
 from ..engine.machine import (
     Machine,
     Outbox,
+    get_at,
     make_payload,
     send_if,
     set_timer_if,
@@ -199,19 +200,20 @@ class EtcdMvccMachine(Machine):
     # -- timers (clients only) -------------------------------------------------
 
     def _tid(self, nodes: MvccState, node):
-        return jnp.int32(1) + 2 * nodes.epoch[node]
+        return jnp.int32(1) + 2 * get_at(nodes.epoch, node)
 
     def on_timer(self, nodes: MvccState, node, timer_id, now_us, rand_u32) -> Tuple[MvccState, Outbox]:
         outbox = self.empty_outbox()
         is_boot = timer_id == 0
         t_epoch = (timer_id - 1) // 2
-        live = is_boot | (t_epoch == nodes.epoch[node])
+        epoch = get_at(nodes.epoch, node)
+        live = is_boot | (t_epoch == epoch)
         is_client = node != SERVER
 
-        new_epoch = jnp.where(is_boot & live, nodes.epoch[node] + 1, nodes.epoch[node])
+        new_epoch = jnp.where(is_boot & live, epoch + 1, epoch)
         nodes = update_node(nodes, node, epoch=new_epoch)
 
-        done_c = nodes.acked[node] >= self.target_ops
+        done_c = get_at(nodes.acked, node) >= self.target_ops
         act = live & is_client & ~done_c
 
         # PREMATURE_GIVEUP variant (timeout mishandling): after GIVEUP_US
@@ -221,42 +223,42 @@ class EtcdMvccMachine(Machine):
         give_up = (
             jnp.bool_(self.PREMATURE_GIVEUP)
             & act
-            & (nodes.seq[node] > nodes.acked[node])
-            & (now_us - nodes.issued_at[node] >= GIVEUP_US)
+            & (get_at(nodes.seq, node) > get_at(nodes.acked, node))
+            & (now_us - get_at(nodes.issued_at, node) >= GIVEUP_US)
         )
         nodes = update_node(
             nodes, node,
             abandoned_seq=jnp.where(
-                give_up, nodes.seq[node], nodes.abandoned_seq[node]
+                give_up, get_at(nodes.seq, node), get_at(nodes.abandoned_seq, node)
             ),
         )
 
         # issue the next op once the current one is acked (or abandoned)
-        need_new = act & ((nodes.acked[node] == nodes.seq[node]) | give_up)
-        new_seq = nodes.seq[node] + 1
+        need_new = act & ((get_at(nodes.acked, node) == get_at(nodes.seq, node)) | give_up)
+        new_seq = get_at(nodes.seq, node) + 1
         kind = (rand_u32[0] % jnp.uint32(N_OPS)).astype(jnp.int32)
         ttl = jnp.int32(TTL_MIN_US) + (rand_u32[1] % jnp.uint32(TTL_SPAN_US)).astype(jnp.int32)
-        seq_p = jnp.where(need_new, new_seq, nodes.seq[node])
-        opk_p = jnp.where(need_new, kind, nodes.opk[node])
-        arg_p = jnp.where(need_new, ttl, nodes.oparg[node])
+        seq_p = jnp.where(need_new, new_seq, get_at(nodes.seq, node))
+        opk_p = jnp.where(need_new, kind, get_at(nodes.opk, node))
+        arg_p = jnp.where(need_new, ttl, get_at(nodes.oparg, node))
         own_key = node - 1
         is_put_kind = (opk_p == OP_PUT) | (opk_p == OP_PUT_LEASED)
         puts_sent = jnp.where(
             need_new & is_put_kind,
-            set2d(nodes.puts_sent, node, own_key, nodes.puts_sent[node, own_key] + 1),
+            set2d(nodes.puts_sent, node, own_key, get_at(nodes.puts_sent, (node, own_key)) + 1),
             nodes.puts_sent,
         )
         nodes = nodes.replace(puts_sent=puts_sent)
         nodes = update_node(
             nodes, node, seq=seq_p, opk=opk_p, oparg=arg_p,
-            issued_at=jnp.where(need_new, now_us, nodes.issued_at[node]),
+            issued_at=jnp.where(need_new, now_us, get_at(nodes.issued_at, node)),
         )
 
         # (re)send the in-flight op; re-arm the retry chain. The
         # PREMATURE_GIVEUP variant is a deadline-RPC client: each op is
         # sent exactly once at issue (no retransmits — the deadline,
         # not the retry loop, handles "failure").
-        send = act & (seq_p > nodes.acked[node])
+        send = act & (seq_p > get_at(nodes.acked, node))
         if self.PREMATURE_GIVEUP:
             send = send & need_new
         outbox = send_if(
@@ -283,7 +285,7 @@ class EtcdMvccMachine(Machine):
 
         lease_of_key = nodes.key_lease[SERVER]  # [K], slot+1
         safe_slot = jnp.clip(lease_of_key - 1, 0, self.L - 1)
-        kill = (nodes.ver[SERVER] > 0) & (lease_of_key > 0) & expired[safe_slot]
+        kill = (nodes.ver[SERVER] > 0) & (lease_of_key > 0) & get_at(expired, safe_slot)
         n_del = jnp.sum(kill.astype(jnp.int32))
         new_rev = nodes.rev[SERVER] + n_del
 
@@ -312,7 +314,7 @@ class EtcdMvccMachine(Machine):
         p0 = ks == (K - 2)
         p1 = ks == (K - 1)
         slot = c - 1  # the client's lease slot
-        lease_ok = nodes.lease_used[SERVER, slot] >= 0
+        lease_ok = get_at(nodes.lease_used[SERVER], slot) >= 0
 
         rev0 = nodes.rev[SERVER]
         ver = nodes.ver[SERVER]
@@ -372,7 +374,7 @@ class EtcdMvccMachine(Machine):
         is_ka = (kind == OP_KA) & lease_ok
         ls = jnp.arange(self.L) == slot
         lrow = srow[:, None] & ls[None, :]
-        expire = now_us + jnp.where(is_grant, arg, nodes.lease_ttl[SERVER, slot])
+        expire = now_us + jnp.where(is_grant, arg, get_at(nodes.lease_ttl[SERVER], slot))
         set_used = is_grant | (is_ka & ~jnp.bool_(self.KEEPALIVE_NO_EXTEND))
         set_real = is_grant | is_ka
         nodes = nodes.replace(
@@ -404,17 +406,17 @@ class EtcdMvccMachine(Machine):
             in_window = seq < 128
             word = jnp.clip(seq // 32, 0, 3)
             bit = jnp.int32(1) << jnp.clip(seq % 32, 0, 31)
-            is_dup = in_window & ((swept.applied_bits[src, word] & bit) != 0)
+            is_dup = in_window & ((get_at(swept.applied_bits, (src, word)) & bit) != 0)
         else:
             is_dup = jnp.where(
                 jnp.bool_(self.NO_DEDUP), jnp.bool_(False),
-                seq <= swept.last_req[SERVER, slot],
+                seq <= get_at(swept.last_req[SERVER], slot),
             )
         applied, status = self._apply(swept, src, seq, payload[2], payload[3], now_us)
         applied = applied.replace(
             last_req=set2d(
                 applied.last_req, SERVER, slot,
-                jnp.maximum(applied.last_req[SERVER, slot], seq),
+                jnp.maximum(get_at(applied.last_req[SERVER], slot), seq),
             )
         )
         if self.PREMATURE_GIVEUP:
@@ -432,7 +434,7 @@ class EtcdMvccMachine(Machine):
         # the PREMATURE_GIVEUP safety breach (a compensated-for write
         # becoming visible) — only reachable by a late-but-delivered
         # request, i.e. the delay-spike fault kind
-        late_abandoned = seq <= applied.abandoned_seq[src]
+        late_abandoned = seq <= get_at(applied.abandoned_seq, src)
         applied = applied.replace(
             dirty_abandoned=jnp.where(
                 (jnp.arange(self.NUM_NODES) == SERVER) & late_abandoned,
@@ -456,11 +458,11 @@ class EtcdMvccMachine(Machine):
 
         # ---- client: ACK -------------------------------------------------
         is_ack = (node != SERVER) & (mtype == M_ACK)
+        acked = get_at(nodes.acked, node)
         nodes = update_node(
             nodes, node,
             acked=jnp.where(
-                is_ack, jnp.maximum(nodes.acked[node], jnp.minimum(seq, nodes.seq[node])),
-                nodes.acked[node],
+                is_ack, jnp.maximum(acked, jnp.minimum(seq, get_at(nodes.seq, node))), acked
             ),
         )
         return nodes, outbox
@@ -479,9 +481,12 @@ class EtcdMvccMachine(Machine):
         early = nodes.early_expiry[SERVER]
 
         # server never applied more puts to a client key than issued
-        client_keys = jnp.arange(self.n_clients)
-        sent = nodes.puts_sent[client_keys + 1, client_keys]
-        appl = nodes.puts_applied[SERVER, client_keys]
+        # (client c's own key is c - 1: the diagonal of the clients' rows,
+        # read by a static mask — an index vector would be a gather)
+        nc = self.n_clients
+        own = jnp.eye(nc, dtype=bool)
+        sent = jnp.sum(jnp.where(own, nodes.puts_sent[1:, :nc], 0), axis=1)
+        appl = nodes.puts_applied[SERVER, :nc]
         dup = jnp.any(appl > sent)
 
         live = nodes.ver[SERVER] > 0

@@ -85,7 +85,9 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from ..engine.machine import Machine, Outbox, make_payload, send_if, set_at, set_timer_if, update_node
+from ..engine.machine import (
+    Machine, Outbox, get_at, make_payload, send_if, set_at, set_timer_if, update_node,
+)
 from .kafka_group import (
     COMMIT_REGRESS, HB_US, LOST_RECORD, POLL_US, SESSION_CHECK_US, SESSION_US,
 )
@@ -256,11 +258,11 @@ class KafkaMachine(Machine):
 
     def _accepts(self, nodes: KafkaState, part, producer, seq) -> jax.Array:
         """Idempotence predicate — the line the NoDedup variant removes."""
-        return seq == nodes.expected[part, producer]
+        return seq == get_at(nodes.expected, (part, producer))
 
     def _append(self, nodes: KafkaState, part, producer, seq, do) -> KafkaState:
         cap = self.log_capacity
-        length = nodes.log_len[part]
+        length = get_at(nodes.log_len, part)
         accepts = do & self._accepts(nodes, part, producer, seq)
         room = length < cap
         fresh = accepts & room
@@ -271,7 +273,7 @@ class KafkaMachine(Machine):
         )
         at = (jnp.arange(self.P)[:, None] == part) & (
             jnp.arange(self.NUM_NODES)[None, :] == producer) & fresh
-        in_order = seq == nodes.ghost_next[part, producer]
+        in_order = seq == get_at(nodes.ghost_next, (part, producer))
         nodes = nodes.replace(
             log_producer=jnp.where(cell, producer, nodes.log_producer),
             log_seq=jnp.where(cell, seq, nodes.log_seq),
@@ -305,8 +307,8 @@ class KafkaMachine(Machine):
     def _commit_accepts(self, nodes: KafkaState, src, c_gen, c_part) -> jax.Array:
         """Fencing predicate: current generation, a joined member, the owner."""
         return (
-            (c_gen == nodes.gen) & nodes.joined[src]
-            & (nodes.assign_member[c_part] == src)
+            (c_gen == nodes.gen) & get_at(nodes.joined, src)
+            & (get_at(nodes.assign_member, c_part) == src)
         )
 
     # -- timers ---------------------------------------------------------------
@@ -334,25 +336,29 @@ class KafkaMachine(Machine):
 
         # producer: start the next record when idle, inside the window
         ptick = (timer_id == T_PRODUCE) & is_prod
-        start = ptick & ~nodes.inflight[node] & (now_us < self.produce_until_us)
+        start = ptick & ~get_at(nodes.inflight, node) & (now_us < self.produce_until_us)
         part = (rand_u32[0] % jnp.uint32(self.P)).astype(jnp.int32)
         # the record in flight is sent again RETRY_US after its last send:
         # a retry timer armed for an earlier record fires before the
         # deadline, finds nothing due and dies (`mq.py` resends on it)
         retry = (
-            (timer_id == T_RETRY) & is_prod & nodes.inflight[node]
-            & (now_us >= nodes.retry_at[node])
+            (timer_id == T_RETRY) & is_prod & get_at(nodes.inflight, node)
+            & (now_us >= get_at(nodes.retry_at, node))
         )
         nodes = update_node(
             nodes, node,
-            inflight=nodes.inflight[node] | start,
-            pend_part=jnp.where(start, part, nodes.pend_part[node]),
-            pend_seq=jnp.where(start, nodes.next_seq[node, part], nodes.pend_seq[node]),
-            retry_at=jnp.where(start | retry, now_us + RETRY_US, nodes.retry_at[node]),
+            inflight=get_at(nodes.inflight, node) | start,
+            pend_part=jnp.where(start, part, get_at(nodes.pend_part, node)),
+            pend_seq=jnp.where(
+                start, get_at(nodes.next_seq, (node, part)), get_at(nodes.pend_seq, node)
+            ),
+            retry_at=jnp.where(start | retry, now_us + RETRY_US, get_at(nodes.retry_at, node)),
         )
         nodes = self._count(nodes, produced=start)
         outbox = set_timer_if(outbox, 0, ptick, PRODUCE_US, T_PRODUCE)
-        record = make_payload(w, M_PRODUCE, nodes.pend_part[node], nodes.pend_seq[node])
+        record = make_payload(
+            w, M_PRODUCE, get_at(nodes.pend_part, node), get_at(nodes.pend_seq, node)
+        )
         outbox = send_if(outbox, 0, start | retry, BROKER, record)
         outbox = set_timer_if(outbox, 1, start | retry, RETRY_US, T_RETRY)
 
@@ -363,12 +369,12 @@ class KafkaMachine(Machine):
 
         # member: fetch the next owned partition (round-robin cursor)
         poll = (timer_id == T_POLL) & is_member
-        rr = nodes.poll_rr[node]
-        owned = nodes.my_assign[node]  # bool[P]
+        rr = get_at(nodes.poll_rr, node)
+        owned = get_at(nodes.my_assign, node)  # bool[P]
         order = jnp.mod(rr + jnp.arange(self.P, dtype=jnp.int32), self.P)
-        pick = order[jnp.argmax(owned[order])]
+        pick = get_at(order, jnp.argmax(get_at(owned, order)))
         want = poll & jnp.any(owned)
-        fetch = make_payload(w, M_FETCH, pick, nodes.position[node, pick])
+        fetch = make_payload(w, M_FETCH, pick, get_at(nodes.position, (node, pick)))
         outbox = send_if(outbox, 0, want, BROKER, fetch)
         nodes = update_node(nodes, node, poll_rr=jnp.where(poll, jnp.mod(pick + 1, self.P), rr))
         outbox = set_timer_if(outbox, 0, poll, POLL_US, T_POLL)
@@ -387,20 +393,20 @@ class KafkaMachine(Machine):
         is_produce = is_broker & (mtype == M_PRODUCE)
         r_part, r_seq = jnp.clip(payload[1], 0, p_max), payload[2]
         nodes = self._append(nodes, r_part, src, r_seq, is_produce)
-        ack = make_payload(w, M_ACK, r_part, nodes.expected[r_part, src])
+        ack = make_payload(w, M_ACK, r_part, get_at(nodes.expected, (r_part, src)))
         outbox = send_if(outbox, 0, is_produce, src, ack)
 
         # broker: FETCH -> the range [offset, min(high watermark, offset + 8))
         is_fetch = is_broker & (mtype == M_FETCH)
         f_part, f_off = jnp.clip(payload[1], 0, p_max), payload[2]
-        f_hi = jnp.minimum(nodes.log_len[f_part], f_off + FETCH_MAX)
+        f_hi = jnp.minimum(get_at(nodes.log_len, f_part), f_off + FETCH_MAX)
         have = (f_off >= 0) & (f_hi > f_off)
         resp_f = make_payload(w, M_FETCH_RESP, f_part, f_off, f_hi)
         outbox = send_if(outbox, 0, is_fetch & have, src, resp_f)
 
         # coordinator: heartbeat / join
         hb = is_broker & (mtype == M_HB)
-        new_member = hb & ~nodes.joined[src]
+        new_member = hb & ~get_at(nodes.joined, src)
         nodes = nodes.replace(
             joined=set_at(nodes.joined, src, True, hb),
             last_hb=set_at(nodes.last_hb, src, now_us, hb),
@@ -423,9 +429,9 @@ class KafkaMachine(Machine):
         commit = is_broker & (mtype == M_COMMIT)
         c_gen, c_part, c_off = payload[1], jnp.clip(payload[2], 0, p_max), payload[3]
         accept = commit & self._commit_accepts(nodes, src, c_gen, c_part)
-        same_regime = c_gen == nodes.commit_gen[c_part]
-        apply = accept & (~same_regime | (c_off > nodes.committed[c_part]))
-        regress = apply & (c_off < nodes.committed[c_part])
+        same_regime = c_gen == get_at(nodes.commit_gen, c_part)
+        apply = accept & (~same_regime | (c_off > get_at(nodes.committed, c_part)))
+        regress = apply & (c_off < get_at(nodes.committed, c_part))
         nodes = nodes.replace(
             committed=set_at(nodes.committed, c_part, c_off, apply),
             commit_gen=set_at(nodes.commit_gen, c_part, c_gen, apply),
@@ -437,27 +443,27 @@ class KafkaMachine(Machine):
         is_ack = self._is_producer(node) & (mtype == M_ACK)
         a_part, a_cursor = jnp.clip(payload[1], 0, p_max), payload[2]
         acked = (
-            is_ack & nodes.inflight[node] & (a_part == nodes.pend_part[node])
-            & (a_cursor > nodes.pend_seq[node])
+            is_ack & get_at(nodes.inflight, node) & (a_part == get_at(nodes.pend_part, node))
+            & (a_cursor > get_at(nodes.pend_seq, node))
         )
         nodes = update_node(
             nodes, node,
-            inflight=nodes.inflight[node] & ~acked,
-            next_seq=set_at(nodes.next_seq[node], a_part, a_cursor, acked),
+            inflight=get_at(nodes.inflight, node) & ~acked,
+            next_seq=set_at(get_at(nodes.next_seq, node), a_part, a_cursor, acked),
         )
 
         # member: heartbeat response -> adopt a new generation, resume
         # every owned partition from its committed offset
         hb_resp = is_member & (mtype == M_HB_RESP)
         r_gen, r_mask = payload[1], payload[2]
-        adopt = hb_resp & (r_gen != nodes.m_gen[node])
+        adopt = hb_resp & (r_gen != get_at(nodes.m_gen, node))
         new_assign = ((r_mask >> jnp.arange(self.P, dtype=jnp.int32)) & 1) != 0
         resume = jnp.stack([payload[3 + p] for p in range(self.P)])
         nodes = update_node(
             nodes, node,
-            m_gen=jnp.where(adopt, r_gen, nodes.m_gen[node]),
-            my_assign=jnp.where(adopt, new_assign, nodes.my_assign[node]),
-            position=jnp.where(adopt, resume, nodes.position[node]),
+            m_gen=jnp.where(adopt, r_gen, get_at(nodes.m_gen, node)),
+            my_assign=jnp.where(adopt, new_assign, get_at(nodes.my_assign, node)),
+            position=jnp.where(adopt, resume, get_at(nodes.position, node)),
         )
 
         # member: a fetched range at the position -> consume it (ghost),
@@ -465,19 +471,19 @@ class KafkaMachine(Machine):
         fr = is_member & (mtype == M_FETCH_RESP)
         g_part, g_off, g_hi = jnp.clip(payload[1], 0, p_max), payload[2], payload[3]
         take = (
-            fr & nodes.my_assign[node, g_part] & (g_off == nodes.position[node, g_part])
-            & (g_hi > g_off)
+            fr & get_at(nodes.my_assign, (node, g_part))
+            & (g_off == get_at(nodes.position, (node, g_part))) & (g_hi > g_off)
         )
         offs = jnp.arange(self.log_capacity)[None, :]
         ate = (jnp.arange(self.P)[:, None] == g_part) & (offs >= g_off) & (offs < g_hi) & take
         nodes = nodes.replace(
             consumed=nodes.consumed | ate,
             position=set_at(
-                nodes.position, node, set_at(nodes.position[node], g_part, g_hi, take)
+                nodes.position, node, set_at(get_at(nodes.position, node), g_part, g_hi, take)
             ),
         )
         nodes = self._count(nodes, consumed=jnp.where(take, g_hi - g_off, 0))
-        commit_msg = make_payload(w, M_COMMIT, nodes.m_gen[node], g_part, g_hi)
+        commit_msg = make_payload(w, M_COMMIT, get_at(nodes.m_gen, node), g_part, g_hi)
         outbox = send_if(outbox, 0, take, BROKER, commit_msg)
         return nodes, outbox
 

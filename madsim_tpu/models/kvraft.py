@@ -59,7 +59,7 @@ end, and `clk` `[C * 16]`, a clerk's record — a lane's batch of `[5, 5]`
 tables is tiled to 8 x 128 words a lane on a TPU and a step's cost
 follows the padded bytes (a first form with seven `[5, 5]` tables and
 thirteen `[5]` leaves ran 16.3 ms a step of 8192 lanes, `PERF.md` §6);
-a handler reads a cell's or a record's words one-hot (`pick`) and
+a handler reads a cell's or a record's words one-hot (`get_at`) and
 writes the cell or the record with ONE masked pass. `KvRaftState`'s properties give the tables by name.
 
 Invariants, checked after every event:
@@ -85,7 +85,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..engine.machine import (
-    Machine, Outbox, RoleRows, make_payload, send_if, set_at, set_timer_if,
+    Machine, Outbox, RoleRows, get_at, make_payload, send_if, set_at, set_timer_if,
 )
 from .raft import LEADER, M_AER, RaftCmdState, RaftMachine
 
@@ -132,21 +132,6 @@ def pack_cmd(op, clerk, seq, j):
 
 def unpack_cmd(cmd):
     return cmd & 1, (cmd >> 1) & 0xF, (cmd >> 5) & 0x1FFF, (cmd >> 18) & 0x1FFF
-
-
-def pick(arr, i):
-    """`arr[i]` for a traced `i` as a one-hot select-reduce over the
-    last axis (or the last two, for `i = (row, col)`): on a TPU a lane's
-    read of one word of a small table costs a whole gather fusion, ~100
-    us a step of 8192 lanes, and a masked reduction a few (`PERF.md`
-    §5-§6; a `lax.dynamic_slice` under vmap was 60 ms a step)."""
-    if isinstance(i, tuple):
-        row, col = i
-        hit = (jnp.arange(arr.shape[-2])[:, None] == row) & (
-            jnp.arange(arr.shape[-1])[None, :] == col)
-    else:
-        hit = jnp.arange(arr.shape[-1]) == i
-    return jnp.sum(jnp.where(hit, arr, 0))
 
 
 def hash_step(h, j):
@@ -343,7 +328,7 @@ class KvRaftMachine(Machine):
     def _cell(self, nodes: KvRaftState, srv, c):
         """The eight words of cell (srv, c), each a one-hot read."""
         base = (srv * self.clerks + c) * CELL
-        return jnp.stack([pick(nodes.svc, base + k) for k in range(CELL)])
+        return jnp.stack([get_at(nodes.svc, base + k) for k in range(CELL)])
 
     def _set_cell(self, nodes: KvRaftState, srv, c, cond, cell, fields: dict) -> KvRaftState:
         """Cell (srv, c) <- `cell` with `fields` ({word: value}) replaced,
@@ -360,13 +345,13 @@ class KvRaftMachine(Machine):
         """Apply up to APPLY_PER_EVENT committed entries on server `srv`
         under `do`; answers go out in message slots 1.."""
         cap = self.log_capacity
-        commit = pick(nodes.raft.commit, srv)
-        applied = pick(nodes.last_applied, srv)
+        commit = get_at(nodes.raft.commit, srv)
+        applied = get_at(nodes.last_applied, srv)
         for k in range(APPLY_PER_EVENT):
             idx = applied + 1
             can = do & (idx <= commit)
             at = jnp.minimum(idx, cap)
-            cmd = pick(nodes.raft.log_cmd, (srv, at))
+            cmd = get_at(nodes.raft.log_cmd, (srv, at))
             op, c, seq, j = unpack_cmd(cmd)
             c = jnp.minimum(c, self.clerks - 1)
             cell = self._cell(nodes, srv, c)
@@ -394,7 +379,7 @@ class KvRaftMachine(Machine):
             first = can & (idx > nodes.applied_hi)
             nodes = self._flag(nodes, APPEND_ORDER, grow & (j != old_len))
             nodes = self._flag(
-                nodes, APPLY_DIVERGED, can & ~first & (pick(nodes.ghost_cmd, at) != cmd))
+                nodes, APPLY_DIVERGED, can & ~first & (get_at(nodes.ghost_cmd, at) != cmd))
             nodes = nodes.replace(
                 ghost_cmd=set_at(nodes.ghost_cmd, at, cmd, first),
                 applied_hi=jnp.where(first, idx, nodes.applied_hi),
@@ -419,7 +404,7 @@ class KvRaftMachine(Machine):
         arm = on & (behind > 0) & (~was_behind | chain_fired)
         outbox = set_timer_if(
             outbox, 1, arm, APPLY_DELAY_US,
-            jnp.int32(T_APPLY) + 4 * pick(nodes.raft.epoch, srv),
+            jnp.int32(T_APPLY) + 4 * get_at(nodes.raft.epoch, srv),
         )
         return nodes.replace(
             backlog_hwm=jnp.maximum(nodes.backlog_hwm, jnp.where(on, behind, 0))
@@ -430,7 +415,7 @@ class KvRaftMachine(Machine):
     def _record(self, nodes: KvRaftState, clk):
         """Clerk `clk`'s record as a dict of its fields, each a one-hot read."""
         rec = {
-            name: pick(nodes.clk, clk * REC + i) for i, name in enumerate(CLERK_FIELDS)
+            name: get_at(nodes.clk, clk * REC + i) for i, name in enumerate(CLERK_FIELDS)
         }
         for name in _BOOL_FIELDS:
             rec[name] = rec[name] != 0
@@ -508,10 +493,10 @@ class KvRaftMachine(Machine):
         # a server's timers are Raft's, but for the apply re-arm. A clerk's
         # event goes to Raft under a node outside the peer set: every write
         # of Raft's handlers is a row mask, so it writes nothing
-        was_behind = pick(nodes.raft.commit, srv) > pick(nodes.last_applied, srv)
+        was_behind = get_at(nodes.raft.commit, srv) > get_at(nodes.last_applied, srv)
         chain_fired = (
             is_server & ~is_boot & (timer_id % 4 == T_APPLY)
-            & (timer_id // 4 == pick(nodes.raft.epoch, srv))
+            & (timer_id // 4 == get_at(nodes.raft.epoch, srv))
         )
         raft, r_out = self.raft.on_timer(
             nodes.raft, jnp.where(is_server, node, s), timer_id, now_us, rand_u32)
@@ -556,7 +541,7 @@ class KvRaftMachine(Machine):
 
         # ---- server: Raft's messages (another event: a node outside the
         # peer set, which writes nothing) ----
-        was_behind = pick(nodes.raft.commit, srv) > pick(nodes.last_applied, srv)
+        was_behind = get_at(nodes.raft.commit, srv) > get_at(nodes.last_applied, srv)
         is_raft = is_server & (mtype <= M_AER)
         raft, r_out = self.raft.on_message(
             nodes.raft, jnp.where(is_raft, node, s), src, payload, now_us, rand_u32)
@@ -567,8 +552,8 @@ class KvRaftMachine(Machine):
         c = jnp.clip(src - s, 0, self.clerks - 1)
         seq, op, j = payload[1], payload[2], payload[3]
         cell = self._cell(nodes, srv, c)
-        term = pick(raft.term, srv)
-        leads = is_req & (pick(raft.role, srv) == LEADER)
+        term = get_at(raft.term, srv)
+        leads = is_req & (get_at(raft.role, srv) == LEADER)
         applied = leads & (cell[LAST_SEQ] == seq)
         local = leads & (op == OP_GET) & (not self.GET_THROUGH_LOG)
         held = (cell[PEND_SEQ] == seq) & (cell[PEND_TERM] == term)

@@ -47,7 +47,9 @@ import jax.numpy as jnp
 from flax import struct
 from jax import lax
 
-from ..engine.machine import Machine, Outbox, make_payload, send_if, set_at, set_timer_if, update_node
+from ..engine.machine import (
+    Machine, Outbox, get_at, make_payload, send_if, set_at, set_timer_if, update_node,
+)
 from ..utils import set2d
 
 # roles
@@ -266,7 +268,7 @@ class RaftMachine(Machine):
         return make_payload(self.PAYLOAD_WIDTH, *vals)
 
     def _tid(self, nodes, node, base):
-        return jnp.int32(base) + 4 * nodes.epoch[node]
+        return jnp.int32(base) + 4 * get_at(nodes.epoch, node)
 
     # vote-tally representation (see DUP_VOTE_COUNT): bitmask of voter
     # ids by default, plain counter for the seeded buggy variant
@@ -294,16 +296,19 @@ class RaftMachine(Machine):
         t_epoch = timer_id // 4
         # BOOT (engine-raw id 0) always valid; others require current epoch.
         is_boot = timer_id == T_BOOT
-        live = is_boot | (t_epoch == nodes.epoch[node])
+        epoch = get_at(nodes.epoch, node)
+        live = is_boot | (t_epoch == epoch)
 
         # ---- BOOT: bump epoch, arm election + client timers ----
-        new_epoch = jnp.where(is_boot & live, nodes.epoch[node] + 1, nodes.epoch[node])
+        new_epoch = jnp.where(is_boot & live, epoch + 1, epoch)
         nodes = update_node(nodes, node, epoch=new_epoch)
         timeout = self._rand_timeout(rand_u32[0])
         boot_deadline = now_us + timeout
         nodes = update_node(
             nodes, node,
-            elec_deadline=jnp.where(is_boot & live, boot_deadline, nodes.elec_deadline[node]),
+            elec_deadline=jnp.where(
+                is_boot & live, boot_deadline, get_at(nodes.elec_deadline, node)
+            ),
         )
         outbox = set_timer_if(outbox, 0, is_boot & live, timeout, self._tid(nodes, node, T_ELECTION))
         if self.CLIENT_TIMER:
@@ -311,47 +316,55 @@ class RaftMachine(Machine):
 
         # ---- ELECTION ----
         is_elec = live & (base == T_ELECTION) & ~is_boot
-        not_yet = now_us < nodes.elec_deadline[node]
+        not_yet = now_us < get_at(nodes.elec_deadline, node)
         # re-arm at the postponed deadline (heartbeats push it forward)
-        rearm_delay = jnp.maximum(nodes.elec_deadline[node] - now_us, 1)
+        rearm_delay = jnp.maximum(get_at(nodes.elec_deadline, node) - now_us, 1)
         outbox = set_timer_if(outbox, 0, is_elec & not_yet, rearm_delay, self._tid(nodes, node, T_ELECTION))
 
-        start = is_elec & ~not_yet & (nodes.role[node] != LEADER)
-        new_term = nodes.term[node] + 1
+        start = is_elec & ~not_yet & (get_at(nodes.role, node) != LEADER)
+        new_term = get_at(nodes.term, node) + 1
         timeout2 = self._rand_timeout(rand_u32[1])
         nodes = update_node(
             nodes, node,
-            term=jnp.where(start, new_term, nodes.term[node]),
-            role=jnp.where(start, CANDIDATE, nodes.role[node]),
-            voted_for=jnp.where(start, node, nodes.voted_for[node]),
-            votes=jnp.where(start, self._vote_init(node), nodes.votes[node]),
-            elec_deadline=jnp.where(start, now_us + timeout2, nodes.elec_deadline[node]),
+            term=jnp.where(start, new_term, get_at(nodes.term, node)),
+            role=jnp.where(start, CANDIDATE, get_at(nodes.role, node)),
+            voted_for=jnp.where(start, node, get_at(nodes.voted_for, node)),
+            votes=jnp.where(start, self._vote_init(node), get_at(nodes.votes, node)),
+            elec_deadline=jnp.where(start, now_us + timeout2, get_at(nodes.elec_deadline, node)),
         )
         outbox = set_timer_if(
             outbox, 0, is_elec & ~not_yet, timeout2, self._tid(nodes, node, T_ELECTION)
         )
-        last_idx = nodes.log_len[node]
-        last_term = nodes.log_term[node, last_idx]
-        rv = self._pay(M_RV, nodes.term[node], node, last_idx, last_term)
+        last_idx = get_at(nodes.log_len, node)
+        last_term = get_at(nodes.log_term, (node, last_idx))
+        rv = self._pay(M_RV, get_at(nodes.term, node), node, last_idx, last_term)
         peers = self._peers(node)
         for s in range(self.MAX_MSGS):
             outbox = send_if(outbox, s, start, peers[s], rv)
 
         # ---- HEARTBEAT (leader replicates) ----
         is_hb = live & (base == T_HEARTBEAT) & ~is_boot
-        is_leader = nodes.role[node] == LEADER
+        is_leader = get_at(nodes.role, node) == LEADER
         do_hb = is_hb & is_leader
         outbox = set_timer_if(outbox, 1, do_hb, HEARTBEAT_US, self._tid(nodes, node, T_HEARTBEAT))
+        # the node's rows, read once; each peer's words come from them
+        my_next = get_at(nodes.next_idx, node)  # [N]
+        my_log = get_at(nodes.log_term, node)  # [CAP+1]
+        my_len, my_term = get_at(nodes.log_len, node), get_at(nodes.term, node)
+        my_commit = get_at(nodes.commit, node)
+        if self.LOG_COMMANDS:
+            my_cmds = get_at(nodes.log_cmd, node)
         for s in range(self.MAX_MSGS):
             peer = peers[s]
-            ni = nodes.next_idx[node, peer]
+            ni = get_at(my_next, peer)
             prev_idx = ni - 1
-            prev_term = nodes.log_term[node, prev_idx]
-            has_entry = ni <= nodes.log_len[node]
-            entry_term = jnp.where(has_entry, nodes.log_term[node, jnp.minimum(ni, self.log_capacity)], 0)
-            ae = (M_AE, nodes.term[node], prev_idx, prev_term, entry_term, nodes.commit[node])
+            prev_term = get_at(my_log, prev_idx)
+            has_entry = ni <= my_len
+            at = jnp.minimum(ni, self.log_capacity)
+            entry_term = jnp.where(has_entry, get_at(my_log, at), 0)
+            ae = (M_AE, my_term, prev_idx, prev_term, entry_term, my_commit)
             if self.LOG_COMMANDS:
-                ae += (nodes.log_cmd[node, jnp.minimum(ni, self.log_capacity)],)
+                ae += (get_at(my_cmds, at),)
             outbox = send_if(outbox, s, do_hb, peer, self._pay(*ae))
 
         # ---- CLIENT (leader appends an entry) ----
@@ -365,19 +378,19 @@ class RaftMachine(Machine):
         """Append one entry of the node's current term to its own log
         where `want` (traced; the caller has checked that the node is
         the leader) and the log has room. Returns (nodes, appended)."""
-        can_append = want & (nodes.log_len[node] < self.log_capacity)
-        new_len = nodes.log_len[node] + 1
+        can_append = want & (get_at(nodes.log_len, node) < self.log_capacity)
+        new_len = get_at(nodes.log_len, node) + 1
         nodes = update_node(
             nodes, node,
-            log_len=jnp.where(can_append, new_len, nodes.log_len[node]),
+            log_len=jnp.where(can_append, new_len, get_at(nodes.log_len, node)),
             log_term=jnp.where(
                 can_append,
                 set_at(
-                    nodes.log_term[node],
+                    get_at(nodes.log_term, node),
                     jnp.minimum(new_len, self.log_capacity),
-                    nodes.term[node],
+                    get_at(nodes.term, node),
                 ),
-                nodes.log_term[node],
+                get_at(nodes.log_term, node),
             ),
         )
         nodes = nodes.replace(
@@ -406,26 +419,28 @@ class RaftMachine(Machine):
             outbox = self.empty_outbox()
             t, cand, last_idx, last_term = payload[1], payload[2], payload[3], payload[4]
             # step down on newer term
-            newer = t > nodes.term[node]
+            newer = t > get_at(nodes.term, node)
             nodes = update_node(
                 nodes, node,
-                term=jnp.where(newer, t, nodes.term[node]),
-                role=jnp.where(newer, FOLLOWER, nodes.role[node]),
-                voted_for=jnp.where(newer, -1, nodes.voted_for[node]),
+                term=jnp.where(newer, t, get_at(nodes.term, node)),
+                role=jnp.where(newer, FOLLOWER, get_at(nodes.role, node)),
+                voted_for=jnp.where(newer, -1, get_at(nodes.voted_for, node)),
             )
-            my_last = nodes.log_len[node]
-            my_last_term = nodes.log_term[node, my_last]
+            my_last = get_at(nodes.log_len, node)
+            my_last_term = get_at(nodes.log_term, (node, my_last))
             log_ok = (last_term > my_last_term) | ((last_term == my_last_term) & (last_idx >= my_last))
-            can_vote = (nodes.voted_for[node] == -1) | (nodes.voted_for[node] == cand)
-            grant = (t == nodes.term[node]) & can_vote & log_ok
+            voted_for = get_at(nodes.voted_for, node)
+            can_vote = (voted_for == -1) | (voted_for == cand)
+            grant = (t == get_at(nodes.term, node)) & can_vote & log_ok
             nodes = update_node(
                 nodes, node,
-                voted_for=jnp.where(grant, cand, nodes.voted_for[node]),
+                voted_for=jnp.where(grant, cand, get_at(nodes.voted_for, node)),
                 elec_deadline=jnp.where(
-                    grant, now_us + self._rand_timeout(rand_u32[0]), nodes.elec_deadline[node]
+                    grant, now_us + self._rand_timeout(rand_u32[0]),
+                    get_at(nodes.elec_deadline, node),
                 ),
             )
-            vote = self._pay(M_VOTE, nodes.term[node], grant.astype(jnp.int32))
+            vote = self._pay(M_VOTE, get_at(nodes.term, node), grant.astype(jnp.int32))
             outbox = send_if(outbox, 0, jnp.bool_(True), src, vote)
             return nodes, outbox
 
@@ -433,43 +448,44 @@ class RaftMachine(Machine):
             nodes, = args
             outbox = self.empty_outbox()
             t, granted = payload[1], payload[2]
-            newer = t > nodes.term[node]
+            newer = t > get_at(nodes.term, node)
             nodes = update_node(
                 nodes, node,
-                term=jnp.where(newer, t, nodes.term[node]),
-                role=jnp.where(newer, FOLLOWER, nodes.role[node]),
-                voted_for=jnp.where(newer, -1, nodes.voted_for[node]),
+                term=jnp.where(newer, t, get_at(nodes.term, node)),
+                role=jnp.where(newer, FOLLOWER, get_at(nodes.role, node)),
+                voted_for=jnp.where(newer, -1, get_at(nodes.voted_for, node)),
             )
-            counts = (t == nodes.term[node]) & (nodes.role[node] == CANDIDATE) & (granted == 1)
-            new_votes = self._vote_add(nodes.votes[node], src, counts)
+            role = get_at(nodes.role, node)
+            counts = (t == get_at(nodes.term, node)) & (role == CANDIDATE) & (granted == 1)
+            new_votes = self._vote_add(get_at(nodes.votes, node), src, counts)
             win = (
                 counts
                 & (self._vote_count(new_votes) >= self.majority)
-                & (nodes.role[node] == CANDIDATE)
+                & (role == CANDIDATE)
             )
             n = self.NUM_NODES
-            nodes = update_node(nodes, node, votes=new_votes, role=jnp.where(win, LEADER, nodes.role[node]))
+            nodes = update_node(nodes, node, votes=new_votes, role=jnp.where(win, LEADER, role))
             # leader volatile state
+            last = get_at(nodes.log_len, node)
             nodes = nodes.replace(
                 next_idx=jnp.where(
                     win,
-                    set_at(nodes.next_idx, node, jnp.full((n,), nodes.log_len[node] + 1, jnp.int32)),
+                    set_at(nodes.next_idx, node, jnp.full((n,), last + 1, jnp.int32)),
                     nodes.next_idx,
                 ),
                 match_idx=jnp.where(
                     win,
-                    set_at(
-                        nodes.match_idx, node,
-                        set_at(jnp.zeros((n,), jnp.int32), node, nodes.log_len[node]),
-                    ),
+                    set_at(nodes.match_idx, node, set_at(jnp.zeros((n,), jnp.int32), node, last)),
                     nodes.match_idx,
                 ),
             )
             # announce leadership immediately with heartbeats + arm timer
             peers = self._peers(node)
-            prev_idx = nodes.log_len[node]
-            prev_term = nodes.log_term[node, prev_idx]
-            ae = self._pay(M_AE, nodes.term[node], prev_idx, prev_term, 0, nodes.commit[node])
+            prev_idx = last
+            prev_term = get_at(nodes.log_term, (node, prev_idx))
+            ae = self._pay(
+                M_AE, get_at(nodes.term, node), prev_idx, prev_term, 0, get_at(nodes.commit, node)
+            )
             for s in range(self.MAX_MSGS):
                 outbox = send_if(outbox, s, win, peers[s], ae)
             outbox = set_timer_if(outbox, 0, win, HEARTBEAT_US, self._tid(nodes, node, T_HEARTBEAT))
@@ -481,29 +497,30 @@ class RaftMachine(Machine):
             t, prev_idx, prev_term, entry_term, leader_commit = (
                 payload[1], payload[2], payload[3], payload[4], payload[5],
             )
-            stale = t < nodes.term[node]
-            newer = t > nodes.term[node]
+            stale = t < get_at(nodes.term, node)
+            newer = t > get_at(nodes.term, node)
             nodes = update_node(
                 nodes, node,
-                term=jnp.where(newer, t, nodes.term[node]),
-                role=jnp.where(~stale, FOLLOWER, nodes.role[node]),
-                voted_for=jnp.where(newer, -1, nodes.voted_for[node]),
+                term=jnp.where(newer, t, get_at(nodes.term, node)),
+                role=jnp.where(~stale, FOLLOWER, get_at(nodes.role, node)),
+                voted_for=jnp.where(newer, -1, get_at(nodes.voted_for, node)),
                 elec_deadline=jnp.where(
-                    ~stale, now_us + self._rand_timeout(rand_u32[0]), nodes.elec_deadline[node]
+                    ~stale, now_us + self._rand_timeout(rand_u32[0]),
+                    get_at(nodes.elec_deadline, node),
                 ),
             )
-            log_ok = (prev_idx <= nodes.log_len[node]) & (nodes.log_term[node, prev_idx] == prev_term)
+            my_len = get_at(nodes.log_len, node)
+            my_log = get_at(nodes.log_term, node)  # [CAP+1]
+            log_ok = (prev_idx <= my_len) & (get_at(my_log, prev_idx) == prev_term)
             ok = ~stale & log_ok
             has_entry = entry_term > 0
             slot = jnp.minimum(prev_idx + 1, self.log_capacity)
-            existing_matches = (nodes.log_len[node] >= prev_idx + 1) & (
-                nodes.log_term[node, slot] == entry_term
-            )
+            existing_matches = (my_len >= prev_idx + 1) & (get_at(my_log, slot) == entry_term)
             append = ok & has_entry
             new_len = jnp.where(
                 append,
-                jnp.where(existing_matches, jnp.maximum(nodes.log_len[node], prev_idx + 1), prev_idx + 1),
-                nodes.log_len[node],
+                jnp.where(existing_matches, jnp.maximum(my_len, prev_idx + 1), prev_idx + 1),
+                my_len,
             )
             # Raft §5.3: commit caps at the index of the last entry THIS
             # AE verified (prev_idx, +1 if it carried an entry) — not at
@@ -515,14 +532,12 @@ class RaftMachine(Machine):
             )
             nodes = update_node(
                 nodes, node,
-                log_term=jnp.where(
-                    append, set_at(nodes.log_term[node], slot, entry_term), nodes.log_term[node]
-                ),
+                log_term=jnp.where(append, set_at(my_log, slot, entry_term), my_log),
                 log_len=new_len,
                 commit=jnp.where(
                     ok,
-                    jnp.maximum(nodes.commit[node], jnp.minimum(leader_commit, commit_cap)),
-                    nodes.commit[node],
+                    jnp.maximum(get_at(nodes.commit, node), jnp.minimum(leader_commit, commit_cap)),
+                    get_at(nodes.commit, node),
                 ),
             )
             if self.LOG_COMMANDS:
@@ -530,7 +545,7 @@ class RaftMachine(Machine):
                     append, set2d(nodes.log_cmd, node, slot, payload[6]), nodes.log_cmd
                 ))
             match = jnp.where(has_entry, prev_idx + 1, prev_idx)
-            aer = self._pay(M_AER, nodes.term[node], ok.astype(jnp.int32), match)
+            aer = self._pay(M_AER, get_at(nodes.term, node), ok.astype(jnp.int32), match)
             outbox = send_if(outbox, 0, jnp.bool_(True), src, aer)
             return nodes, outbox
 
@@ -538,16 +553,16 @@ class RaftMachine(Machine):
             nodes, = args
             outbox = self.empty_outbox()
             t, success, midx = payload[1], payload[2], payload[3]
-            newer = t > nodes.term[node]
+            newer = t > get_at(nodes.term, node)
             nodes = update_node(
                 nodes, node,
-                term=jnp.where(newer, t, nodes.term[node]),
-                role=jnp.where(newer, FOLLOWER, nodes.role[node]),
-                voted_for=jnp.where(newer, -1, nodes.voted_for[node]),
+                term=jnp.where(newer, t, get_at(nodes.term, node)),
+                role=jnp.where(newer, FOLLOWER, get_at(nodes.role, node)),
+                voted_for=jnp.where(newer, -1, get_at(nodes.voted_for, node)),
             )
-            is_lead = (nodes.role[node] == LEADER) & (t == nodes.term[node])
+            is_lead = (get_at(nodes.role, node) == LEADER) & (t == get_at(nodes.term, node))
             good = is_lead & (success == 1)
-            new_match = jnp.maximum(nodes.match_idx[node, src], midx)
+            new_match = jnp.maximum(get_at(nodes.match_idx, (node, src)), midx)
             nodes = nodes.replace(
                 match_idx=jnp.where(
                     good, set2d(nodes.match_idx, node, src, new_match), nodes.match_idx
@@ -559,7 +574,7 @@ class RaftMachine(Machine):
                         is_lead & (success == 0),
                         set2d(
                             nodes.next_idx, node, src,
-                            jnp.maximum(nodes.next_idx[node, src] - 1, 1),
+                            jnp.maximum(get_at(nodes.next_idx, (node, src)) - 1, 1),
                         ),
                         nodes.next_idx,
                     ),
@@ -568,17 +583,20 @@ class RaftMachine(Machine):
             # advance commit: highest idx replicated on a majority with
             # an entry from the current term (Raft §5.4.2)
             idxs = jnp.arange(self.log_capacity + 1, dtype=jnp.int32)  # [CAP+1]
-            replicated = nodes.match_idx[node][None, :] >= idxs[:, None]  # [CAP+1, N]
+            replicated = get_at(nodes.match_idx, node)[None, :] >= idxs[:, None]  # [CAP+1, N]
             cnt = jnp.sum(replicated, axis=1)
-            cur_term_entry = nodes.log_term[node] == nodes.term[node]  # [CAP+1]
+            cur_term_entry = get_at(nodes.log_term, node) == get_at(nodes.term, node)  # [CAP+1]
             quorum = self.majority - 1 if self.QUORUM_OFF_BY_ONE else self.majority
             if self.COMMIT_OLD_TERM_BY_COUNT:
                 cur_term_entry = jnp.bool_(True)
-            committable = (cnt >= quorum) & cur_term_entry & (idxs >= 1) & (idxs <= nodes.log_len[node])
+            committable = (
+                (cnt >= quorum) & cur_term_entry & (idxs >= 1)
+                & (idxs <= get_at(nodes.log_len, node))
+            )
             best = jnp.max(jnp.where(committable, idxs, 0))
+            commit = get_at(nodes.commit, node)
             nodes = update_node(
-                nodes, node,
-                commit=jnp.where(good, jnp.maximum(nodes.commit[node], best), nodes.commit[node]),
+                nodes, node, commit=jnp.where(good, jnp.maximum(commit, best), commit),
             )
             return nodes, outbox
 
