@@ -2054,7 +2054,8 @@ def cmd_prof(args) -> int:
 def main(argv=None) -> int:
     """`python -m madsim_tpu <argv>`. Where a recorder is active before
     `main` is entered (the benchmark, an in-process caller), the parse
-    and the process's priming are its `cli_parse` span; a
+    (the build of the one sub-parser the argv names) and the process's
+    priming are its `cli_parse` span; a
     `--perf-timeline` run opens its recorder after the parse, so its
     timeline has no `cli_parse`."""
     from .perf.recorder import maybe_span
@@ -2065,188 +2066,231 @@ def main(argv=None) -> int:
         return args.fn(args)
 
 
-def _parse(argv):
-    """The parsed subcommand, with logging, a multihost job and the
-    persistent compile cache wired as it asks."""
-    parser = argparse.ArgumentParser(prog="madsim_tpu")
-    sub = parser.add_subparsers(dest="cmd", required=True)
+#: every command of the CLI, in the order `-h` lists them: name ->
+#: (`add_parser` keyword arguments, the function that adds its
+#: arguments and its `fn`)
+COMMANDS: dict = {}
 
-    def obs_flags(p):
-        """Observability flags (every subcommand): logging + recorder."""
-        p.add_argument(
-            "--log-level", default=os.environ.get("MADSIM_TPU_LOG"),
-            help="wire init_tracing at this level (DEBUG/INFO/...; also "
-            "$MADSIM_TPU_LOG) — log lines carry the sim span context",
-        )
-        p.add_argument(
-            "--log-jsonl", default=None, metavar="PATH",
-            help="also sink logs as structured JSONL to PATH",
-        )
 
-    def common(p):
-        obs_flags(p)
-        p.add_argument("--machine", default="raft")
-        p.add_argument("--nodes", type=int, default=0)
-        p.add_argument("--seed", type=int, default=0)
-        p.add_argument("--horizon", type=float, default=5.0, help="virtual seconds")
-        p.add_argument("--queue", type=int, default=96)
-        p.add_argument("--faults", type=int, default=2)
-        p.add_argument("--loss", type=float, default=0.0)
-        p.add_argument("--max-steps", type=int, default=3000)
-        p.add_argument(
-            "--fault-tmax", type=int, default=0,
-            help="fault injection window in us (0 = 60%% of horizon)",
-        )
-        p.add_argument(
-            "--fault-kinds", default="pair,kill",
-            help="comma list of fault kinds to draw from: "
-            "pair,kill,dir,group,storm,delay,pause,skew,dup,torn,"
-            "heal-asym (default pair,kill; any other kind switches to "
-            "the v2 schedule derivation; dup is per-delivery Bernoulli "
-            "duplication, not a scheduled window; torn restarts damage "
-            "durable state per Machine.torn_spec(); heal-asym "
-            "partitions heal one direction at a time)",
-        )
-        p.add_argument(
-            "--strict-restart", action="store_true",
-            help="crash-with-amnesia restarts: a restarted node keeps "
-            "ONLY the leaves its Machine.durable_spec() contract marks "
-            "durable — the engine wipes the rest generically, so "
-            "illegally-kept volatile state becomes findable",
-        )
-        p.add_argument(
-            "--churn", default=None, metavar="NAME",
-            help="a fault PROCESS beside the scheduled faults: ticks that "
-            "draw their faults as they fire, one queue slot however many. "
-            "fig8 = 6.824 TestFigure8Unreliable2C's loop: every U[0,13) ms "
-            "(10%% of ticks U[0,500) ms) disconnect the leader w.p. 1/2, "
-            "reconnect a random node while under a majority is connected. "
-            "kv3a = 6.824 lab 3A's partitioner and crash "
-            "(TestPersistPartitionUnreliable3A): every 1 s + U[0,200) ms "
-            "re-draw a random two-way split of the nodes the machine names "
-            "(kvraft: its servers); at --churn-until heal, kill them all at "
-            "once and restart them 150 ms later. Needs --churn-until",
-        )
-        p.add_argument(
-            "--churn-until", type=float, default=None, metavar="S",
-            help="virtual second at which --churn reconnects every node "
-            "and stops (kv3a: and kills and restarts the named nodes)",
-        )
-        p.add_argument(
-            "--log-capacity", type=int, default=None, metavar="N",
-            help="log entries a node of a raft machine can hold (default "
-            "8: a lane ends when every node has committed a full log), or "
-            "records a partition log of a kafka machine, or entries a "
-            "server's log of a kvraft machine, can hold (default 64: a full "
-            "log refuses appends and counts log_full)",
-        )
-        p.add_argument(
-            "--latency", default=None, metavar="MIN_US,MAX_US",
-            help="uniform send latency in virtual us (default 1000,10000)",
-        )
-        p.add_argument(
-            "--rng-stream", type=int, default=2, choices=(2, 3),
-            help="per-step RNG stream version: 2 = legacy split-chain "
-            "(default; replays every recorded seed), 3 = counter-based "
-            "(one threefry per event — faster; new hunts should use it; "
-            "corpus entries record the version either way)",
-        )
-        p.add_argument(
-            "--compile-cache", default=os.environ.get("MADSIM_TPU_COMPILE_CACHE"),
-            help="JAX persistent compilation cache directory (also "
-            "$MADSIM_TPU_COMPILE_CACHE; default <checkout>/"
-            ".madsim-jit-cache): pay each compile once per machine, "
-            "not once per process. $JAX_COMPILATION_CACHE_DIR, where "
-            "set, wins over all of these",
-        )
-        p.add_argument(
-            "--flight-recorder", action="store_true",
-            help="engine flight recorder: rolling per-lane trace digests "
-            "+ checkpoint ring + on-device fault/queue metrics (results "
-            "are bit-identical either way; see `audit`)",
-        )
-        p.add_argument(
-            "--coverage", action="store_true",
-            help="scenario-coverage telemetry: per-lane AFL-style hit "
-            "maps over (model abstract state, event kind, fault "
-            "context), OR-reduced on device at stream harvest (results "
-            "are bit-identical either way; enables --stop-on-plateau "
-            "and `coverage` reports)",
-        )
-        p.add_argument(
-            "--cov-buffer", type=int, default=None, metavar="N",
-            help="coverage slot-buffer depth per lane (default: engine "
-            "default; 0 = unbuffered escape hatch, the per-event map "
-            "scatter — final maps are bit-identical either way)",
-        )
-        p.add_argument(
-            "--provenance", action="store_true",
-            help="causal provenance: every queued event and node "
-            "carries a 32-bit lineage word (one bit per scheduled "
-            "fault, ORed along deliveries); failures decode to the "
-            "implicated faults in hunt reports, shrink uses attribution "
-            "to order its candidates, and `why` renders the causal "
-            "chain (results are bit-identical either way)",
-        )
-        p.add_argument(
-            "--stats", default=None, metavar="BASE",
-            help="StatsEmitter base path (also $MADSIM_TPU_STATS): "
-            "stream per-batch stats to BASE.jsonl + Prometheus textfile "
-            "BASE.prom + latest-snapshot BASE.json (what `serve "
-            "--service stats` exposes)",
-        )
+def _command(name: str, **kwargs):
+    """Register the decorated function as the one that adds the
+    arguments of command `name`."""
 
-    def stream_flags(p):
-        """Streaming-run flags (explore/hunt/perf)."""
-        p.add_argument(
-            "--devices", type=int, default=0, metavar="N",
-            help="span the hunt over the first N devices as one jitted "
-            "SPMD program (a 1-D 'batch' mesh; lane leaves sharded, "
-            "global leaves replicated). Results are byte-identical at "
-            "any N; batch must be a multiple of N. 0 = unsharded "
-            "single-device path (the default). On a CPU-only box, "
-            "force virtual devices with "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=N",
-        )
-        p.add_argument(
-            "--stop-on-plateau", type=int, default=0, metavar="N",
-            help="with --coverage: stop the run early when N consecutive "
-            "seed batches add zero new coverage slots (the saturation "
-            "signal — more seeds are no longer finding new scenarios); "
-            "reported honestly in the summary",
-        )
-        p.add_argument(
-            "--stop-after-batches", type=int, default=0, metavar="N",
-            help="deliberately stop after N seed batches (the run stays "
-            "resumable via --checkpoint; CI's interrupt/resume smoke and "
-            "'hunt in slices' both use this)",
-        )
-        p.add_argument(
-            "--perf-timeline", default=None, metavar="PATH",
-            help="record the HOST wall-clock timeline of this run "
-            "(compile/dispatch/counters_poll/ring_drain/checkpoint/"
-            "stats spans + dispatch-gap idle accounting) as Chrome/"
-            "Perfetto trace_event JSON at PATH, with a bound verdict "
-            "(compile- vs device- vs dispatch-gap-bound) printed after "
-            "the run — the real-time complement of `trace`'s "
-            "virtual-time view",
-        )
-        p.add_argument(
-            "--xla-profile", default=None, metavar="DIR",
-            help="additionally wrap the run in jax.profiler.trace(DIR) "
-            "— a device/XLA-level profile for tensorboard/xprof "
-            "(heavier than --perf-timeline; opt-in)",
-        )
+    def register(add):
+        COMMANDS[name] = (kwargs, add)
+        return add
 
-    p = sub.add_parser("explore", help="run a seed batch, report failing seeds")
-    common(p)
+    return register
+
+
+def _obs_flags(p):
+    """Observability flags (every subcommand): logging + recorder."""
+    p.add_argument(
+        "--log-level", default=os.environ.get("MADSIM_TPU_LOG"),
+        help="wire init_tracing at this level (DEBUG/INFO/...; also "
+        "$MADSIM_TPU_LOG) — log lines carry the sim span context",
+    )
+    p.add_argument(
+        "--log-jsonl", default=None, metavar="PATH",
+        help="also sink logs as structured JSONL to PATH",
+    )
+
+
+def _common_flags(p):
+    """The engine flags of every command that builds one."""
+    _obs_flags(p)
+    p.add_argument("--machine", default="raft")
+    p.add_argument("--nodes", type=int, default=0)
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--horizon", type=float, default=5.0, help="virtual seconds")
+    p.add_argument("--queue", type=int, default=96)
+    p.add_argument("--faults", type=int, default=2)
+    p.add_argument("--loss", type=float, default=0.0)
+    p.add_argument("--max-steps", type=int, default=3000)
+    p.add_argument(
+        "--fault-tmax", type=int, default=0,
+        help="fault injection window in us (0 = 60%% of horizon)",
+    )
+    p.add_argument(
+        "--fault-kinds", default="pair,kill",
+        help="comma list of fault kinds to draw from: "
+        "pair,kill,dir,group,storm,delay,pause,skew,dup,torn,"
+        "heal-asym (default pair,kill; any other kind switches to "
+        "the v2 schedule derivation; dup is per-delivery Bernoulli "
+        "duplication, not a scheduled window; torn restarts damage "
+        "durable state per Machine.torn_spec(); heal-asym "
+        "partitions heal one direction at a time)",
+    )
+    p.add_argument(
+        "--strict-restart", action="store_true",
+        help="crash-with-amnesia restarts: a restarted node keeps "
+        "ONLY the leaves its Machine.durable_spec() contract marks "
+        "durable — the engine wipes the rest generically, so "
+        "illegally-kept volatile state becomes findable",
+    )
+    p.add_argument(
+        "--churn", default=None, metavar="NAME",
+        help="a fault PROCESS beside the scheduled faults: ticks that "
+        "draw their faults as they fire, one queue slot however many. "
+        "fig8 = 6.824 TestFigure8Unreliable2C's loop: every U[0,13) ms "
+        "(10%% of ticks U[0,500) ms) disconnect the leader w.p. 1/2, "
+        "reconnect a random node while under a majority is connected. "
+        "kv3a = 6.824 lab 3A's partitioner and crash "
+        "(TestPersistPartitionUnreliable3A): every 1 s + U[0,200) ms "
+        "re-draw a random two-way split of the nodes the machine names "
+        "(kvraft: its servers); at --churn-until heal, kill them all at "
+        "once and restart them 150 ms later. Needs --churn-until",
+    )
+    p.add_argument(
+        "--churn-until", type=float, default=None, metavar="S",
+        help="virtual second at which --churn reconnects every node "
+        "and stops (kv3a: and kills and restarts the named nodes)",
+    )
+    p.add_argument(
+        "--log-capacity", type=int, default=None, metavar="N",
+        help="log entries a node of a raft machine can hold (default "
+        "8: a lane ends when every node has committed a full log), or "
+        "records a partition log of a kafka machine, or entries a "
+        "server's log of a kvraft machine, can hold (default 64: a full "
+        "log refuses appends and counts log_full)",
+    )
+    p.add_argument(
+        "--latency", default=None, metavar="MIN_US,MAX_US",
+        help="uniform send latency in virtual us (default 1000,10000)",
+    )
+    p.add_argument(
+        "--rng-stream", type=int, default=2, choices=(2, 3),
+        help="per-step RNG stream version: 2 = legacy split-chain "
+        "(default; replays every recorded seed), 3 = counter-based "
+        "(one threefry per event — faster; new hunts should use it; "
+        "corpus entries record the version either way)",
+    )
+    p.add_argument(
+        "--compile-cache", default=os.environ.get("MADSIM_TPU_COMPILE_CACHE"),
+        help="JAX persistent compilation cache directory (also "
+        "$MADSIM_TPU_COMPILE_CACHE; default <checkout>/"
+        ".madsim-jit-cache): pay each compile once per machine, "
+        "not once per process. $JAX_COMPILATION_CACHE_DIR, where "
+        "set, wins over all of these",
+    )
+    p.add_argument(
+        "--flight-recorder", action="store_true",
+        help="engine flight recorder: rolling per-lane trace digests "
+        "+ checkpoint ring + on-device fault/queue metrics (results "
+        "are bit-identical either way; see `audit`)",
+    )
+    p.add_argument(
+        "--coverage", action="store_true",
+        help="scenario-coverage telemetry: per-lane AFL-style hit "
+        "maps over (model abstract state, event kind, fault "
+        "context), OR-reduced on device at stream harvest (results "
+        "are bit-identical either way; enables --stop-on-plateau "
+        "and `coverage` reports)",
+    )
+    p.add_argument(
+        "--cov-buffer", type=int, default=None, metavar="N",
+        help="coverage slot-buffer depth per lane (default: engine "
+        "default; 0 = unbuffered escape hatch, the per-event map "
+        "scatter — final maps are bit-identical either way)",
+    )
+    p.add_argument(
+        "--provenance", action="store_true",
+        help="causal provenance: every queued event and node "
+        "carries a 32-bit lineage word (one bit per scheduled "
+        "fault, ORed along deliveries); failures decode to the "
+        "implicated faults in hunt reports, shrink uses attribution "
+        "to order its candidates, and `why` renders the causal "
+        "chain (results are bit-identical either way)",
+    )
+    p.add_argument(
+        "--stats", default=None, metavar="BASE",
+        help="StatsEmitter base path (also $MADSIM_TPU_STATS): "
+        "stream per-batch stats to BASE.jsonl + Prometheus textfile "
+        "BASE.prom + latest-snapshot BASE.json (what `serve "
+        "--service stats` exposes)",
+    )
+
+
+def _stream_flags(p):
+    """Streaming-run flags (explore/hunt/perf)."""
+    p.add_argument(
+        "--devices", type=int, default=0, metavar="N",
+        help="span the hunt over the first N devices as one jitted "
+        "SPMD program (a 1-D 'batch' mesh; lane leaves sharded, "
+        "global leaves replicated). Results are byte-identical at "
+        "any N; batch must be a multiple of N. 0 = unsharded "
+        "single-device path (the default). On a CPU-only box, "
+        "force virtual devices with "
+        "XLA_FLAGS=--xla_force_host_platform_device_count=N",
+    )
+    p.add_argument(
+        "--stop-on-plateau", type=int, default=0, metavar="N",
+        help="with --coverage: stop the run early when N consecutive "
+        "seed batches add zero new coverage slots (the saturation "
+        "signal — more seeds are no longer finding new scenarios); "
+        "reported honestly in the summary",
+    )
+    p.add_argument(
+        "--stop-after-batches", type=int, default=0, metavar="N",
+        help="deliberately stop after N seed batches (the run stays "
+        "resumable via --checkpoint; CI's interrupt/resume smoke and "
+        "'hunt in slices' both use this)",
+    )
+    p.add_argument(
+        "--perf-timeline", default=None, metavar="PATH",
+        help="record the HOST wall-clock timeline of this run "
+        "(compile/dispatch/counters_poll/ring_drain/checkpoint/"
+        "stats spans + dispatch-gap idle accounting) as Chrome/"
+        "Perfetto trace_event JSON at PATH, with a bound verdict "
+        "(compile- vs device- vs dispatch-gap-bound) printed after "
+        "the run — the real-time complement of `trace`'s "
+        "virtual-time view",
+    )
+    p.add_argument(
+        "--xla-profile", default=None, metavar="DIR",
+        help="additionally wrap the run in jax.profiler.trace(DIR) "
+        "— a device/XLA-level profile for tensorboard/xprof "
+        "(heavier than --perf-timeline; opt-in)",
+    )
+
+
+def _fleet_root(q):
+    q.add_argument(
+        "--root", default=os.environ.get("MADSIM_TPU_FLEET_ROOT", "fleet"),
+        help="fleet state directory (jobs/, corpus.json; also "
+        "$MADSIM_TPU_FLEET_ROOT)",
+    )
+
+
+def _fleet_client_flags(q):
+    q.add_argument(
+        "--addr", default=None,
+        help="control-plane host:port (default $MADSIM_TPU_FLEET_ADDR "
+        "or 127.0.0.1:8142)",
+    )
+    q.add_argument(
+        "--port-file", default=None, metavar="PATH",
+        help="resolve the daemon as 127.0.0.1:<port read from PATH> "
+        "(the file `fleet serve --port-file` writes atomically)",
+    )
+    q.add_argument(
+        "--no-retry", action="store_true",
+        help="fail fast instead of retrying transient HTTP errors "
+        "(connection refused during a server restart, 502/503/504) "
+        "with seeded-jitter backoff",
+    )
+
+
+@_command("explore", help="run a seed batch, report failing seeds")
+def _explore_args(p):
+    _common_flags(p)
     p.add_argument("--seeds", type=int, default=1024)
     p.add_argument(
         "--stream", action="store_true",
         help="seed-streaming path (refill finished lanes; for large batches)",
     )
     p.add_argument("--batch", type=int, default=8192, help="lanes per streaming batch")
-    stream_flags(p)
+    _stream_flags(p)
     p.add_argument(
         "--multihost", action="store_true",
         help="shard the batch over a jax.distributed job "
@@ -2254,8 +2298,10 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_explore)
 
-    p = sub.add_parser("replay", help="bit-identical replay of one seed with trace")
-    common(p)
+
+@_command("replay", help="bit-identical replay of one seed with trace")
+def _replay_args(p):
+    _common_flags(p)
     p.add_argument("--tail", type=int, default=30, help="print last N events (0=all)")
     p.add_argument(
         "--devices", type=int, default=0,
@@ -2273,12 +2319,14 @@ def _parse(argv):
                    help="events of context around the divergence")
     p.set_defaults(fn=cmd_replay)
 
-    p = sub.add_parser(
-        "trace",
-        help="replay one seed and export its virtual-time event timeline "
-        "(Perfetto trace_event JSON / structured JSONL)",
-    )
-    common(p)
+
+@_command(
+    "trace",
+    help="replay one seed and export its virtual-time event timeline "
+    "(Perfetto trace_event JSON / structured JSONL)",
+)
+def _trace_args(p):
+    _common_flags(p)
     p.add_argument(
         "--perfetto", default=None, metavar="PATH",
         help="write Chrome/Perfetto trace_event JSON (one thread row per "
@@ -2290,17 +2338,21 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_trace)
 
-    p = sub.add_parser("shrink", help="minimize a failing seed's config")
-    common(p)
+
+@_command("shrink", help="minimize a failing seed's config")
+def _shrink_args(p):
+    _common_flags(p)
     p.set_defaults(fn=cmd_shrink)
 
-    p = sub.add_parser(
-        "why",
-        help="explain a failing seed: replay with causal provenance, "
-        "name the implicated faults (kind, time, target), and render "
-        "the violation's past cone as text / JSON / Perfetto flows",
-    )
-    common(p)
+
+@_command(
+    "why",
+    help="explain a failing seed: replay with causal provenance, "
+    "name the implicated faults (kind, time, target), and render "
+    "the violation's past cone as text / JSON / Perfetto flows",
+)
+def _why_args(p):
+    _common_flags(p)
     p.add_argument(
         "seed_pos", nargs="?", type=int, default=None, metavar="SEED",
         help="the failing seed (equivalent to --seed; pass the repro "
@@ -2326,14 +2378,16 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_why)
 
-    p = sub.add_parser(
-        "hunt", help="explore + shrink + record failing seeds in the corpus"
-    )
-    common(p)
+
+@_command(
+    "hunt", help="explore + shrink + record failing seeds in the corpus"
+)
+def _hunt_args(p):
+    _common_flags(p)
     p.add_argument("--seeds", type=int, default=1024)
     p.add_argument("--stream", action="store_true", help="seed-streaming hunt")
     p.add_argument("--batch", type=int, default=8192, help="lanes per streaming batch")
-    stream_flags(p)
+    _stream_flags(p)
     p.add_argument("--corpus", default="corpus.json")
     p.add_argument("--limit", type=int, default=5, help="max seeds to shrink+record")
     p.add_argument(
@@ -2371,11 +2425,13 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_hunt)
 
-    p = sub.add_parser(
-        "regress",
-        help="re-verify every corpus entry (open must reproduce, fixed must pass)",
-    )
-    obs_flags(p)
+
+@_command(
+    "regress",
+    help="re-verify every corpus entry (open must reproduce, fixed must pass)",
+)
+def _regress_args(p):
+    _obs_flags(p)
     p.add_argument("--corpus", default="corpus.json")
     p.add_argument(
         "--promote", action="store_true",
@@ -2383,13 +2439,15 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_regress)
 
-    p = sub.add_parser(
-        "audit",
-        help="bisect every corpus entry's recorded digest trail to the "
-        "first divergent checkpoint (corpus-rot diagnosis); --record "
-        "re-records trails + env metadata at HEAD",
-    )
-    obs_flags(p)
+
+@_command(
+    "audit",
+    help="bisect every corpus entry's recorded digest trail to the "
+    "first divergent checkpoint (corpus-rot diagnosis); --record "
+    "re-records trails + env metadata at HEAD",
+)
+def _audit_args(p):
+    _obs_flags(p)
     p.add_argument("--corpus", default="corpus.json")
     p.add_argument(
         "--record", action="store_true",
@@ -2402,35 +2460,41 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_audit)
 
-    p = sub.add_parser("check", help="engine determinism self-check")
-    common(p)
+
+@_command("check", help="engine determinism self-check")
+def _check_args(p):
+    _common_flags(p)
     p.add_argument("--seeds", type=int, default=64)
     p.set_defaults(fn=cmd_check)
 
-    p = sub.add_parser(
-        "perf",
-        help="host wall-clock observatory: stream a workload with the "
-        "PerfRecorder on and write the Perfetto host timeline "
-        "(compile/dispatch/poll/drain spans + dispatch-gap idle), with "
-        "a compile- vs device- vs dispatch-gap-bound verdict",
-    )
-    common(p)
+
+@_command(
+    "perf",
+    help="host wall-clock observatory: stream a workload with the "
+    "PerfRecorder on and write the Perfetto host timeline "
+    "(compile/dispatch/poll/drain spans + dispatch-gap idle), with "
+    "a compile- vs device- vs dispatch-gap-bound verdict",
+)
+def _perf_args(p):
+    _common_flags(p)
     p.add_argument("out", help="host-timeline Perfetto JSON output path")
     p.add_argument("--seeds", type=int, default=2048)
     p.add_argument("--batch", type=int, default=512, help="lanes per streaming batch")
-    stream_flags(p)
+    _stream_flags(p)
     p.set_defaults(fn=cmd_perf)
 
-    p = sub.add_parser(
-        "prof",
-        help="the three-clock profiler: stream a hunt batch with "
-        "device-phase annotations + a jax.profiler capture on "
-        "(MADSIM_TPU_XPROF), and with --merge align host spans, the "
-        "device profile and a failing lane's virtual-time trace into "
-        "one Perfetto session; `prof compile` prints the per-stage "
-        "compile autopsy (trace/lower/backend + flops/bytes)",
-    )
-    common(p)
+
+@_command(
+    "prof",
+    help="the three-clock profiler: stream a hunt batch with "
+    "device-phase annotations + a jax.profiler capture on "
+    "(MADSIM_TPU_XPROF), and with --merge align host spans, the "
+    "device profile and a failing lane's virtual-time trace into "
+    "one Perfetto session; `prof compile` prints the per-stage "
+    "compile autopsy (trace/lower/backend + flops/bytes)",
+)
+def _prof_args(p):
+    _common_flags(p)
     p.add_argument(
         "action", nargs="?", choices=("compile",), default=None,
         help="compile: autopsy the streaming quartet's compile at this "
@@ -2458,15 +2522,17 @@ def _parse(argv):
         help="keep the raw jax.profiler logdir here (default: a "
         "throwaway tempdir)",
     )
-    stream_flags(p)
+    _stream_flags(p)
     p.set_defaults(fn=cmd_prof)
 
-    p = sub.add_parser(
-        "coverage",
-        help="render a persisted scenario-coverage map (total %%, "
-        "per-band marginals, thinnest fault x phase cells, per-model "
-        "breakdown); --diff OLD shows what a run added over another",
-    )
+
+@_command(
+    "coverage",
+    help="render a persisted scenario-coverage map (total %%, "
+    "per-band marginals, thinnest fault x phase cells, per-model "
+    "breakdown); --diff OLD shows what a run added over another",
+)
+def _coverage_args(p):
     p.add_argument("doc", help="coverage JSON written by `hunt --coverage-out`")
     p.add_argument(
         "--diff", default=None, metavar="OLD",
@@ -2483,13 +2549,15 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_coverage)
 
-    p = sub.add_parser(
-        "serve",
-        help="run an L5 service over real TCP (MADSIM_TPU_MODE=real); "
-        "pickle wire format — trusted networks only. `--service stats` "
-        "serves the last run's StatsEmitter snapshot over HTTP instead "
-        "(/stats JSON + /metrics Prometheus; any mode)",
-    )
+
+@_command(
+    "serve",
+    help="run an L5 service over real TCP (MADSIM_TPU_MODE=real); "
+    "pickle wire format — trusted networks only. `--service stats` "
+    "serves the last run's StatsEmitter snapshot over HTTP instead "
+    "(/stats JSON + /metrics Prometheus; any mode)",
+)
+def _serve_args(p):
     p.add_argument("--service", default="etcd",
                    choices=["etcd", "kafka", "s3", "stats"])
     p.add_argument("--addr", default="127.0.0.1:23790", help="host:port (port 0 = ephemeral)")
@@ -2532,48 +2600,25 @@ def _parse(argv):
     )
     p.set_defaults(fn=cmd_serve)
 
-    p = sub.add_parser(
-        "fleet",
-        help="the hunt farm: DST as a continuously operating service — "
-        "a durable job store + queue (JSON-on-disk, atomic, "
-        "fingerprinted), `worker` (leases jobs, runs checkpointed "
-        "batch units packed by warm-compile subkey, shrinks + files "
-        "finds), `serve` (jax-free HTTP control plane: POST /jobs, "
-        "GET /jobs/{id}[/result|/events|/timeline], DELETE /jobs/{id}, "
-        "/queue /metrics /healthz) and thin client verbs, including "
-        "the observatory (`watch` SSE tail, `timeline` Perfetto "
-        "merge, `top` farm view)",
-    )
+
+@_command(
+    "fleet",
+    help="the hunt farm: DST as a continuously operating service — "
+    "a durable job store + queue (JSON-on-disk, atomic, "
+    "fingerprinted), `worker` (leases jobs, runs checkpointed "
+    "batch units packed by warm-compile subkey, shrinks + files "
+    "finds), `serve` (jax-free HTTP control plane: POST /jobs, "
+    "GET /jobs/{id}[/result|/events|/timeline], DELETE /jobs/{id}, "
+    "/queue /metrics /healthz) and thin client verbs, including "
+    "the observatory (`watch` SSE tail, `timeline` Perfetto "
+    "merge, `top` farm view)",
+)
+def _fleet_args(p):
     fl = p.add_subparsers(dest="fleet_cmd", required=True)
 
-    def fleet_root(q):
-        q.add_argument(
-            "--root", default=os.environ.get("MADSIM_TPU_FLEET_ROOT", "fleet"),
-            help="fleet state directory (jobs/, corpus.json; also "
-            "$MADSIM_TPU_FLEET_ROOT)",
-        )
-
-    def fleet_client_flags(q):
-        q.add_argument(
-            "--addr", default=None,
-            help="control-plane host:port (default $MADSIM_TPU_FLEET_ADDR "
-            "or 127.0.0.1:8142)",
-        )
-        q.add_argument(
-            "--port-file", default=None, metavar="PATH",
-            help="resolve the daemon as 127.0.0.1:<port read from PATH> "
-            "(the file `fleet serve --port-file` writes atomically)",
-        )
-        q.add_argument(
-            "--no-retry", action="store_true",
-            help="fail fast instead of retrying transient HTTP errors "
-            "(connection refused during a server restart, 502/503/504) "
-            "with seeded-jitter backoff",
-        )
-
     q = fl.add_parser("serve", help="jax-free HTTP control plane over a fleet root")
-    obs_flags(q)
-    fleet_root(q)
+    _obs_flags(q)
+    _fleet_root(q)
     q.add_argument("--addr", default="127.0.0.1:8142",
                    help="bind host:port (port 0 = ephemeral)")
     q.add_argument(
@@ -2594,8 +2639,8 @@ def _parse(argv):
         "time (kill -9 loses at most one batch; jobs sharing a "
         "cache_subkey run back-to-back on the warm jit)",
     )
-    obs_flags(q)
-    fleet_root(q)
+    _obs_flags(q)
+    _fleet_root(q)
     q.add_argument("--worker-id", default=None,
                    help="stable lease identity (default w<pid>; reusing an "
                    "id reclaims its own leases immediately after a crash)")
@@ -2647,8 +2692,8 @@ def _parse(argv):
     q.set_defaults(fn=cmd_fleet)
 
     q = fl.add_parser("submit", help="submit a hunt job; prints the job id")
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.add_argument("--machine", required=True)
     q.add_argument("--nodes", type=int, default=0)
     q.add_argument("--seed", type=int, default=0, help="seed-range start")
@@ -2710,8 +2755,8 @@ def _parse(argv):
                    "unit boundary)"),
     ):
         q = fl.add_parser(verb, help=hlp)
-        obs_flags(q)
-        fleet_client_flags(q)
+        _obs_flags(q)
+        _fleet_client_flags(q)
         q.add_argument("job", help="job id (from `fleet submit`)")
         if verb == "status":
             q.add_argument("--feed", type=int, default=20,
@@ -2726,8 +2771,8 @@ def _parse(argv):
         q.set_defaults(fn=cmd_fleet)
 
     q = fl.add_parser("queue", help="state counts + per-job summaries")
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.set_defaults(fn=cmd_fleet)
 
     q = fl.add_parser(
@@ -2736,8 +2781,8 @@ def _parse(argv):
         "poll: the server parks between events), one line per event; "
         "exits 0 when the job reaches a terminal state",
     )
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.add_argument("job", help="job id (from `fleet submit`)")
     q.add_argument("--since", type=int, default=0, metavar="SEQ",
                    help="resume the tail after event SEQ (0 = replay "
@@ -2751,8 +2796,8 @@ def _parse(argv):
         "Perfetto timeline: queue-wait, per-batch progress and worker "
         "internals on a shared wall clock",
     )
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.add_argument("job", help="job id (from `fleet submit`)")
     q.add_argument("--out", default=None, metavar="PATH",
                    help="output trace path (default "
@@ -2767,8 +2812,8 @@ def _parse(argv):
         "MADSIM_TPU_XPROF=1), aligned by xprof clock-sync markers "
         "into one Perfetto session",
     )
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.add_argument("job", help="job id (from `fleet submit`)")
     q.add_argument("--out", default=None, metavar="PATH",
                    help="output trace path (default "
@@ -2782,8 +2827,8 @@ def _parse(argv):
         "lease holder, last event) — jax-free, needs only the HTTP "
         "control plane",
     )
-    obs_flags(q)
-    fleet_client_flags(q)
+    _obs_flags(q)
+    _fleet_client_flags(q)
     q.add_argument("--interval", type=float, default=2.0, metavar="S",
                    help="seconds between redraws")
     q.add_argument("--once", action="store_true",
@@ -2798,8 +2843,8 @@ def _parse(argv):
         "write tmp files, and rebuild the queue counts; exit 0 clean "
         "/ 1 corruption found",
     )
-    obs_flags(q)
-    fleet_root(q)
+    _obs_flags(q)
+    _fleet_root(q)
     q.add_argument("--dry-run", action="store_true",
                    help="scan + report only; quarantine/remove nothing")
     q.add_argument("--reclaim", action="store_true",
@@ -2824,7 +2869,7 @@ def _parse(argv):
         "report byte-identical to an unperturbed oracle run; a "
         "failing seed reproduces from its printed line forever",
     )
-    obs_flags(q)
+    _obs_flags(q)
     q.add_argument("--seed", type=int, default=0,
                    help="chaos schedule seed (the repro key)")
     q.add_argument("--sweep", type=int, default=1,
@@ -2861,24 +2906,59 @@ def _parse(argv):
                    help="keep the scratch farm even on success")
     q.set_defaults(fn=cmd_fleet)
 
-    p = sub.add_parser(
-        "lint",
-        help="static determinism & contract analysis: D-rules "
-        "(wall-clock/entropy/set-order/callback hazards, AST-only), "
-        "C-rules (Machine contract: handler purity, durable/torn spec "
-        "congruence, coverage projection), G-rules (fault-kind mirror "
-        "and RNG-layout cross-checks), and the whole-program families "
-        "— L (jax-free layer map), T (traced-value taint/donation), "
-        "R (static RNG ledger), S (sharding readiness: lane-axis "
-        "dataflow vs the collective registry). Exit 0 clean / "
-        "1 findings / 2 usage error — pre-commit friendly",
-    )
+
+@_command(
+    "lint",
+    help="static determinism & contract analysis: D-rules "
+    "(wall-clock/entropy/set-order/callback hazards, AST-only), "
+    "C-rules (Machine contract: handler purity, durable/torn spec "
+    "congruence, coverage projection), G-rules (fault-kind mirror "
+    "and RNG-layout cross-checks), and the whole-program families "
+    "— L (jax-free layer map), T (traced-value taint/donation), "
+    "R (static RNG ledger), S (sharding readiness: lane-axis "
+    "dataflow vs the collective registry). Exit 0 clean / "
+    "1 findings / 2 usage error — pre-commit friendly",
+)
+def _lint_args(p):
     from .analysis.cli import add_lint_args
 
     add_lint_args(p)
     p.set_defaults(fn=cmd_lint)
 
-    args = parser.parse_args(argv)
+
+def _build_parser(names) -> argparse.ArgumentParser:
+    """The top-level parser with the sub-parsers of `names`, in table
+    order. Its usage names every command whichever are built."""
+    parser = argparse.ArgumentParser(prog="madsim_tpu")
+    sub = parser.add_subparsers(
+        dest="cmd", required=True,
+        # argparse's own text where the whole table is built: a metavar
+        # also renames the positional in its "invalid choice" and
+        # "required" errors, which only the full build can raise
+        metavar=None if len(names) == len(COMMANDS)
+        else "{" + ",".join(COMMANDS) + "}",
+    )
+    for name in names:
+        kwargs, add = COMMANDS[name]
+        add(sub.add_parser(name, **kwargs))
+    return parser
+
+
+def _parse(argv):
+    """The parsed subcommand, with logging, a multihost job and the
+    persistent compile cache wired as it asks. Only the sub-parser the
+    argv names is built; no argv, `-h` or an unknown command builds the
+    whole table, for the top-level help and errors. Counts
+    `cli.parser_one` / `cli.parser_full` and notes `built` (sub-parsers
+    built) on the enclosing `cli_parse` span."""
+    from .perf.recorder import maybe_count, maybe_note
+
+    argv = sys.argv[1:] if argv is None else list(argv)
+    one = bool(argv) and argv[0] in COMMANDS
+    names = argv[:1] if one else list(COMMANDS)
+    maybe_count("cli.parser_one" if one else "cli.parser_full")
+    maybe_note(built=len(names))
+    args = _build_parser(names).parse_args(argv)
     if getattr(args, "log_level", None) or getattr(args, "log_jsonl", None):
         from .tracing import init_tracing
 

@@ -52,6 +52,10 @@ as duration minus children without guessing from ``depth``:
                        (children of ``batch_fold``)
 ``cli_parse``          `main`: the parser's build, `parse_args`, logging
                        and compile-cache priming, up to the subcommand.
+                       Arg ``built``: the sub-parsers built, 1 (the
+                       command the argv names: counter
+                       ``cli.parser_one``) or every command's (no argv,
+                       ``-h``, an unknown command: ``cli.parser_full``).
                        Only where a recorder is active before `main` is
                        entered (the benchmark, an in-process caller): a
                        ``--perf-timeline`` run opens its recorder after
